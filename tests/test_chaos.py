@@ -315,7 +315,7 @@ class TestCombinedChaos:
         _set_breakers(s.cop.tpu, threshold=1000)
         s.vars["tidb_distsql_scan_concurrency"] = "6"
         FP.seed(424242)
-        FP.enable("cop/device-error", ("prob", 0.25, DeviceTransientError("flaky tunnel")))
+        FP.enable("cop/device-error", ("prob", 0.25, DeviceTransientError("flaky connection")))
         FP.enable("cop/before-task", ("prob", 0.25, _chaos(s, random.Random(4))))
         _run_battery(s, base, engines=("tpu", "auto", "host"), rounds=2)
         FP.disable_all()

@@ -227,7 +227,7 @@ class TestClassification:
         assert classify_device_error(TiDBError("boring")) is None
 
     def test_transport_markers_are_transient(self):
-        for msg in ("UNAVAILABLE: tunnel reset", "socket closed", "request timed out",
+        for msg in ("UNAVAILABLE: connection reset", "socket closed", "request timed out",
                     "RESOURCE_EXHAUSTED: hbm"):
             assert isinstance(classify_device_error(RuntimeError(msg)), DeviceTransientError), msg
 

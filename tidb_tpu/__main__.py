@@ -15,12 +15,7 @@ def load_config(path: str) -> dict:
     """TOML config file (ref: config/config.go + config.toml.example —
     the file layer below CLI flags). Recognized keys mirror the flag
     names; [log]/[security]/[gc] tables flatten into them."""
-    try:
-        import tomllib  # 3.11+
-    except ImportError:
-        # tomllib IS tomli vendored into the stdlib; on 3.10 pip's
-        # vendored copy is the only API-compatible parser in the image
-        from pip._vendor import tomli as tomllib
+    import tomllib
 
     with open(path, "rb") as f:
         raw = tomllib.load(f)

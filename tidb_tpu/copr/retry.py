@@ -186,11 +186,11 @@ class Backoffer:
 # --- engine-boundary fault classification ---------------------------------
 
 # substrings marking a device fault worth retrying on-device (XLA runtime
-# status codes + tunnel/transport hiccups); everything else device-side is
+# status codes + transport hiccups); everything else device-side is
 # fatal and feeds the breaker
 _TRANSIENT_MARKERS = (
     "resource_exhausted", "unavailable", "deadline_exceeded", "aborted",
-    "cancelled", "preempt", "connection", "socket", "tunnel", "timed out",
+    "cancelled", "preempt", "connection", "socket", "timed out",
     "timeout", "temporarily",
 )
 

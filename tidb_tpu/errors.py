@@ -177,7 +177,7 @@ class DeviceError(TiDBError):
 
 
 class DeviceTransientError(DeviceError):
-    """Retriable device fault (preempted/ busy/ tunnel hiccup): worth a
+    """Retriable device fault (preempted/ busy/ transport hiccup): worth a
     backoff-retry on the device path before conceding to the host."""
 
 

@@ -22,19 +22,8 @@ from functools import partial
 import numpy as np
 
 from ..jaxenv import jax, jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.4.38 JAX keeps it in the experimental namespace
-    # check_rep's rep-rule table is incomplete there (a nested-pjit rule
-    # returns None and _check_rep crashes) — it is a validation pass only,
-    # so disable it rather than lose the whole mesh path
-    import functools
-
-    from jax.experimental.shard_map import shard_map as _esm
-
-    shard_map = functools.partial(_esm, check_rep=False)
 
 _US_DAY = 24 * 60 * 60 * 1_000_000
 

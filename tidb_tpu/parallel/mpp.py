@@ -35,19 +35,8 @@ import hashlib
 import numpy as np
 
 from ..jaxenv import jax, jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.4.38 JAX keeps it in the experimental namespace
-    # check_rep's rep-rule table is incomplete there (a nested-pjit rule
-    # returns None and _check_rep crashes) — it is a validation pass only,
-    # so disable it rather than lose the whole mesh path
-    import functools
-
-    from jax.experimental.shard_map import shard_map as _esm
-
-    shard_map = functools.partial(_esm, check_rep=False)
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..chunk.chunk import Chunk, Column, col_numpy_dtype, VARLEN
 from ..expr.expression import Column as ExprCol, Constant, Expression
@@ -368,12 +357,15 @@ class MPPEngine:
 
         return self._cached_stat(sd, ("pushsel", repr(rc)), compute)
 
-    def _dev_put(self, key, build):
+    def _dev_put(self, key, build, sharding):
         """Device array for `key`, uploading via build() on miss. Stale
         versions of the same (table, tag) are evicted eagerly; the rest
-        LRU under DEV_CACHE_BYTES."""
+        LRU under DEV_CACHE_BYTES. The upload lands in the layout the
+        program's in_spec names (`sharding`): a cached lane parked on the
+        default device would be re-scattered over the mesh by every
+        dispatch, which is the transfer this cache exists to avoid."""
         if key is None:
-            arr = jnp.asarray(build())
+            arr = jax.device_put(build(), sharding)
             # uncacheable mesh upload: still this statement's volume —
             # the MPP path charges the same TLS tracker seam the cop
             # engine's h2d does, so memory arbitration sees MPP too
@@ -386,7 +378,7 @@ class MPPEngine:
         tid, ver, tag = key[0], key[1], key[2]
         for k in [k for k in self._dev_cache if k[0] == tid and k[2] == tag and k[1] != ver]:
             self._dev_cache_nbytes -= self._dev_cache.pop(k).nbytes
-        arr = jnp.asarray(build())
+        arr = jax.device_put(build(), sharding)
         consume_current(arr.nbytes)  # uploader pays (volume proxy, PR 4 rule)
         self._dev_cache[key] = arr
         self._dev_cache_nbytes += arr.nbytes
@@ -1245,28 +1237,23 @@ class MPPEngine:
                 return None if _ver < 0 else (_tid, _ver, tag, _tot, _sh)
 
             spec = P(axis) if is_sharded else P()
+
+            def put(tag, build, _shd=NamedSharding(mesh, spec), _ck=ck, _tg=tg):
+                args.append(self._dev_put(_ck(_tg(tag)), build, _shd))
+
             if pref:
-                args.append(self._dev_put(
-                    ck(tg(("frowid", h))), lambda: lay(sel)))
+                put(("frowid", h), lambda: lay(sel))
             else:
-                args.append(self._dev_put(
-                    ck(tg("rowid")),
-                    lambda: lay(np.arange(n, dtype=np.int64))))
-            args.append(self._dev_put(ck(tg(("frv", h) if pref else "rv")), _rv))
+                put("rowid", lambda: lay(np.arange(n, dtype=np.int64)))
+            put(("frv", h) if pref else "rv", _rv)
             in_specs += [spec, spec]
             for off in offs:
                 if pref:
-                    args.append(self._dev_put(
-                        ck(tg(("fd", off, h))),
-                        lambda _o=off: lay(s.lane(_o)[0][sel])))
-                    args.append(self._dev_put(
-                        ck(tg(("fv", off, h))),
-                        lambda _o=off: lay(s.lane(_o)[1][sel])))
+                    put(("fd", off, h), lambda _o=off: lay(s.lane(_o)[0][sel]))
+                    put(("fv", off, h), lambda _o=off: lay(s.lane(_o)[1][sel]))
                 else:
-                    args.append(self._dev_put(
-                        ck(tg(("d", off))), lambda _o=off: lay(s.lane(_o)[0])))
-                    args.append(self._dev_put(
-                        ck(tg(("v", off))), lambda _o=off: lay(s.lane(_o)[1])))
+                    put(("d", off), lambda _o=off: lay(s.lane(_o)[0]))
+                    put(("v", off), lambda _o=off: lay(s.lane(_o)[1]))
                 in_specs += [spec, spec]
             scan_arg_meta.append((id(s.frag), offs, is_sharded, pref))
             shapes.append((total, is_sharded, offs, pref))
@@ -1290,7 +1277,8 @@ class MPPEngine:
                    tuple(lvl.lut_stride), lvl.lut_dom)
 
             def build(_lvl=lvl, _soj=soj):
-                arr = jnp.asarray(self._build_lut(_lvl, _soj))
+                arr = jax.device_put(self._build_lut(_lvl, _soj),
+                                     NamedSharding(mesh, P()))
                 # uploader pays (PR 4 volume-proxy rule); cache hits are
                 # free — the statement that built the structure carried it
                 consume_current(arr.nbytes)
@@ -1315,7 +1303,7 @@ class MPPEngine:
             self.compile_count += 1
         from ..jaxenv import unpack_rows
 
-        packed = np.asarray(prog(*[jnp.asarray(a) for a in args]))
+        packed = np.asarray(prog(*args))
         tick()
         outs = unpack_rows(packed)
         dropped = int(outs[-1][0])

@@ -44,6 +44,7 @@ Failure discipline (the durability fault domain, PR 10):
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import struct
 import subprocess
@@ -63,18 +64,37 @@ _LIB_LOCK = threading.Lock()
 
 
 def _load_lib() -> ctypes.CDLL:
-    """Build (once, mtime-cached) and load the native library."""
+    """Build (once per source content) and load the native library.
+
+    The binary is keyed on a hash of wal.cpp kept beside it, not on
+    mtimes: a copied tree (the chip tool, a fresh checkout next to a
+    leftover binary) does not keep the order of modification times, and
+    a stale binary would load without complaint."""
     global _LIB
     with _LIB_LOCK:
         if _LIB is not None:
             return _LIB
         src = os.path.abspath(_SRC)
         so = os.path.join(os.path.dirname(src), "libtpuwal.so")
-        if not os.path.exists(so) or os.path.getmtime(so) < os.path.getmtime(src):
+        stamp = so + ".src.sha256"
+        with open(src, "rb") as f:
+            want = hashlib.sha256(f.read()).hexdigest()
+        have = None
+        if os.path.exists(so) and os.path.exists(stamp):
+            with open(stamp) as f:
+                have = f.read().strip()
+        if have != want:
+            # build beside the target and rename: concurrent first users
+            # (xdist workers) must never dlopen a half-written file
+            tmp = f"{so}.{os.getpid()}.tmp"
             subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", so, src],
+                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", tmp, src],
                 check=True, capture_output=True,
             )
+            os.replace(tmp, so)
+            with open(stamp + f".{os.getpid()}.tmp", "w") as f:
+                f.write(want)
+            os.replace(stamp + f".{os.getpid()}.tmp", stamp)
         lib = ctypes.CDLL(so)
         lib.wal_open.restype = ctypes.c_void_p
         lib.wal_open.argtypes = [ctypes.c_char_p]

@@ -179,6 +179,11 @@ class MiniClient:
     def query_col(self, sql: str) -> list[str]:
         """COM_QUERY -> first column of every row as text (the acked-
         commit audit needs the values, not just the row count)."""
+        return ["" if r[0] is None else r[0] for r in self.query_rows(sql)]
+
+    def query_rows(self, sql: str) -> list[tuple]:
+        """COM_QUERY -> every row as a tuple of text values (None for
+        NULL); an OK packet (no resultset) reads as no rows."""
         self._write_packet(b"\x03" + sql.encode("utf8"), 0)
         pkt = self._read_packet()
         first = pkt[0]
@@ -191,7 +196,7 @@ class MiniClient:
         for _ in range(ncols):
             self._read_packet()
         self._read_packet()  # EOF
-        out: list[str] = []
+        out: list[tuple] = []
         while True:
             pkt = self._read_packet()
             if pkt[0] == 0xFE and len(pkt) < 9:
@@ -199,12 +204,16 @@ class MiniClient:
             if pkt[0] == 0xFF:
                 errno = struct.unpack_from("<H", pkt, 1)[0]
                 raise RuntimeError(f"server error {errno} mid-resultset")
-            if pkt[0] == 0xFB:  # NULL
-                out.append("")
-                continue
-            n, pos = self._read_lenc(pkt, 0)
-            out.append(pkt[pos:pos + n].decode("utf8", "replace"))
-        return out
+            row, pos = [], 0
+            for _ in range(ncols):
+                if pkt[pos] == 0xFB:  # NULL
+                    row.append(None)
+                    pos += 1
+                    continue
+                n, pos = self._read_lenc(pkt, pos)
+                row.append(pkt[pos:pos + n].decode("utf8", "replace"))
+                pos += n
+            out.append(tuple(row))
 
     @staticmethod
     def _read_lenc(buf: bytes, pos: int) -> tuple[int, int]:
