@@ -453,7 +453,13 @@ class CopClient:
         tags = {"region": t.region_id}
         if self.replica_name:
             tags["replica"] = self.replica_name
-        with tracing.activate(trace), memory.bind(mem), (
+        # the store's timeline ring rides along from here (not only around
+        # the engine call below): a tile-cache miss builds its batch
+        # before any engine runs, and its `tile.build` belongs on the ring
+        with tracing.activate(trace), memory.bind(mem), TL.bind(
+            getattr(self.storage, "timeline", None),
+            getattr(sctx, "group", "default") if sctx is not None else "default",
+        ), (
             trace.span("cop.task", **tags) if trace is not None else tracing._NOOP
         ):
             return self._run_task_traced(table, dag, t, read_ts, engine, bo, cache, sctx, st)

@@ -12,6 +12,7 @@ builds the task batch from the txn's merged view (client.py send).
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from threading import RLock
 
@@ -22,6 +23,7 @@ from ..codec import tablecodec
 from ..codec.row import decode_row
 from ..catalog.schema import TableInfo
 from ..mysqltypes.datum import Datum
+from ..utils import timeline as TL
 
 
 @dataclass
@@ -607,14 +609,23 @@ class TileCache:
                 self.hits += 1
                 return cached
             self.misses += 1
-        snap = self.storage.snapshot(read_ts)
-        segs, loose = snap.scan_segments(start, end)
-        batch = build_batch_from_segments(table, segs, loose, ver)
+        # `tile.build` (the host half: this region's columnar batch)
+        # encloses `tile.gather` (segments → columns); the device half is
+        # booked by the mirror (tpu_engine.DeviceBatch)
+        t0 = time.perf_counter_ns()
+        with TL.span("tile.gather") as gather:
+            snap = self.storage.snapshot(read_ts)
+            segs, loose = snap.scan_segments(start, end)
+            batch = build_batch_from_segments(table, segs, loose, ver)
+            gather.args["segments"] = len(segs)
         batch.start, batch.end = start, end
         batch.min_valid_ts = last_commit_ts
         if read_ts >= last_commit_ts:
             with self._lock:
                 self._cache[key] = batch
+        TL.boundary("tile.build", t0, time.perf_counter_ns(), part="host",
+                    table=table.name, rows=batch.n_rows, columns=len(batch.data),
+                    host_bytes=int(batch_nbytes(batch)))
         return batch
 
     def invalidate_table(self, table_id: int) -> None:

@@ -56,45 +56,39 @@ from .tilecache import (
 class _Timed:
     """A jitted program with its first dispatch timed: JAX traces+compiles
     synchronously inside the first call (later calls dispatch async in
-    sub-ms), so the first-call wall IS the compile cost — the
-    tidb_tpu_compile_seconds series and the trace's device.compile phase.
-    A benign race (two threads both timing the first call) at worst
-    records one extra sample."""
+    sub-ms), so the first-call wall IS the compile cost — booked as
+    `<prefix>.compile` (the tidb_tpu_compile_seconds series and the
+    trace's compile phase); every later call is booked as
+    `<prefix>.dispatch`: the jit call IS the async dispatch — its wall
+    is queueing cost, not compute (the fetch observes that). The cop
+    engine's programs book under `device.`, the MPP engine's under
+    `mpp.`. A benign race (two threads both timing the first call) at
+    worst records one extra sample."""
 
-    __slots__ = ("fn", "_compiled")
+    __slots__ = ("fn", "_compiled", "_compile", "_dispatch")
 
-    def __init__(self, fn):
+    def __init__(self, fn, prefix: str = "device"):
         self.fn = fn
         self._compiled = False
+        self._compile = prefix + ".compile"
+        self._dispatch = prefix + ".dispatch"
 
     def __call__(self, *args):
-        if self._compiled:
-            tl = TL.active()
-            if tl is None:
-                return self.fn(*args)
-            # warmed path: the jit call IS the async dispatch — its wall
-            # is queueing cost, not compute (device_get observes that)
-            t0 = time.perf_counter_ns()
-            out = self.fn(*args)
-            tl.device_event("device.dispatch", "dispatch", t0, time.perf_counter_ns())
-            return out
         t0 = time.perf_counter_ns()
         out = self.fn(*args)
         t1 = time.perf_counter_ns()
-        dt = (t1 - t0) / 1e9
-        self._compiled = True
-        M.TPU_COMPILE_SECONDS.observe(dt)
-        tracing.add_phase("compile_ms", dt * 1e3)
-        tracing.add_phase_event("device.compile", t0, t1)
-        tl = TL.active()
-        if tl is not None:
-            tl.device_event("device.compile", "compile", t0, t1)
+        if self._compiled:
+            TL.boundary(self._dispatch, t0, t1)
+        else:
+            self._compiled = True
+            TL.boundary(self._compile, t0, t1)
         return out
 
 
 def _to_device(a: np.ndarray, device=None):
-    """Host→device upload with transfer accounting (the h2d half of
-    tidb_tpu_transfer_bytes_total and the trace's device.transfer phase).
+    """Host→device upload with transfer accounting (`device.h2d`: the h2d
+    half of tidb_tpu_transfer_bytes_total, the upload stage of
+    tidb_tpu_tile_build_seconds and the trace's device.transfer phase).
     With `device` the array is COMMITTED to that mesh device — jit
     follows committed inputs, so pinning the uploads is what pins the
     whole launch to its runner lane (PR 6 per-device dispatch).
@@ -103,37 +97,22 @@ def _to_device(a: np.ndarray, device=None):
     consume can raise the quota/server-limit error right at the
     allocation site (a real allocation failure, never a device fault)."""
     _mem.consume_current(a.nbytes)
-    t0 = time.perf_counter_ns()
-    out = jnp.asarray(a) if device is None else jax.device_put(a, device)
-    t1 = time.perf_counter_ns()
-    M.TPU_TRANSFER_BYTES.inc(a.nbytes, dir="h2d")
-    tracing.add_phase("h2d_bytes", a.nbytes)
-    tracing.add_phase("h2d_ms", (t1 - t0) / 1e6)
-    tracing.add_phase_event("device.transfer", t0, t1, dir="h2d", bytes=int(a.nbytes))
-    tl = TL.active()
-    if tl is not None:
-        tl.device_event("device.h2d", "transfer", t0, t1, bytes=int(a.nbytes))
-    return out
+    with TL.span("device.h2d", bytes=int(a.nbytes)):
+        return jnp.asarray(a) if device is None else jax.device_put(a, device)
 
 
-def _fetch(x):
+def _fetch(x, programs: int = 1):
     """Device→host fetch: `jax.device_get` blocks until the async dispatch
-    finishes computing, so its wall is the observable device execute+fetch
-    time (tidb_tpu_device_execute_seconds); result bytes are the d2h half
-    of the transfer series."""
+    finishes computing, so the wall of `device.execute` is the HOST
+    blocked in `device_get` for the `programs` dispatched programs of
+    the launch: the observable device execute+fetch time
+    (tidb_tpu_device_execute_seconds); result bytes are the d2h half of
+    the transfer series."""
     t0 = time.perf_counter_ns()
     out = jax.device_get(x)
     t1 = time.perf_counter_ns()
-    dt = (t1 - t0) / 1e9
     nbytes = sum(getattr(l, "nbytes", 0) for l in jax.tree_util.tree_leaves(out))
-    M.TPU_EXECUTE_SECONDS.observe(dt, resource_group=TL.current_group())
-    M.TPU_TRANSFER_BYTES.inc(nbytes, dir="d2h")
-    tracing.add_phase("execute_ms", dt * 1e3)
-    tracing.add_phase("d2h_bytes", nbytes)
-    tracing.add_phase_event("device.execute", t0, t1, d2h_bytes=int(nbytes))
-    tl = TL.active()
-    if tl is not None:
-        tl.device_event("device.execute", "execute", t0, t1, d2h_bytes=int(nbytes))
+    TL.boundary("device.execute", t0, t1, d2h_bytes=int(nbytes), programs=programs)
     # NOT consumed into the memory tracker: the fetched result becomes a
     # chunk that drain() charges at materialization — charging the d2h
     # here too would double-count the same data on the device path only
@@ -324,9 +303,13 @@ class DeviceBatch:
         self.logical_nbytes = 0
         rv = np.zeros(self.padded, dtype=bool)
         rv[:n] = True
+        t_build = time.perf_counter_ns()
         self.row_valid = _to_device(rv.reshape(self.t, self.r), device)
         self.wire_nbytes += self.padded
         self.logical_nbytes += self.padded
+        TL.boundary("tile.build", t_build, time.perf_counter_ns(), part="mirror",
+                    table=getattr(getattr(batch, "table", None), "name", ""),
+                    rows=n, column="row_valid")
 
     def _pad2d(self, a: np.ndarray):
         from .tilecache import _pad2d
@@ -354,6 +337,10 @@ class DeviceBatch:
             # compressed payload is small enough to keep, which the dense
             # padded form never was. Writes race benignly: the encode is
             # deterministic and dict assignment is atomic.
+            # `tile.build` (this lane's mirror) encloses `tile.encode`
+            # (codec choice + encode, skipped on an encode-cache hit) and
+            # the `device.h2d` uploads of the lane's payloads
+            t_build = time.perf_counter_ns()
             ecache = getattr(self.batch, "_enc_cache", None)
             if ecache is None:
                 ecache = self.batch._enc_cache = {}
@@ -365,26 +352,28 @@ class DeviceBatch:
                     self.vocabs[off] = vocab
                 v = self.batch.valid[off]
             else:
-                d = self.batch.data[off]
-                v = self.batch.valid[off]
-                vocab = None
-                if d.dtype == object:
-                    coll = getattr(self.batch.table.columns[off].ft, "collate", "utf8mb4_bin")
-                    codes, vocab = _dict_encode_lane(d, v, coll)
-                    self.vocabs[off] = vocab
-                    d = codes
-                if self.compress:
-                    pay_d, sig_d = encode_data_lane(d, v, (self.t, self.r))
-                    pay_v, sig_v = encode_valid_lane(v, (self.t, self.r))
-                    # cache the verdict even when both sides stayed dense:
-                    # the entry is a tuple of references (d IS the batch's
-                    # own lane) and skipping it would re-pay the O(n)
-                    # codec probes on every mirror rebuild — which cluster
-                    # exactly on the memory-pressure evict/spill paths
-                    ecache[ekey] = (d, vocab, pay_d, sig_d, pay_v, sig_v)
-                else:
-                    pay_d = pay_v = None
-                    sig_d, sig_v = ("dense",), ("dense",)
+                with TL.span("tile.encode", column=off) as enc:
+                    d = self.batch.data[off]
+                    v = self.batch.valid[off]
+                    vocab = None
+                    if d.dtype == object:
+                        coll = getattr(self.batch.table.columns[off].ft, "collate", "utf8mb4_bin")
+                        codes, vocab = _dict_encode_lane(d, v, coll)
+                        self.vocabs[off] = vocab
+                        d = codes
+                    if self.compress:
+                        pay_d, sig_d = encode_data_lane(d, v, (self.t, self.r))
+                        pay_v, sig_v = encode_valid_lane(v, (self.t, self.r))
+                        # cache the verdict even when both sides stayed dense:
+                        # the entry is a tuple of references (d IS the batch's
+                        # own lane) and skipping it would re-pay the O(n)
+                        # codec probes on every mirror rebuild — which cluster
+                        # exactly on the memory-pressure evict/spill paths
+                        ecache[ekey] = (d, vocab, pay_d, sig_d, pay_v, sig_v)
+                    else:
+                        pay_d = pay_v = None
+                        sig_d, sig_v = ("dense",), ("dense",)
+                    enc.args["codec"] = sig_d[0]
             logical = self.padded * (d.dtype.itemsize + 1)  # dense data+valid
             self._data[off] = (
                 _to_device(self._pad2d(d), self.device) if pay_d is None
@@ -404,20 +393,17 @@ class DeviceBatch:
             M.TPU_TILE_COMPRESSED_BYTES.inc(
                 self._wire(self._valid[off]), codec=sig_v[0]
             )
-            tracing.add_phase("wire_bytes", wire)
-            tracing.add_phase("logical_bytes", logical)
             self.upload_ids[off] = (tracing._next_id(), wire)
+            TL.boundary("tile.build", t_build, time.perf_counter_ns(), part="mirror",
+                        table=getattr(getattr(self.batch, "table", None), "name", ""),
+                        rows=self.batch.n_rows, column=off,
+                        wire_bytes=wire, logical_bytes=logical)
         else:
             rec = self.upload_ids.get(off)
             if rec is not None:
                 now = time.perf_counter_ns()
-                tracing.add_phase("cache_ref_bytes", rec[1])
-                tracing.add_phase_event("device.cache_ref", now, now,
-                                        upload_id=rec[0], bytes=rec[1])
-                tl = TL.active()
-                if tl is not None:
-                    tl.device_event("device.cache_ref", "transfer", now, now,
-                                    upload_id=rec[0], bytes=rec[1])
+                TL.boundary("device.cache_ref", now, now,
+                            upload_id=rec[0], bytes=rec[1])
         return self._data[off], self._valid[off]
 
 
@@ -755,31 +741,44 @@ class TPUEngine:
         if lane is None:
             lane = placed = self.place(batch)
         try:
-            with _lane_guard(lane):
+            t_ask = time.perf_counter_ns()  # before the lane lock is asked for
+            # one launch identity for every boundary booked below (a
+            # re-run inside a grouped launch keeps the group's id)
+            with _lane_guard(lane), TL.launch_scope(tracing._next_id()):
                 t0 = time.perf_counter_ns()
-                plan = self._plan_for(dag, batch, lane)
-                if plan is None:
-                    with self._lock:
-                        self.fallbacks += 1
-                    M.TPU_FALLBACK.inc(path="cop", reason="not_lowerable")
-                    return execute_dag_host(dag, batch)
-                if isinstance(plan, DevicePlan):
-                    chunk = _mark_device(plan.finalize(_fetch(plan.launch())))
-                else:
-                    chunk = _mark_device(plan())
-                if _solo_event:
-                    # every device dispatch shows on the timeline, solo
-                    # launches included (grouped ones are the batcher's)
-                    lane.launches += 1
-                    M.TPU_LANE_LAUNCHES.inc(device=lane.name, mode="solo")
-                    tl = TL.active()
-                    if tl is not None:
-                        tl.device_event(
-                            "cop.launch", "launch", t0, time.perf_counter_ns(),
-                            launch_id=tracing._next_id(), occupancy=1,
-                            device=lane.name,
+                launched = False
+                try:
+                    with TL.span("cop.lower", tasks=1, groups=1):
+                        plan = self._plan_for(dag, batch, lane)
+                    if plan is None:
+                        with self._lock:
+                            self.fallbacks += 1
+                        M.TPU_FALLBACK.inc(path="cop", reason="not_lowerable")
+                        return execute_dag_host(dag, batch)
+                    if isinstance(plan, DevicePlan):
+                        host = _fetch(plan.launch())
+                        with TL.span("cop.finalize", tasks=1):
+                            chunk = _mark_device(plan.finalize(host))
+                    else:
+                        chunk = _mark_device(plan())
+                    launched = True
+                    return chunk
+                finally:
+                    if _solo_event:
+                        # every device dispatch shows on the timeline, solo
+                        # launches included (grouped ones are the
+                        # batcher's). A solo launch never queued: its only
+                        # wait is the lane lock
+                        if launched:
+                            lane.launches += 1
+                            M.TPU_LANE_LAUNCHES.inc(device=lane.name, mode="solo")
+                        trace = tracing.current_trace()
+                        TL.boundary(
+                            "cop.launch", t0, time.perf_counter_ns(),
+                            occupancy=1, device=lane.name, ok=launched,
+                            queued_ns=t0 - t_ask, lane_lock_ns=t0 - t_ask,
+                            waiters=[trace.trace_id] if trace is not None else [],
                         )
-                return chunk
         finally:
             if placed is not None:
                 self.release_lane(placed)
@@ -814,7 +813,12 @@ class TPUEngine:
         padded by repeating its last task, padding discarded), so steady
         state pays at most log2(MAX_FUSE) extra compiles per key — per
         device lane (jit caches executables per committed device)."""
-        plans = [self._plan_for(dag, batch, lane) for dag, batch in items]
+        with TL.span("cop.lower", tasks=len(items)) as lower:
+            plans = [self._plan_for(dag, batch, lane) for dag, batch in items]
+            lower.args["groups"] = len({
+                p.key for p in plans
+                if isinstance(p, DevicePlan) and p.key is not None and p.args is not None
+            })
         results: list = [None] * len(items)
         fusable: dict = {}  # program key -> [task index]
         launched = []  # (kind, payload) in launch order
@@ -875,16 +879,18 @@ class TPUEngine:
                 launched.append(("grp", (grp, out)))
 
         if launched:
-            fetched = _fetch([payload[1] for _, payload in launched])
-            for (kind, payload), host in zip(launched, fetched):
-                if kind == "one":
-                    i = payload[0]
-                    results[i] = _mark_device(plans[i].finalize(host))
-                else:
-                    for j, i in enumerate(payload[0]):
-                        results[i] = _mark_device(plans[i].finalize(
-                            jax.tree_util.tree_map(lambda a: a[j], host)
-                        ))
+            fetched = _fetch([payload[1] for _, payload in launched],
+                             programs=len(launched))
+            with TL.span("cop.finalize", tasks=len(items)):
+                for (kind, payload), host in zip(launched, fetched):
+                    if kind == "one":
+                        i = payload[0]
+                        results[i] = _mark_device(plans[i].finalize(host))
+                    else:
+                        for j, i in enumerate(payload[0]):
+                            results[i] = _mark_device(plans[i].finalize(
+                                jax.tree_util.tree_map(lambda a: a[j], host)
+                            ))
         return results
 
     # --- lowering ----------------------------------------------------------
@@ -1044,11 +1050,12 @@ class TPUEngine:
         return rec(e)
 
     def _mask(self, r_conds, lanes, row_valid):
-        mask = row_valid
-        for c in r_conds:
-            d, v = self._eval_device(c, lanes)
-            mask = mask & v & (d != 0)
-        return mask
+        with jax.named_scope("sel"):
+            mask = row_valid
+            for c in r_conds:
+                d, v = self._eval_device(c, lanes)
+                mask = mask & v & (d != 0)
+            return mask
 
     def _program(self, key, builder):
         with self._lock:
@@ -1180,10 +1187,14 @@ class TPUEngine:
             return enc
         if not enc:  # all-valid alias: the mask IS row_valid, for free
             return row_valid
+        # `decode.<codec>` names the ops in the device trace: op metadata
+        # only, it changes neither the program nor any cache key
         if "p" in enc:  # pack: frame-of-reference sub-word + base scalar
-            return enc["p"].astype(enc["b"].dtype) + enc["b"]
+            with jax.named_scope("decode.pack"):
+                return enc["p"].astype(enc["b"].dtype) + enc["b"]
         if "c" in enc:  # dict: sorted vocab gather
-            return enc["v"][enc["c"]]
+            with jax.named_scope("decode.dict"):
+                return enc["v"][enc["c"]]
         # rle: static-length expand; total_repeat_length truncates to the
         # narrowed shape (only pad rows drop). The tail BEYOND the last
         # run gathers from the trailing zero-value pad run the encoder
@@ -1191,10 +1202,11 @@ class TPUEngine:
         # pad rows decode to 0/False — and every kernel additionally
         # masks with row_valid before reducing
         shape = row_valid.shape
-        flat = jnp.repeat(
-            enc["rv"], enc["rl"], total_repeat_length=shape[0] * shape[1]
-        )
-        return flat.reshape(shape)
+        with jax.named_scope("decode.rle"):
+            flat = jnp.repeat(
+                enc["rv"], enc["rl"], total_repeat_length=shape[0] * shape[1]
+            )
+            return flat.reshape(shape)
 
     @classmethod
     def _unflatten(cls, flat, order, row_valid):
@@ -1290,20 +1302,21 @@ class TPUEngine:
             l = self._unflatten(flat, order, row_valid)
             mask = self._mask(r_conds, l, row_valid)
             flat_mask = mask.reshape(-1)
-            # combined group code, mixed radix; NULL key → extra slot
-            if gb:
-                code = jnp.zeros(flat_mask.shape, dtype=jnp.int32)
-                for (idx, lo), dom in zip(key_cols, domains):
-                    d, v = l[idx]
-                    kd = (d.reshape(-1).astype(jnp.int32) - lo + 1) * v.reshape(-1)
-                    code = code * (dom + 1) + kd
-            else:
-                code = jnp.zeros(flat_mask.shape, dtype=jnp.int32)
-            seg = jnp.where(flat_mask, code, nseg)  # masked rows → overflow slot
-            outs = [_seg_sum(flat_mask.astype(jnp.int64), seg, nseg)]
-            for a in agg.aggs:
-                outs.extend(self._agg_partials_device(a, l, flat_mask, seg, nseg))
-            return outs
+            with jax.named_scope("agg"):
+                # combined group code, mixed radix; NULL key → extra slot
+                if gb:
+                    code = jnp.zeros(flat_mask.shape, dtype=jnp.int32)
+                    for (idx, lo), dom in zip(key_cols, domains):
+                        d, v = l[idx]
+                        kd = (d.reshape(-1).astype(jnp.int32) - lo + 1) * v.reshape(-1)
+                        code = code * (dom + 1) + kd
+                else:
+                    code = jnp.zeros(flat_mask.shape, dtype=jnp.int32)
+                seg = jnp.where(flat_mask, code, nseg)  # masked rows → overflow slot
+                outs = [_seg_sum(flat_mask.astype(jnp.int64), seg, nseg)]
+                for a in agg.aggs:
+                    outs.extend(self._agg_partials_device(a, l, flat_mask, seg, nseg))
+                return outs
 
         fn, aux = self._packed_program(key, kernel, nseg)
 
@@ -1354,52 +1367,53 @@ class TPUEngine:
             def kernel(flat, row_valid):
                 l = self._unflatten(flat, order, row_valid)
                 mask = self._mask(r_conds, l, row_valid).reshape(-1)
-                n = mask.shape[0]
-                # lexicographic sort: masked rows last, then NULL flag +
-                # value per key; the trailing iota operand is the row perm
-                ops = [(~mask).astype(jnp.int32)]
-                for ki in key_idx:
-                    d, v = l[ki]
-                    vf = v.reshape(-1)
-                    ops.append((~vf).astype(jnp.int32))
-                    # zero data under NULL so residual bytes can't split
-                    # the NULL group (direct path normalizes the same way).
-                    # float/uint64 keys group by canonical bit pattern:
-                    # equality (all GROUP BY needs) survives the bitcast,
-                    # with -0.0 folded into +0.0 first
-                    dr = d.reshape(-1)
-                    if jnp.issubdtype(dr.dtype, jnp.floating):
-                        dr = jnp.where(dr == 0.0, 0.0, dr.astype(jnp.float64))
-                        dr = jax.lax.bitcast_convert_type(dr, jnp.int64)
-                    elif dr.dtype == jnp.uint64:
-                        dr = jax.lax.bitcast_convert_type(dr, jnp.int64)
-                    else:
-                        dr = dr.astype(jnp.int64)
-                    ops.append(jnp.where(vf, dr, 0))
-                perm = lex_sort_perm(ops)
-                res = [o[perm] for o in ops]
-                s_mask = res[0] == 0
-                s_keys = res[1:]
-                diff = jnp.zeros(n, dtype=bool).at[0].set(True)
-                one = jnp.ones(1, dtype=bool)
-                for k in s_keys:
-                    diff = diff | jnp.concatenate([one, k[1:] != k[:-1]])
-                new = diff & s_mask
-                seg0 = jnp.cumsum(new.astype(jnp.int32)) - 1
-                n_groups = jnp.maximum(seg0[-1] + 1, 0)
-                # groups beyond capacity fold into the overflow slot; the
-                # exact n_groups triggers a host-side retry at higher cap
-                seg = jnp.where(s_mask, jnp.minimum(seg0, gcap), gcap)
-                outs = []
-                for j in range(len(key_idx)):
-                    knull = s_keys[2 * j]
-                    kval = s_keys[2 * j + 1]
-                    outs.append(_seg_max(jnp.where(s_mask, kval, I64_MIN), seg, gcap, I64_MIN))
-                    outs.append(_seg_max(jnp.where(s_mask, 1 - knull.astype(jnp.int64), -1), seg, gcap, -1))
-                l_perm = {i: (dd.reshape(-1)[perm], vv.reshape(-1)[perm]) for i, (dd, vv) in l.items()}
-                for a in agg.aggs:
-                    outs.extend(self._agg_partials_device(a, l_perm, s_mask, seg, gcap, index_lane=perm))
-                return n_groups, outs
+                with jax.named_scope("agg"):
+                    n = mask.shape[0]
+                    # lexicographic sort: masked rows last, then NULL flag +
+                    # value per key; the trailing iota operand is the row perm
+                    ops = [(~mask).astype(jnp.int32)]
+                    for ki in key_idx:
+                        d, v = l[ki]
+                        vf = v.reshape(-1)
+                        ops.append((~vf).astype(jnp.int32))
+                        # zero data under NULL so residual bytes can't split
+                        # the NULL group (direct path normalizes the same way).
+                        # float/uint64 keys group by canonical bit pattern:
+                        # equality (all GROUP BY needs) survives the bitcast,
+                        # with -0.0 folded into +0.0 first
+                        dr = d.reshape(-1)
+                        if jnp.issubdtype(dr.dtype, jnp.floating):
+                            dr = jnp.where(dr == 0.0, 0.0, dr.astype(jnp.float64))
+                            dr = jax.lax.bitcast_convert_type(dr, jnp.int64)
+                        elif dr.dtype == jnp.uint64:
+                            dr = jax.lax.bitcast_convert_type(dr, jnp.int64)
+                        else:
+                            dr = dr.astype(jnp.int64)
+                        ops.append(jnp.where(vf, dr, 0))
+                    perm = lex_sort_perm(ops)
+                    res = [o[perm] for o in ops]
+                    s_mask = res[0] == 0
+                    s_keys = res[1:]
+                    diff = jnp.zeros(n, dtype=bool).at[0].set(True)
+                    one = jnp.ones(1, dtype=bool)
+                    for k in s_keys:
+                        diff = diff | jnp.concatenate([one, k[1:] != k[:-1]])
+                    new = diff & s_mask
+                    seg0 = jnp.cumsum(new.astype(jnp.int32)) - 1
+                    n_groups = jnp.maximum(seg0[-1] + 1, 0)
+                    # groups beyond capacity fold into the overflow slot; the
+                    # exact n_groups triggers a host-side retry at higher cap
+                    seg = jnp.where(s_mask, jnp.minimum(seg0, gcap), gcap)
+                    outs = []
+                    for j in range(len(key_idx)):
+                        knull = s_keys[2 * j]
+                        kval = s_keys[2 * j + 1]
+                        outs.append(_seg_max(jnp.where(s_mask, kval, I64_MIN), seg, gcap, I64_MIN))
+                        outs.append(_seg_max(jnp.where(s_mask, 1 - knull.astype(jnp.int64), -1), seg, gcap, -1))
+                    l_perm = {i: (dd.reshape(-1)[perm], vv.reshape(-1)[perm]) for i, (dd, vv) in l.items()}
+                    for a in agg.aggs:
+                        outs.extend(self._agg_partials_device(a, l_perm, s_mask, seg, gcap, index_lane=perm))
+                    return n_groups, outs
 
             return kernel
 
@@ -1761,26 +1775,27 @@ class TPUEngine:
         def kernel(flat, row_valid):
             l = self._unflatten(flat, order, row_valid)
             mask = self._mask(r_conds, l, row_valid)
-            d, v = self._eval_device(r_e, l)
-            d = jnp.full(mask.shape, d) if d.ndim == 0 else d
-            v = jnp.full(mask.shape, v) if v.ndim == 0 else v
-            d, v, m = d.reshape(-1), v.reshape(-1), mask.reshape(-1)
-            # integer keys stay integer (exact for packed datetimes/decimals)
-            if jnp.issubdtype(d.dtype, jnp.floating):
-                lo, hi = -jnp.inf, jnp.inf
-            else:
-                d = d.astype(jnp.int64)
-                info = np.iinfo(np.int64)
-                lo, hi = info.min, info.max - 1
-            if desc:
-                # NULLs last desc; masked rows last
-                sortkey = jnp.where(m & v, d, lo)
-            else:
-                # top_k takes largest → negate for asc; NULLs first asc
-                sortkey = jnp.where(m, jnp.where(v, -d, hi), lo)
-            _, idx = jax.lax.top_k(sortkey, min(n, sortkey.shape[0]))
-            # ship only k validity bits, not the full row mask
-            return idx, m[idx]
+            with jax.named_scope("topn"):
+                d, v = self._eval_device(r_e, l)
+                d = jnp.full(mask.shape, d) if d.ndim == 0 else d
+                v = jnp.full(mask.shape, v) if v.ndim == 0 else v
+                d, v, m = d.reshape(-1), v.reshape(-1), mask.reshape(-1)
+                # integer keys stay integer (exact for packed datetimes/decimals)
+                if jnp.issubdtype(d.dtype, jnp.floating):
+                    lo, hi = -jnp.inf, jnp.inf
+                else:
+                    d = d.astype(jnp.int64)
+                    info = np.iinfo(np.int64)
+                    lo, hi = info.min, info.max - 1
+                if desc:
+                    # NULLs last desc; masked rows last
+                    sortkey = jnp.where(m & v, d, lo)
+                else:
+                    # top_k takes largest → negate for asc; NULLs first asc
+                    sortkey = jnp.where(m, jnp.where(v, -d, hi), lo)
+                _, idx = jax.lax.top_k(sortkey, min(n, sortkey.shape[0]))
+                # ship only k validity bits, not the full row mask
+                return idx, m[idx]
 
         fn = self._program(key, kernel)
 
@@ -1814,20 +1829,21 @@ class TPUEngine:
         def kernel(flat, row_valid):
             l = self._unflatten(flat, order, row_valid)
             mask = self._mask(r_conds, l, row_valid).reshape(-1)
-            rows = mask.shape[0]
-            ops = [(~mask).astype(jnp.int32)]  # masked rows last
-            for r_e, desc in r_by:
-                d, v = self._eval_device(r_e, l)
-                d = jnp.full((rows,), d) if d.ndim == 0 else d.reshape(-1)
-                v = jnp.full((rows,), v) if v.ndim == 0 else v.reshape(-1)
-                # NULLs first asc / last desc (host _lex_argsort contract)
-                nullkey = jnp.where(v, 0, 1) if desc else jnp.where(v, 1, 0)
-                dd = jnp.where(v, d, jnp.zeros((), d.dtype))
-                if desc:
-                    dd = -dd if jnp.issubdtype(d.dtype, jnp.floating) else ~dd
-                ops += [nullkey.astype(jnp.int32), dd]
-            perm = lex_sort_perm(ops)
-            return perm[: min(n, rows)], ops[0][perm][: min(n, rows)] == 0
+            with jax.named_scope("topn"):
+                rows = mask.shape[0]
+                ops = [(~mask).astype(jnp.int32)]  # masked rows last
+                for r_e, desc in r_by:
+                    d, v = self._eval_device(r_e, l)
+                    d = jnp.full((rows,), d) if d.ndim == 0 else d.reshape(-1)
+                    v = jnp.full((rows,), v) if v.ndim == 0 else v.reshape(-1)
+                    # NULLs first asc / last desc (host _lex_argsort contract)
+                    nullkey = jnp.where(v, 0, 1) if desc else jnp.where(v, 1, 0)
+                    dd = jnp.where(v, d, jnp.zeros((), d.dtype))
+                    if desc:
+                        dd = -dd if jnp.issubdtype(d.dtype, jnp.floating) else ~dd
+                    ops += [nullkey.astype(jnp.int32), dd]
+                perm = lex_sort_perm(ops)
+                return perm[: min(n, rows)], ops[0][perm][: min(n, rows)] == 0
 
         fn = self._program(key, kernel)
 

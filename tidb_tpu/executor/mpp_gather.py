@@ -20,6 +20,8 @@ from ..planner.ranger import prefix_next
 from ..planner.plans import Join, LogicalPlan
 from ..sched.scheduler import raise_if_interrupted
 from ..utils import memory
+from ..utils import timeline as TL
+from ..utils import tracing
 from .executors import ExecContext, Executor, FinalHashAggExec
 
 log = logging.getLogger("tidb_tpu.mpp")
@@ -208,9 +210,16 @@ class MPPGatherExec(Executor):
             return None
         resolved = False  # admitted breakers heard success/failure/abort
         try:
-            with memory.bind(getattr(sctx, "mem", None)):
-                scan_datas = self._build_scan_datas(client, engine, gate)
-                st("processed_rows", sum(sd.n_rows for sd in scan_datas))
+            # the statement's trace, the store's timeline ring and a phase
+            # frame bound to THIS thread, as the cop client binds them
+            # around a task: the engine's boundary hook reads all three
+            with memory.bind(getattr(sctx, "mem", None)), tracing.activate(trace), TL.bind(
+                getattr(client.storage, "timeline", None), getattr(sctx, "group", "default"),
+            ), tracing.collect_phases() as ph:
+                with TL.span("mpp.gather", scans=len(self.mplan.scans)) as sp:
+                    scan_datas = self._build_scan_datas(client, engine, gate)
+                    sp.args["rows"] = sum(sd.n_rows for sd in scan_datas)
+                st("processed_rows", sp.args["rows"])
                 mesh = engine._mesh if getattr(engine, "_mesh", None) is not None else make_mesh()
                 engine._mesh = mesh
                 bo = Backoffer.for_ctx(sctx, stats=st)
@@ -242,6 +251,9 @@ class MPPGatherExec(Executor):
                     # like the reference planner — it never hard-fails
                     failpoint="mpp/device-error",
                 )
+            # compile / transfer / fetch of the mesh dispatch as exec
+            # details (slow log, STATEMENTS_SUMMARY) and TRACE spans
+            client._note_device_phases(ph, st, trace)
             # success/fault resolved every admitted breaker inside the
             # guard; a prepare-time DECLINE touched no device, so the
             # finally below releases any claimed probe slots instead

@@ -31,6 +31,8 @@ Design notes:
 from __future__ import annotations
 
 import hashlib
+import threading
+import time
 
 import numpy as np
 
@@ -43,6 +45,8 @@ from ..expr.expression import Column as ExprCol, Constant, Expression
 from ..mysqltypes.datum import Datum
 from ..planner.fragment import BROADCAST, HASH, LOCAL, JoinFrag, MPPPlan, ScanFrag
 from ..utils import metrics as M
+from ..utils import timeline as TL
+from ..utils import tracing
 from ..utils.memory import consume_current
 
 I64_MAX = np.iinfo(np.int64).max
@@ -357,6 +361,20 @@ class MPPEngine:
 
         return self._cached_stat(sd, ("pushsel", repr(rc)), compute)
 
+    @staticmethod
+    def _upload(build, sharding, kind: str):
+        """One cold mesh upload, booked as `mpp.upload` (the h2d half of
+        tidb_tpu_transfer_bytes_total): the host layout `build()` makes
+        (`build_ns` of the span) and the `device_put` into the layout
+        the program's in_spec names."""
+        t0 = time.perf_counter_ns()
+        host = build()
+        t1 = time.perf_counter_ns()
+        arr = jax.device_put(host, sharding)
+        TL.boundary("mpp.upload", t0, time.perf_counter_ns(), bytes=int(arr.nbytes),
+                    kind=kind, build_ns=t1 - t0)
+        return arr
+
     def _dev_put(self, key, build, sharding):
         """Device array for `key`, uploading via build() on miss. Stale
         versions of the same (table, tag) are evicted eagerly; the rest
@@ -365,7 +383,7 @@ class MPPEngine:
         default device would be re-scattered over the mesh by every
         dispatch, which is the transfer this cache exists to avoid."""
         if key is None:
-            arr = jax.device_put(build(), sharding)
+            arr = self._upload(build, sharding, "lane")
             # uncacheable mesh upload: still this statement's volume —
             # the MPP path charges the same TLS tracker seam the cop
             # engine's h2d does, so memory arbitration sees MPP too
@@ -378,7 +396,7 @@ class MPPEngine:
         tid, ver, tag = key[0], key[1], key[2]
         for k in [k for k in self._dev_cache if k[0] == tid and k[2] == tag and k[1] != ver]:
             self._dev_cache_nbytes -= self._dev_cache.pop(k).nbytes
-        arr = jax.device_put(build(), sharding)
+        arr = self._upload(build, sharding, "lane")
         consume_current(arr.nbytes)  # uploader pays (volume proxy, PR 4 rule)
         self._dev_cache[key] = arr
         self._dev_cache_nbytes += arr.nbytes
@@ -1053,7 +1071,39 @@ class MPPEngine:
         join levels + rowpos aggregation. `build_cache` (the store's
         BuildSideCache) keeps LUT structures device-resident across
         statements under (table, span, `schema_ver`, codec-sig) keys;
-        None builds them per dispatch (direct-engine tests)."""
+        None builds them per dispatch (direct-engine tests).
+
+        On the timeline one call is one `mpp.launch` enclosing
+        `mpp.prepare` (host analysis), `mpp.upload` (each cold lane or
+        LUT), `mpp.compile` (first call of a program) or `mpp.dispatch`,
+        `mpp.fetch` (the host blocked until the program has computed and
+        its packed result has crossed) and `mpp.finalize`. The SPMD
+        program spans the whole mesh and no lock serializes dispatches,
+        so the lane is the mesh's, split by calling thread like a
+        resource group's: one thread's spans nest, two threads' may
+        overlap in time and must not share a track."""
+        n_dev = mesh.shape[axis]
+        trace = tracing.current_trace()
+        said = {"outcome": "error", "program": ""}
+        t0 = time.perf_counter_ns()
+        lane = f"mesh:{axis}={n_dev} ({threading.current_thread().name})"
+        with TL.device_scope(lane), TL.launch_scope(tracing._next_id()):
+            try:
+                return self._execute(mplan, scans, mesh, variables, axis, gate,
+                                     fused, build_cache, schema_ver, said)
+            finally:
+                TL.boundary(
+                    "mpp.launch", t0, time.perf_counter_ns(),
+                    mesh=f"{axis}={n_dev}", program=said["program"],
+                    outcome=said["outcome"],
+                    waiters=[trace.trace_id] if trace is not None else [],
+                )
+
+    def _execute(self, mplan, scans, mesh, variables, axis, gate, fused,
+                 build_cache, schema_ver, said: dict):
+        """`execute` inside its launch scope; `said` takes what the
+        `mpp.launch` span says of the run (program digest, outcome)."""
+        t_prep = time.perf_counter_ns()
         # reset per dispatch: a stale reason from a PREVIOUS statement
         # must never leak into this one's enforce_mpp warning / EXPLAIN
         self.last_fallback_reason = ""
@@ -1064,6 +1114,8 @@ class MPPEngine:
         meta = self.prepare(mplan, scans, variables, gate=gate, fused=fused)
         if meta is None:
             self._fallback(self._decline_key)
+            TL.boundary("mpp.prepare", t_prep, time.perf_counter_ns())
+            said["outcome"] = "declined"
             return None
         # fusion outcome accounting: every level fused / some did /
         # fusion found nothing / sysvar off — the per-level decline
@@ -1183,6 +1235,7 @@ class MPPEngine:
             if demote is not None:
                 agm["mode"], agm["rp_ck"] = "rowpos", None
                 agm["clustered_reason"] = demote
+        TL.boundary("mpp.prepare", t_prep, time.perf_counter_ns())
         for s in scans:
             tick()  # each scan's lane build/upload is O(table bytes)
             is_sharded = id(s.frag) in sharded
@@ -1277,8 +1330,8 @@ class MPPEngine:
                    tuple(lvl.lut_stride), lvl.lut_dom)
 
             def build(_lvl=lvl, _soj=soj):
-                arr = jax.device_put(self._build_lut(_lvl, _soj),
-                                     NamedSharding(mesh, P()))
+                arr = self._upload(lambda: self._build_lut(_lvl, _soj),
+                                   NamedSharding(mesh, P()), "lut")
                 # uploader pays (PR 4 volume-proxy rule); cache hits are
                 # free — the statement that built the structure carried it
                 consume_current(arr.nbytes)
@@ -1295,35 +1348,52 @@ class MPPEngine:
 
         tick()
         key = self._program_key(mplan, meta, scans, shapes, n_dev)
+        said["program"] = key[:12]
         prog = self._programs.get(key)
         if prog is None:
-            prog = self._build_program(mplan, meta, scan_arg_meta, mesh, axis,
-                                       n_dev, tuple(in_specs), lut_fids)
+            from ..copr.tpu_engine import _Timed
+
+            # first call = trace + compile (`mpp.compile`, observed into
+            # tidb_tpu_compile_seconds); later calls are `mpp.dispatch`
+            prog = _Timed(self._build_program(mplan, meta, scan_arg_meta, mesh, axis,
+                                              n_dev, tuple(in_specs), lut_fids),
+                          prefix="mpp")
             self._programs[key] = prog
             self.compile_count += 1
         from ..jaxenv import unpack_rows
 
-        packed = np.asarray(prog(*args))
-        tick()
-        outs = unpack_rows(packed)
-        dropped = int(outs[-1][0])
-        outs = outs[:-1]
-        if dropped:
-            # skewed keys overflowed an exchange bucket: the run is
-            # incomplete — never surface it; host path takes over
-            self._fallback("capacity_overflow",
-                           f"exchange bucket overflow ({dropped} rows)")
-            return None
-        # one bump per SUCCESSFUL mesh dispatch (see the outcome block
-        # up top): retried attempts and fallbacks never reach here
-        M.TPU_MPP_FUSED.inc(outcome=outcome)
-        if meta["agg"] is not None:
-            if meta["agg"]["mode"] == "sorted":
-                return self._finalize_topk(mplan, meta, outs), True
-            if meta["agg"]["mode"] in ("rowpos", "clustered"):
-                return self._finalize_rowpos(mplan, meta, scans, outs), True
-            return self._finalize_agg(mplan, meta, outs), True
-        return self._finalize_rows(mplan, meta, scans, outs), meta["agg"] is not None
+        out = prog(*args)
+        # the fetch apart from the call: the dispatch returns at once,
+        # the host then blocks here until the mesh has computed
+        t_fetch = time.perf_counter_ns()
+        packed = np.asarray(out)
+        t_fin = time.perf_counter_ns()
+        TL.boundary("mpp.fetch", t_fetch, t_fin, d2h_bytes=int(packed.nbytes))
+        try:
+            tick()
+            outs = unpack_rows(packed)
+            dropped = int(outs[-1][0])
+            outs = outs[:-1]
+            if dropped:
+                # skewed keys overflowed an exchange bucket: the run is
+                # incomplete — never surface it; host path takes over
+                self._fallback("capacity_overflow",
+                               f"exchange bucket overflow ({dropped} rows)")
+                said["outcome"] = "capacity_overflow"
+                return None
+            # one bump per SUCCESSFUL mesh dispatch (see the outcome block
+            # up top): retried attempts and fallbacks never reach here
+            M.TPU_MPP_FUSED.inc(outcome=outcome)
+            said["outcome"] = "ok"
+            if meta["agg"] is not None:
+                if meta["agg"]["mode"] == "sorted":
+                    return self._finalize_topk(mplan, meta, outs), True
+                if meta["agg"]["mode"] in ("rowpos", "clustered"):
+                    return self._finalize_rowpos(mplan, meta, scans, outs), True
+                return self._finalize_agg(mplan, meta, outs), True
+            return self._finalize_rows(mplan, meta, scans, outs), meta["agg"] is not None
+        finally:
+            TL.boundary("mpp.finalize", t_fin, time.perf_counter_ns())
 
     @staticmethod
     def _build_lut(lvl, scan_of_joined) -> np.ndarray:
@@ -1450,6 +1520,11 @@ class MPPEngine:
 
         drop_acc: list = []  # per-exchange local drop counts (psum'd at end)
 
+        # `jax.named_scope` on the stages below names their ops in the
+        # device trace ("exchange", "join.lut", "group", "topk"): op
+        # metadata only — no cost at run time, no change to the program
+        # or to any cache key. Scopes nest; an op belongs to its innermost
+        @jax.named_scope("exchange")
         def exchange_all(lanemap, mask, rowids, okey):
             """all_to_all every lane, bucketed by owner = okey % n_dev.
 
@@ -1501,6 +1576,7 @@ class MPPEngine:
             mask_out = xc(mask)
             return new_map, mask_out, new_rowids
 
+        @jax.named_scope("join.lut")
         def lut_join(frag, lvl, flat, pmap_, pmask, prow, bmap, bmask, brow):
             """Fused-level probe: pack the probe keys in the BUILD-local
             domain and gather the device-resident LUT — no build sort, no
@@ -1640,6 +1716,7 @@ class MPPEngine:
                 mask = mask & v & (d != 0)
             return merged, mask, rowids
 
+        @jax.named_scope("group")
         def sorted_agg_stage(lanemap, mask):
             """Wide-key device aggregation: lexsort+segment reduce locally,
             hash-exchange complete groups to their owner device, final
@@ -1737,6 +1814,7 @@ class MPPEngine:
                 ukey = jnp.where(valid, sk, I64_MAX)
                 return ukey, arrs, valid
 
+            @jax.named_scope("topk")
             def finish_topk(fkey, fvals, fvalid):
                 # device top-k on the fused ORDER BY aggregate
                 agg_idx, desc, k = agg_meta["topn"]
@@ -1773,6 +1851,7 @@ class MPPEngine:
             fkey, fvals, fvalid = seg_reduce(ukey2, vals2, n_dev)
             return finish_topk(fkey, fvals, fvalid)
 
+        @jax.named_scope("group")
         def rowpos_agg_stage(lanemap, mask, rowids):
             """Fused-chain aggregation by BUILD ROW POSITION (PR 11):
             group keys pin one unique build side, so the build rowid the
@@ -1827,7 +1906,8 @@ class MPPEngine:
             # (n_outs, L) matrix and needs L >= n_outs (extra candidate
             # groups are harmless — the host TopN re-cuts exactly)
             kk = min(max(k, len(full) + 4), blk)
-            _, idx = jax.lax.top_k(score, kk)
+            with jax.named_scope("topk"):
+                _, idx = jax.lax.top_k(score, kk)
             gidx = (didx.astype(jnp.int64) * blk + idx.astype(jnp.int64))
             outs = [jnp.where(valid[idx], gidx, -1), valid[idx]]
             # ship the agg lanes only — a dedicated presence lane (base
@@ -1835,6 +1915,7 @@ class MPPEngine:
             outs.extend(f[idx] for f in full[base:])
             return tuple(outs)
 
+        @jax.named_scope("group")
         def clustered_agg_stage(lanemap, mask, rowids):
             """Clustered fused-chain aggregation (PR 11): the stream
             arrives SORTED by the group level's probe key and shard-split
@@ -1899,7 +1980,8 @@ class MPPEngine:
                 lanes[self._topn_lane_pos(agg.aggs, agg_idx, base)], valid,
                 desc)
             kk = min(max(k, len(lanes) - base + 6), nloc)
-            tvals, ti = self._block_topk(score, kk)
+            with jax.named_scope("topk"):
+                tvals, ti = self._block_topk(score, kk)
             # a shard with fewer than kk scoreable groups exhausts
             # _block_topk: once everything above the floor is taken it
             # returns floor-valued picks whose INDEX can repeat an
@@ -1946,19 +2028,20 @@ class MPPEngine:
             if agg_meta["mode"] == "clustered":
                 return with_drops(clustered_agg_stage(lanemap, mask, rowids))
             # fused partial aggregation + psum (exact int/scaled-decimal)
-            nseg = agg_meta["nseg"]
-            code = jnp.zeros(mask.shape, dtype=jnp.int32)
-            for g, dom, km in zip(agg.group_by, agg_meta["domains"], agg_meta["key_meta"]):
-                d, v = lanemap[g.idx]
-                lo = km[1] if km[0] == "int" else 0
-                kd = (d.astype(jnp.int32) - lo + 1) * v
-                code = code * (dom + 1) + kd
-            seg = jnp.where(mask, code, nseg)
-            outs = [(jax.ops.segment_sum(mask.astype(jnp.int64), seg, num_segments=nseg + 1)[:nseg], "sum")]
-            for a, ra in zip(agg.aggs, agg_meta["r_args"]):
-                outs.extend(self._agg_partials(a, ra, lanemap, mask, seg, nseg, eval_dev))
-            red = {"sum": jax.lax.psum, "min": jax.lax.pmin, "max": jax.lax.pmax}
-            return with_drops([red[op](o, axis) for o, op in outs])
+            with jax.named_scope("group"):
+                nseg = agg_meta["nseg"]
+                code = jnp.zeros(mask.shape, dtype=jnp.int32)
+                for g, dom, km in zip(agg.group_by, agg_meta["domains"], agg_meta["key_meta"]):
+                    d, v = lanemap[g.idx]
+                    lo = km[1] if km[0] == "int" else 0
+                    kd = (d.astype(jnp.int32) - lo + 1) * v
+                    code = code * (dom + 1) + kd
+                seg = jnp.where(mask, code, nseg)
+                outs = [(jax.ops.segment_sum(mask.astype(jnp.int64), seg, num_segments=nseg + 1)[:nseg], "sum")]
+                for a, ra in zip(agg.aggs, agg_meta["r_args"]):
+                    outs.extend(self._agg_partials(a, ra, lanemap, mask, seg, nseg, eval_dev))
+                red = {"sum": jax.lax.psum, "min": jax.lax.pmin, "max": jax.lax.pmax}
+                return with_drops([red[op](o, axis) for o, op in outs])
 
         if agg is not None and agg_meta["mode"] == "dense":
             out_specs = P()  # psum'd: replicated (nout, nseg)

@@ -45,7 +45,7 @@ log = logging.getLogger("tidb_tpu.sched")
 
 class _Job:
     __slots__ = ("dag", "batch", "dedup_key", "result", "exc", "followers", "mode",
-                 "trace", "parent_id", "client", "mem")
+                 "trace", "parent_id", "client", "mem", "t_enq_ns")
 
     def __init__(self, dag, batch, dedup_key, client=None):
         self.dag = dag
@@ -68,6 +68,9 @@ class _Job:
         # the per-job serial fallback rebinds it so one statement's
         # quota/server-limit error can never poison co-batched neighbors
         self.mem = memory.current_tracker()
+        # when the task joined (or opened) its launch group: the launch's
+        # `queued_ns` counts from the oldest job's
+        self.t_enq_ns = time.perf_counter_ns()
 
 
 class _Group:
@@ -136,7 +139,6 @@ class LaunchBatcher:
         # on one device, so only same-device (and same-program) tasks fuse
         ckey = (id(engine), lane.idx, dag.digest(), tiles)
         job = _Job(dag, batch, dedup_key, client=client)
-        t_enq = time.perf_counter_ns()
         with self._lock:
             g = self._pending.get(ckey)
             if g is not None and not g.closed:
@@ -156,7 +158,7 @@ class LaunchBatcher:
                 group.jobs.append(job)
                 self._pending[ckey] = group
 
-        TL.group_event("launch.enqueue", "launch", t_enq, t_enq, mode=job.mode,
+        TL.group_event("launch.enqueue", "launch", job.t_enq_ns, job.t_enq_ns, mode=job.mode,
                        trace=job.trace.trace_id if job.trace is not None else None)
         if job.mode == "leader":
             time.sleep(self.WINDOW_S)
@@ -164,7 +166,7 @@ class LaunchBatcher:
                 group.closed = True
                 if self._pending.get(ckey) is group:
                     del self._pending[ckey]
-            TL.group_event("launch.leader_elected", "launch", t_enq,
+            TL.group_event("launch.leader_elected", "launch", job.t_enq_ns,
                            time.perf_counter_ns(),
                            jobs=len(group.jobs), n_dedup=group.n_dedup,
                            device=lane.name)
@@ -189,25 +191,37 @@ class LaunchBatcher:
             # direct callers (tests) without a pre-placed lane
             lane = placed = engine.place(group.jobs[0].batch)
         try:
+            # read before the lock is asked for: the wait for the lane is
+            # a number on the launch (`lane_lock_ns`), not a span — it
+            # encloses the OTHER group's launch and would explain no gap
+            t_ask_ns = time.perf_counter_ns()
+            # one launch identity shared by the timeline events (every
+            # boundary booked inside the scope carries it) and the trace
+            # span fanned into every waiter (same id space as span ids)
+            launch_id = tracing._next_id()
             if lane is not None:
                 # the lane's launch lock serializes device work per device
                 # and keeps its timeline tid free of partial overlap; the
                 # device_scope binding lands every engine-boundary event
                 # recorded below on the REAL device lane
-                with lane.lock, TL.device_scope(lane.name):
-                    self._launch_on(engine, group, stats, lane)
+                with lane.lock, TL.device_scope(lane.name), TL.launch_scope(launch_id):
+                    self._launch_on(engine, group, stats, lane, t_ask_ns, launch_id)
             else:
-                self._launch_on(engine, group, stats, lane)
+                with TL.launch_scope(launch_id):
+                    self._launch_on(engine, group, stats, lane, t_ask_ns, launch_id)
         finally:
             if placed is not None:
                 engine.release_lane(placed)
 
-    def _launch_on(self, engine, group: _Group, stats, lane) -> None:
+    def _launch_on(self, engine, group: _Group, stats, lane,
+                   t_ask_ns: int, launch_id: int) -> None:
         jobs = group.jobs
         t0_ns = time.perf_counter_ns()
-        # one launch identity shared by the timeline event and the trace
-        # span fanned into every waiter (same id space as span ids)
-        launch_id = tracing._next_id()
+        # the launch's waits, as numbers: for the lane lock (asked for at
+        # `t_ask_ns`), and in all since the oldest job joined the group
+        # (collection window + lane lock); queued_ns >= lane_lock_ns >= 0
+        lane_lock_ns = t0_ns - t_ask_ns
+        queued_ns = max(t0_ns - min(j.t_enq_ns for j in jobs), lane_lock_ns)
         # the group's shared uploads belong to NO statement (a neighbor's
         # bytes must not draw the leader's quota verdict) but the SERVER
         # arbiter must still see the volume: a detachable, quota-less
@@ -283,7 +297,8 @@ class LaunchBatcher:
                         f.result, f.exc = j.result, j.exc
             try:
                 self._attribute(jobs, group, t0_ns, phases, launch_id=launch_id,
-                                lane=lane)
+                                lane=lane, queued_ns=queued_ns,
+                                lane_lock_ns=lane_lock_ns)
             except Exception:  # noqa: BLE001 — attribution must never strand waiters
                 log.warning("launch-span fan-out attribution failed", exc_info=True)
             group.done.set()
@@ -301,7 +316,8 @@ class LaunchBatcher:
         return engine.execute(dag, batch)
 
     def _attribute(self, jobs, group: _Group, t0_ns: int, phases: dict,
-                   launch_id: int | None = None, lane=None) -> None:
+                   launch_id: int | None = None, lane=None,
+                   queued_ns: int = 0, lane_lock_ns: int = 0) -> None:
         """Fan the ONE launch out into every co-batched waiter's trace:
         each participant (members, dedup followers, the leader itself)
         gets the SAME launch span — identical launch/span id, occupancy,
@@ -331,15 +347,14 @@ class LaunchBatcher:
             M.TPU_LANE_LAUNCHES.inc(
                 device=lane.name, mode="grouped" if occupancy > 1 else "solo"
             )
-        tl = TL.active()
-        if tl is not None:
-            tl.device_event(
-                "cop.launch", "launch", t0_ns, t0_ns + dur_ns,
-                launch_id=launch_id, occupancy=occupancy, n_dedup=group.n_dedup,
-                shared_h2d_bytes=shared_h2d,
-                device=lane.name if lane is not None else "",
-                waiters=[w.trace.trace_id for w in waiters if w.trace is not None],
-            )
+        TL.boundary(
+            "cop.launch", t0_ns, t0_ns + dur_ns,
+            launch_id=launch_id, occupancy=occupancy, n_dedup=group.n_dedup,
+            shared_h2d_bytes=shared_h2d,
+            device=lane.name if lane is not None else "",
+            queued_ns=queued_ns, lane_lock_ns=lane_lock_ns,
+            waiters=[w.trace.trace_id for w in waiters if w.trace is not None],
+        )
         # store-level stats fan-out (PR 3 debt): a co-batched launch's
         # compile/transfer/execute counters land in EVERY participating
         # client's `cop.stats` — once per client per launch — so EXPLAIN

@@ -89,6 +89,7 @@ class Session:
         self._last_txn_info = ""  # @@tidb_last_txn_info (JSON)
         self._last_query_info = ""  # @@tidb_last_query_info (JSON)
         self._last_plan_from_cache = False
+        self._parse_ns = 0  # the statement text's parse, for `stmt.plan` (0: parse-cache hit)
         self._last_plan_from_binding = False
         self._prev_plan_from_cache = False
         self._prev_plan_from_binding = False
@@ -406,10 +407,15 @@ class Session:
         cached = self._ast_cache.get(sql)
         if cached is not None:
             self._ast_cache.move_to_end(sql)
+            self._parse_ns = 0
             return self._execute_parsed(cached, sql)
         from ..parser.parser import parse
 
+        t_parse = time.perf_counter_ns()
         stmts = parse(sql)
+        # the parse runs before the statement's wall opens: `stmt.plan`
+        # carries it as a number (`parse_ns`), 0 on a parse-cache hit
+        self._parse_ns = time.perf_counter_ns() - t_parse
         if len(stmts) == 1 and len(sql) <= self.AST_CACHE_MAX_SQL:
             self._ast_cache[sql] = stmts[0]
             while len(self._ast_cache) > self.AST_CACHE_SIZE:
@@ -1827,7 +1833,27 @@ class Session:
         plan._uncacheable = builder.used_eager_subquery
         return plan
 
+    def _note_plan_span(self, t0_ns: int) -> None:
+        """`stmt.plan` on the statement's resource-group lane: plan (cache
+        lookup or build + optimize) and executor build of a top-level
+        SELECT, from `t0_ns` to now, nested inside the `statement` wall
+        the same thread records at its end."""
+        tl = self.store.timeline
+        tracer = self._tracer
+        if not tl.enabled or tracer is None:
+            return
+        from ..utils.timeline import PID_GROUPS, group_lane
+
+        tl.record(
+            "stmt.plan", "statement", t0_ns, time.perf_counter_ns(),
+            pid=PID_GROUPS,
+            lane=group_lane(self.vars.get("tidb_resource_group", "default") or "default"),
+            trace_id=tracer.trace_id, parse_ns=self._parse_ns,
+            plan_from_cache=bool(self._last_plan_from_cache),
+        )
+
     def run_select(self, stmt, sql: str | None = None, top_level: bool = False) -> ResultSet:
+        t_plan_ns = time.perf_counter_ns()
         prev_hints = getattr(self, "_cur_hints", None)
         hints = self._effective_hints(stmt, sql)
         self._cur_hints = hints
@@ -1950,6 +1976,8 @@ class Session:
 
                 plan = _LimitPlan(plan, sel_limit)
             ex = build_executor(plan, ctx)
+            if top_level:
+                self._note_plan_span(t_plan_ns)
             if getattr(self, "_trace_collect", False):
                 # TRACE hook: instrument THIS (fully gated) execution rather
                 # than re-running the select outside the normal path

@@ -379,9 +379,24 @@ SERVER_MEM_ACTIONS = REGISTRY.counter(
 # device-path series (ref: "Query Processing on Tensor Computation
 # Runtimes" names compile-cache behavior and host↔device transfer as the
 # dominant hidden costs — these make them first-class)
+# chip compiles take 6 to 410 s (sort-bearing programs, PR 22): the
+# default buckets top out at 30 s and would file them all under +Inf
+_COMPILE_BUCKETS = (0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 15.0, 30.0, 60.0, 120.0, 300.0, 600.0)
 TPU_COMPILE_SECONDS = REGISTRY.histogram(
     "tidb_tpu_compile_seconds",
-    "XLA program trace+compile wall time (first dispatch of a new program key)",
+    "XLA program trace+compile wall time (first dispatch of a new program key, "
+    "cop and MPP engines)",
+    buckets=_COMPILE_BUCKETS,
+)
+# host seconds of building a table's tiles, by stage: gather (segments →
+# host columns, TileCache.get_batch miss), encode (codec choice + encode
+# of one lane, DeviceBatch.lanes) and upload (each device.h2d). Stages
+# open on several threads at once share the wall (utils/timeline
+# _WallShare): the sums add up to the time some thread was building
+TPU_TILE_BUILD_SECONDS = REGISTRY.histogram(
+    "tidb_tpu_tile_build_seconds",
+    "tile build wall time by stage (gather | encode | upload)",
+    buckets=(0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0, 120.0),
 )
 TPU_COMPILE_CACHE = REGISTRY.counter(
     "tidb_tpu_compile_cache_total", "device program-cache lookups by result"

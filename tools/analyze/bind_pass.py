@@ -1,7 +1,7 @@
 """tls-bind: the three thread-local bind seams must be unwind-safe.
 
 `tracing.activate` / `memory.bind` / `timeline.bind` (+ `device_scope`,
-`collect_phases`) install thread-local state the cop pool and batcher
+`launch_scope`, `collect_phases`) install thread-local state the cop pool and batcher
 threads read; a bind left installed past its task poisons whatever runs
 on that pool thread next (wrong statement's tracker charged, wrong
 trace's spans). PR 4/5 review rounds each caught one of these by hand.
@@ -36,6 +36,8 @@ _SEAMS = {
     ("timeline", "bind"),
     ("TL", "device_scope"),
     ("timeline", "device_scope"),
+    ("TL", "launch_scope"),
+    ("timeline", "launch_scope"),
     ("tracing", "collect_phases"),
 }
 

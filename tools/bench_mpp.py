@@ -2,7 +2,7 @@
 
 Three paired comparisons per scale (tools/paired_bench.paired_medians,
 the noisy-box methodology: modes interleave per rep, medians of PAIRED
-samples — see bench_trace_overhead.py for why raw medians lie on a
+samples — see paired_bench.py for why raw medians lie on a
 shared box):
 
   device-vs-host     fused mesh dispatch vs the host hash-join engine

@@ -1,6 +1,6 @@
 """Watchdog-overhead gate (ISSUE 4 acceptance): the paired off/on
-statement bench (tools/paired_bench.py — the same drift-cancelling
-methodology as bench_trace_overhead.py) with the protection layer
+statement bench (tools/paired_bench.py, the drift-cancelling
+methodology) with the protection layer
 DISARMED (default group, no QUERY_LIMIT, no server memory limit) vs
 ARMED-but-idle (a resource group whose QUERY_LIMIT thresholds are sky
 high, plus a huge tidb_server_memory_limit — the watchdog ticks and the

@@ -1,7 +1,7 @@
 """Shared paired off/on statement-bench harness.
 
-Both overhead gates (tools/bench_trace_overhead.py, PR 3;
-tools/bench_watchdog_overhead.py, PR 4) measure the same way: the
+The overhead gates (tools/bench_watchdog_overhead.py, PR 4;
+tools/bench_trace_propagation.py, PR 18) measure the same way: the
 bench_sched point-agg workload run as full statements, modes interleaved
 per STATEMENT (off/on back-to-back, order alternating) with rep 0 of
 each mode as warmup, gated on the median PAIRED delta — on a shared box

@@ -10,9 +10,7 @@
 #   bash tools/t1.sh --bench
 # additionally runs the overhead gates (paired off/on p50, ≤5%) and the
 # compressed-tile gate (paired dense/compressed speedup + wire bytes):
-#   tools/bench_trace_overhead.py    -> BENCH_trace_pr3.json
 #   tools/bench_watchdog_overhead.py -> BENCH_watchdog_pr4.json
-#   tools/bench_timeline_overhead.py -> BENCH_timeline_pr5.json
 #   tools/bench_tiles.py             -> BENCH_tiles_pr7.json
 #   tools/bench_mpp.py               -> BENCH_mpp_pr11.json
 #   tools/bench_serve.py             -> BENCH_serve_pr13.json
@@ -57,7 +55,7 @@ python -m tools.analyze $ANALYZE_ARGS || exit 1
 # `pytest -m slow` / crashpoint.py --rounds/--failover-rounds
 env JAX_PLATFORMS=cpu python tools/crashpoint.py --matrix --failover-rounds 1 --seed 7 || exit 1
 if [ "$RUN_BENCH" = "1" ]; then
-  for b in bench_trace_overhead bench_watchdog_overhead bench_timeline_overhead bench_tiles bench_mpp bench_serve bench_ingest bench_compact bench_trace_propagation bench_route; do
+  for b in bench_watchdog_overhead bench_tiles bench_mpp bench_serve bench_ingest bench_compact bench_trace_propagation bench_route; do
     env JAX_PLATFORMS=cpu python "tools/$b.py" || exit 1
   done
 fi
