@@ -79,6 +79,23 @@ def _null_patterns(n, rng):
         yield "pow2_runs_end_true", p8
 
 
+DAY_US = 86_400_000_000  # a DATE lane holds ((y*13+m)*32+d) days of microseconds
+
+
+def _packed_dates(n, rng):
+    """Packed core-time DATE values over seven years, as a DATE lane holds them."""
+    y, m, d = rng.integers(1992, 1999, n), rng.integers(1, 13, n), rng.integers(1, 29, n)
+    return ((y * 13 + m) * 32 + d) * DAY_US
+
+
+def _odd_rows_off_stride(n, rng):
+    """Multiples of 100 on the even rows, one past a multiple on the odd:
+    strided only where a mask (`_null_patterns`' "alternating") hides the odd rows."""
+    d = rng.integers(1, 51, n) * 100
+    d[1::2] += 1
+    return d
+
+
 def _lanes(n, rng):
     """(name, lane) pairs covering every codec's target shape and the
     shapes that must STAY dense."""
@@ -87,7 +104,16 @@ def _lanes(n, rng):
     yield "wide_int", rng.integers(-(1 << 62), 1 << 62, n).astype(np.int64)  # dense
     yield "low_ndv_wide", rng.choice(
         np.asarray([0, 1 << 40, -(1 << 50), 7], np.int64), n
-    )  # dict (span too wide to pack, 4 distinct values)
+    )  # dict (coprime differences: no stride, span too wide to pack)
+    yield "date_strided", _packed_dates(n, rng)  # pack u2, stride one day
+    yield "decimal_whole", rng.integers(1, 51, n) * 100  # pack u1, stride 100
+    yield "neg_base_strided", -5000 + 7 * rng.integers(0, 200, n)  # pack u1, base < 0
+    yield "two_valued_wide", rng.choice(
+        np.asarray([0, 1 << 40], np.int64), n
+    )  # pack u1, stride 2^40
+    yield "uint64_strided", (rng.integers(0, 60000, n).astype(np.uint64)
+                             * np.uint64(1000) + np.uint64((1 << 63) + 5))
+    yield "nulls_break_stride", _odd_rows_off_stride(n, rng)
     yield "sorted_runs", np.repeat(
         np.arange(n // 50 + 1, dtype=np.int64), 50
     )[:n]  # rle
@@ -146,10 +172,31 @@ class TestCodecRoundtrip:
         assert sig[0] == "pack" and sig[1] == "|u1"
         _, sig = encode_data_lane(np.full(n, 7, np.int64), np.ones(n, bool), shape)
         assert sig[0] == "rle"
-        _, sig = encode_data_lane(
+        # an integer lane with an arithmetic code never takes dict: two
+        # wide values are base + {0, 1} x stride
+        pay, sig = encode_data_lane(
             rng.choice(np.asarray([0, 1 << 40], np.int64), n), np.ones(n, bool), shape
         )
+        assert sig[:2] == ("pack", "|u1") and int(pay["g"]) == 1 << 40
+        pay, sig = encode_data_lane(_packed_dates(n, rng), np.ones(n, bool), shape)
+        assert sig[:2] == ("pack", "<u2") and int(pay["g"]) == DAY_US
+        # DECIMAL whole numbers: dict's one byte a row, without its gather
+        pay, sig = encode_data_lane(rng.integers(1, 51, n) * 100, np.ones(n, bool), shape)
+        assert sig[:2] == ("pack", "|u1") and int(pay["g"]) == 100
+        # the stride is read off the VALID rows alone
+        pay, sig = encode_data_lane(_odd_rows_off_stride(n, rng), np.arange(n) % 2 == 0, shape)
+        assert sig[:2] == ("pack", "|u1") and int(pay["g"]) == 100
+        # coprime differences past 2^32: no arithmetic code, dict stays
+        _, sig = encode_data_lane(
+            rng.choice(np.asarray([0, (1 << 40) + 1, (1 << 41) + 3], np.int64), n),
+            np.ones(n, bool), shape,
+        )
         assert sig[0] == "dict"
+        _, sig = encode_data_lane(
+            rng.choice(np.asarray([0.5, -3.25, 1e300, 2.0], np.float64), n),
+            np.ones(n, bool), shape,
+        )
+        assert sig[0] == "dict"  # floats of few values have no arithmetic code
         _, sig = encode_data_lane(
             rng.integers(-(1 << 62), 1 << 62, n).astype(np.int64),
             np.ones(n, bool), shape,
@@ -175,6 +222,80 @@ class TestCodecRoundtrip:
         )
         _, sig = encode_data_lane(wide, sv, (1, 65536))
         assert sig[0] in ("rle", "dict"), sig
+
+    def test_one_odd_value_forces_unit_stride(self):
+        """The gcd runs over the whole lane, never a sample: one value in
+        100,000 off the stride leaves g = 1 and the plain u2 code."""
+        n = 100_000
+        d = np.random.default_rng(5).integers(1, 51, n) * 100
+        d[77_777] += 1
+        shape = (2, 65536)
+        pay, sig = encode_data_lane(d, np.ones(n, bool), shape)
+        assert sig[:2] == ("pack", "<u2") and int(pay["g"]) == 1
+        dense = np.zeros(shape, d.dtype)
+        got = _decode_host(pay, sig, shape, dense, n)
+        assert np.array_equal(got.reshape(-1)[:n], d)
+
+
+@pytest.fixture(scope="class")
+def lineitem_mirror():
+    """This benchmark configuration's LINEITEM (its DDL, its generator), at
+    a small size, with every column's lane built on the device mirror."""
+    import json
+    import os
+
+    from benchmark.generators import tpch as gen
+    from tidb_tpu.models import tpch
+
+    root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+    with open(os.path.join(root, "configs", "tpch_lineitem_16m.json")) as f:
+        table = json.load(f)["tables"][0]
+    with open(os.path.join(root, "traffic", "scan_streams_2.json")) as f:
+        mix = json.load(f)
+    n = 20_000
+    s = Session()
+    s.execute(table["ddl"])
+    tpch.bulk_load(s, "lineitem", gen.lineitem(n, 1000000007, lineitem=n))
+    s.vars["tidb_cop_engine"] = "tpu"
+    s.vars["tidb_enable_cop_result_cache"] = "OFF"
+    # the TopN text uploads every column of the scan (PERF.md section 5)
+    s.must_query(mix["templates"]["topn"]["sql"].format(date="1993-01-01", limit="5"))
+    batch = next(iter(s.cop.tiles._cache.values()))
+    mirror = next(iter(batch._mirrors.values()))
+    offs = {c.name: i for i, c in enumerate(batch.table.columns) if i in mirror.lane_sigs}
+    return mirror, offs, mix
+
+
+class TestBenchmarkLineitemLanes:
+    def test_no_lane_takes_dict_and_codes_stay_narrow(self, lineitem_mirror):
+        mirror, offs, _ = lineitem_mirror
+        sigs = {name: mirror.lane_sigs[off][0] for name, off in offs.items()}
+        assert len(sigs) == 16
+        assert not [n for n, sg in sigs.items() if sg[0] == "dict"], sigs
+        for name in ("l_shipdate", "l_commitdate", "l_receiptdate"):
+            assert sigs[name][:2] == ("pack", "<u2"), sigs
+            assert int(mirror._data[offs[name]]["g"]) == DAY_US
+        assert sigs["l_quantity"][:2] == ("pack", "|u1"), sigs
+        assert int(mirror._data[offs["l_quantity"]]["g"]) == 100
+
+    def test_decode_traces_without_a_gather(self, lineitem_mirror):
+        """Every lane but the run-length ones (whose decode, jnp.repeat, is
+        not this codec's business) becomes values with no gather; the lanes
+        Q6 reads and the date and quantity lanes are all among them."""
+        mirror, offs, mix = lineitem_mirror
+        order = sorted(off for off in offs.values()
+                       if mirror.lane_sigs[off][0][0] != "rle")
+        names = {n for n, off in offs.items() if off in order}
+        assert names >= set(mix["templates"]["q6"]["reads"]["lineitem"]), names
+        assert names >= {"l_shipdate", "l_commitdate", "l_receiptdate", "l_quantity"}
+        flat = []
+        for off in order:
+            flat += [mirror._data[off], mirror._valid[off]]
+        jaxpr = jax.make_jaxpr(
+            lambda fl, rv: tpu_engine.TPUEngine._unflatten(fl, order, rv)
+        )(flat, mirror.row_valid)
+        assert "gather" not in str(jaxpr)
+        assert "mul" in str(jaxpr)  # the stride's one multiply is there
 
 
 # --- end-to-end SQL bit-identity -------------------------------------------

@@ -58,13 +58,23 @@ class ColumnBatch:
 # compressed form ("GPU Acceleration of SQL Analytics on Compressed Data",
 # arXiv:2506.10092 — decompress-in-kernel beats transfer-then-process):
 #
-#   pack   frame-of-reference downcast for narrow-range int lanes: upload
-#          (d - lo) as uint8/16/32 plus a 0-d base scalar in the ORIGINAL
-#          dtype; decode is one add (bit-exact, ints only)
-#   dict   sorted-unique values + narrow codes for low-NDV lanes (ints AND
-#          floats — skipped when the lane holds NaN, which breaks
-#          searchsorted, or a negative zero, which np.unique would
-#          bit-merge with +0.0); decode is one gather
+#   pack   strided frame of reference for int lanes: over the valid rows,
+#          lo = min and g = gcd of (d - lo) (1 when that is 0 or 1; taken
+#          over the whole lane, never a sample); upload (d - lo) // g as
+#          uint8/16/32 plus the base lo and the stride g as 0-d scalars in
+#          the ORIGINAL dtype (payload leaves, so neither enters a program
+#          or fuse key); decode is one multiply-add, p * g + lo, bit-exact
+#          because p * g <= hi - lo. DATE lanes (multiples of one day of
+#          microseconds) and whole-number DECIMALs code at the width a
+#          dictionary would give. A lane whose hi - lo does not fit its
+#          own dtype takes no pack
+#   dict   sorted-unique values + narrow codes for low-NDV lanes that
+#          have NO arithmetic code: floats (skipped when the lane holds
+#          NaN, which breaks searchsorted, or a negative zero, which
+#          np.unique would bit-merge with +0.0) and ints whose strided
+#          span is still past 2^32. Decode is one gather, which the chip
+#          prices at thousands of HBM bytes an element (PERF.md, PR 27):
+#          an int lane that can pack never takes dict, whatever the bytes
 #   rle    run-length (vals, lens) for sorted/clustered/constant lanes and
 #          few-run validity masks; decode is jnp.repeat with a static
 #          total_repeat_length (pad tail rows are don't-care: every
@@ -164,8 +174,10 @@ def encode_data_lane(d: np.ndarray, v: np.ndarray, shape: tuple[int, int]):
     padded = shape[0] * shape[1]
     item = d.dtype.itemsize
     dense_bytes = padded * item
-    dz = np.where(v, d, np.zeros((), d.dtype)) if not v.all() else d
-    any_valid = bool(v.any())
+    all_valid = bool(v.all())
+    dz = d if all_valid else np.where(v, d, np.zeros((), d.dtype))
+    any_valid = all_valid or bool(v.any())
+    pres = dz if all_valid else dz[v]  # the valid rows: what pack and dict look at
     is_int = np.issubdtype(d.dtype, np.integer)
 
     # a float lane holding negative zero stays dense/pack-free of value
@@ -184,21 +196,32 @@ def encode_data_lane(d: np.ndarray, v: np.ndarray, shape: tuple[int, int]):
         if rle_bytes < best[0]:
             best = (rle_bytes, "rle", (rvals, rlens, np_len))
 
-    lo = hi = None
+    # pack — base + code x stride over the VALID rows. The gcd runs over
+    # the whole lane (never a sample), in the lane's own dtype: a span
+    # that does not fit it would wrap the differences, and such a lane
+    # has no narrower code anyway
+    arith = False
     if any_valid and is_int:
-        lo, hi = dz[v].min(), dz[v].max()
-        cdt = _code_dtype(int(hi) - int(lo))
-        if cdt is not None and cdt().itemsize < item:
-            pack_bytes = padded * cdt().itemsize + item
-            if pack_bytes < best[0]:
-                best = (pack_bytes, "pack", (lo, cdt))
+        lo = pres.min()
+        span = int(pres.max()) - int(lo)
+        if span <= np.iinfo(d.dtype).max:
+            g = max(int(np.gcd.reduce(pres - lo)), 1)
+            cdt = _code_dtype(span // g)
+            arith = cdt is not None and cdt().itemsize < item
+            if arith:
+                pack_bytes = padded * cdt().itemsize + 2 * item
+                if pack_bytes < best[0]:
+                    best = (pack_bytes, "pack", (lo, g, cdt))
 
-    if any_valid and not has_negzero:
-        # dict — sample NDV first so np.unique never runs on a lane that
+    # dict — only for lanes with no arithmetic code (floats of few values,
+    # ints whose strided span is still past 2^32): its decode is a gather,
+    # which the chip prices at thousands of HBM bytes an element, so no
+    # byte count pays for it where one multiply-add would do
+    if any_valid and not has_negzero and not arith:
+        # sample NDV first so np.unique never runs on a lane that
         # obviously won't dictionary-compress; the stride comes from the
         # VALID subset being sampled (a sparse-valid lane would otherwise
         # be under-sampled into a spuriously high NDV estimate)
-        pres = dz[v]
         sample = pres[:: max(1, len(pres) // 4096)][:4096]
         if len(np.unique(sample)) <= min(DICT_MAX_NDV, max(len(sample) // 2, 1)):
             if is_int or not np.isnan(pres).any():
@@ -222,11 +245,13 @@ def encode_data_lane(d: np.ndarray, v: np.ndarray, shape: tuple[int, int]):
             ("rle", np_len, d.dtype.str),
         )
     if kind == "pack":
-        lo, cdt = best[2]
-        packed = (dz.astype(np.int64) - int(lo)).astype(cdt) if d.dtype.kind == "i" \
-            else (dz - lo).astype(cdt)
+        lo, g, cdt = best[2]
+        q = dz - lo  # invalid rows may wrap: don't-care under their valid bit
+        if g > 1:
+            q //= np.asarray(g, d.dtype)
         return (
-            {"p": _pad2d(packed, shape), "b": np.asarray(lo, dtype=d.dtype)},
+            {"p": _pad2d(q.astype(cdt), shape), "b": np.asarray(lo, dtype=d.dtype),
+             "g": np.asarray(g, dtype=d.dtype)},
             ("pack", np.dtype(cdt).str, d.dtype.str),
         )
     uniq, vp, cdt = best[2]

@@ -374,6 +374,8 @@ class DeviceBatch:
                         pay_d = pay_v = None
                         sig_d, sig_v = ("dense",), ("dense",)
                     enc.args["codec"] = sig_d[0]
+                    if sig_d[0] == "pack":
+                        enc.args["stride"] = int(pay_d["g"])
             logical = self.padded * (d.dtype.itemsize + 1)  # dense data+valid
             self._data[off] = (
                 _to_device(self._pad2d(d), self.device) if pay_d is None
@@ -1078,7 +1080,7 @@ class TPUEngine:
         are a prefix of the flattened order, so only padding drops — while
         rle payloads pass through untouched (their decode reads the
         narrowed row_valid shape and truncates to it). Aux leaves (pack
-        base, dict vocab) are positionless and keep their shape."""
+        base and stride, dict vocab) are positionless and keep their shape."""
         flat, rv = args
 
         def cut2d(a):
@@ -1180,19 +1182,22 @@ class TPUEngine:
         passes through; a codec payload (tilecache encode half) expands to
         the dense [T, R] lane INSIDE the jitted program, so XLA fuses
         decode+compute and the wire/h2d form stays the compressed form
-        (arXiv:2506.10092's decompress-in-kernel). `row_valid` supplies
-        the target static shape — the (possibly group-narrowed) one — and
-        doubles as the value of zero-byte all-valid aliases."""
+        (arXiv:2506.10092's decompress-in-kernel). `pack` is one
+        multiply-add, strided or not (stride 1), and elementwise, so it
+        fuses into its consumer; `dict` is a gather and is left to lanes
+        with no arithmetic code (tilecache's codec table). `row_valid`
+        supplies the target static shape — the (possibly group-narrowed)
+        one — and doubles as the value of zero-byte all-valid aliases."""
         if not isinstance(enc, dict):
             return enc
         if not enc:  # all-valid alias: the mask IS row_valid, for free
             return row_valid
         # `decode.<codec>` names the ops in the device trace: op metadata
         # only, it changes neither the program nor any cache key
-        if "p" in enc:  # pack: frame-of-reference sub-word + base scalar
+        if "p" in enc:  # pack: base + code x stride, exact (p*g <= hi-lo)
             with jax.named_scope("decode.pack"):
-                return enc["p"].astype(enc["b"].dtype) + enc["b"]
-        if "c" in enc:  # dict: sorted vocab gather
+                return enc["p"].astype(enc["b"].dtype) * enc["g"] + enc["b"]
+        if "c" in enc:  # dict: sorted vocab gather (lanes with no pack code)
             with jax.named_scope("decode.dict"):
                 return enc["v"][enc["c"]]
         # rle: static-length expand; total_repeat_length truncates to the
