@@ -12,10 +12,17 @@ derived from those dates against CURRENTDATE, `l_extendedprice` =
 cut from a pool of the grammar's text (4.2.2.10) as dbgen cuts them.
 What differs from dbgen is its random stream: the draws come from numpy,
 so a seed gives other rows than dbgen's fixed seeds, of the same
-distributions. The lineitem count of every order is a permutation (from
-the seed) of one fixed multiset, uniform over 1..7, so every seed has
-exactly the configuration's row count: the program's compiled shapes hold
-the row count, and a seed that changed it would be a cold run.
+distributions. The lineitem count of every order is a permutation of one
+fixed multiset, uniform over 1..7, and the permutation is drawn from the
+sizes, NOT from the seed: every seed has exactly the configuration's row
+count and the same orders of the same sizes at the same rows, so that
+`l_orderkey` (a function of the order's number) is the same lane for
+every seed. The program's compiled shapes hold the row count, and its
+program keys hold each lane's codec: a 2^21-row region holds 2^19 +- 400
+orders, `l_orderkey`'s run-length code pads to 2^19 or 2^20 runs on
+either side of that, and a seed that moved a region across it changed
+the programs compiled and the work of every TopN (PERF.md, section 6,
+PR 29). Every value of every other column still comes from the seed.
 
 A configuration names a generator per table as `<module>.<function>`
 (`configs/*.json`, `tables[].generator`); a new family of tables is a new
@@ -178,16 +185,17 @@ def numbered(prefix: bytes, numbers: np.ndarray, digits: int = 9) -> np.ndarray:
 # ---------------------------------------------------------------- the tables
 
 
-def line_counts(n_orders: int, n_lineitem: int, rng) -> np.ndarray:
+def line_counts(n_orders: int, n_lineitem: int) -> np.ndarray:
     """Lineitems of each order: 1..7 in turn, the few steps to the exact
-    total spread over the first orders, then permuted by the seed."""
+    total spread over the first orders, then permuted: the same
+    permutation for every seed (see the module's text)."""
     counts = np.arange(n_orders, dtype=np.int64) % 7 + 1
     diff = n_lineitem - int(counts.sum())
     room = np.flatnonzero(counts < 7) if diff > 0 else np.flatnonzero(counts > 1)
     if abs(diff) > len(room):
         raise ValueError(f"{n_lineitem} lineitems cannot be spread over {n_orders} orders at 1..7 each")
     counts[room[:abs(diff)]] += 1 if diff > 0 else -1
-    return rng.permutation(counts)
+    return np.random.default_rng([n_orders, n_lineitem, 0x7C9]).permutation(counts)
 
 
 _made: dict = {}  # (seed, sizes) -> the tables of the last seed asked for
@@ -210,7 +218,7 @@ def _tables(seed: int, n_lineitem: int, n_orders: int, n_customer: int) -> dict:
     o_day = rng.integers(0, LAST_ORDER_DAY + 1, n_orders)
 
     # LINEITEM
-    counts = line_counts(n_orders, n_lineitem, rng)
+    counts = line_counts(n_orders, n_lineitem)
     first = np.concatenate(([0], np.cumsum(counts)[:-1]))
     of_order = np.repeat(i, counts)
     partkey = rng.integers(1, n_part + 1, n_lineitem)

@@ -28,16 +28,20 @@ TIMELINE_CAPACITY = 1 << 20  # the sysvar's own upper limit
 TRACE_START_SHARE = 0.3  # the profiled interval starts this far into the window
 TRACE_MAX_S = 4.0
 SYNC_MARK = "bench.sync"
-# Warm-up: every text once alone, then all streams together, lap by lap,
-# until QUIET_LAPS laps in a row have built no program (launch groups
-# compile per size and width, and which form depends on timing).
-LAPS_MIN, LAPS_MAX, QUIET_LAPS = 2, 12, 2
+# Warm-up: every text once alone, then all streams together as the window drives them (closed
+# loops until a deadline), in stretches of half the window's length: two at the least, and a
+# third if the second built a program or failed a statement. Launch groups compile per size and
+# width, which of them form depends on timing, and the rarer sizes come up once in seconds of
+# traffic at full speed: the warm-up has to last as long as what it warms. Three at the most:
+# the first builds 8 to 17 programs, each later one 0 to 2 for as long as it was tried (PERF.md,
+# section 6, PR 29), and a fourth would take a traced run of 51 s past 330 s of wall.
+STRETCHES_MIN, STRETCHES_MAX = 2, 3  # of seconds / 2 each
 # A warm-up statement can fail while a program compiles with a cold cache: a follower of a launch
 # group gives up after 120 s (`sched/batcher.py` WAIT_TIMEOUT_S), five such faults open the engine's
 # circuit breaker, and it rejects every statement for 30 s (`copr/retry.py`). So what failed is run
 # again after a pause; a run whose warm-up has no failure never pauses.
 ALONE_RETRY_PAUSES_S = (5, 10, 20, 40)
-FAILED_LAP_PAUSE_S = 10
+FAILED_STRETCH_PAUSE_S = 10
 
 
 def log(**kv) -> None:
@@ -287,6 +291,29 @@ def _last_run_of_each_text(sent: list[Sent]) -> list[Sent]:
     return list({s.stmt.sql: s for s in sent}.values())
 
 
+def warm_together(drv, system, seconds: float) -> tuple[list[Sent], dict]:
+    """All streams together, stretch by stretch (see STRETCHES_MIN). Returns what was sent and the
+    `warmup` line's account of it: how long it lapped, how long the last of it built and failed
+    nothing, which stretches built how many programs, and whether it stopped quiet or at the cap."""
+    lapped: list[Sent] = []
+    builds: list[int] = []
+    quiet, quiet_s = False, 0.0
+    t0 = time.perf_counter()
+    while len(builds) < STRETCHES_MAX and (len(builds) < STRETCHES_MIN or not quiet):
+        built, t = system.programs_built(), time.perf_counter()
+        stretch = drv.run(deadline_ns=time.perf_counter_ns() + int(seconds / 2 * 1e9))
+        lapped += stretch
+        builds.append(system.programs_built() - built)
+        failed = any(s.error for s in stretch)
+        quiet = not builds[-1] and not failed
+        quiet_s = quiet_s + time.perf_counter() - t if quiet else 0.0
+        if failed:  # the breaker may be open: the texts are lapped again after a pause
+            time.sleep(FAILED_STRETCH_PAUSE_S)
+    return lapped, {"together_s": round(time.perf_counter() - t0, 3), "together_stretches": len(builds),
+                    "built_by_stretch": builds, "quiet_s": round(quiet_s, 3),
+                    "stopped": "quiet" if quiet else "cap"}
+
+
 def run_cell(*, manifest: dict, cell: dict, config: dict, mix: dict, seed: int, seconds: float,
              trace: bool, rows_scale: float, t_process_ns: int, device: dict,
              describe_trace: str | None = None, system_cls=System) -> dict:
@@ -322,28 +349,14 @@ def run_cell(*, manifest: dict, cell: dict, config: dict, mix: dict, seed: int, 
             time.sleep(pause)
             alone += drv.warm_alone(failed_alone)
         t_alone = time.perf_counter() - t
-        lapped: list[Sent] = []
-        laps = quiet = 0
-        built = system.programs_built()
-        while laps < LAPS_MAX and (laps < LAPS_MIN or quiet < QUIET_LAPS):
-            lap = drv.run(laps=1)
-            lapped += lap
-            laps += 1
-            now = system.programs_built()
-            # a lap with a failed statement is not quiet: the texts are lapped again
-            lap_failed = any(s.error for s in lap)
-            quiet = quiet + 1 if now == built and not lap_failed else 0
-            built = now
-            if lap_failed:
-                time.sleep(FAILED_LAP_PAUSE_S)
+        lapped, together = warm_together(drv, system, seconds)
         failed_warm = [s.error for s in alone + lapped if s.error]
         not_warm = [s.error for s in _last_run_of_each_text(alone + lapped) if s.error]
         log(step="warmup", statements=len(alone) + len(lapped), failed=len(failed_warm),
             first_error=(failed_warm or [None])[0], failed_in_last_run=len(not_warm),
-            alone_s=round(t_alone, 3), together_s=round(time.perf_counter() - t - t_alone, 3),
-            together_laps=laps, quiet_laps=quiet,
+            alone_s=round(t_alone, 3), **together,
             compile_s=round(system.counters().get("tidb_tpu_compile_seconds_sum", 0.0) - compile_s0, 3),
-            programs_built=built - built0)
+            programs_built=system.programs_built() - built0)
         if not_warm:
             # the traffic is chosen so that no operation fails; a text whose last run failed is not warm
             raise RuntimeError(f"{len(not_warm)} text(s) failed in their last warm-up run: {not_warm[0]}")
@@ -409,9 +422,9 @@ def run_cell(*, manifest: dict, cell: dict, config: dict, mix: dict, seed: int, 
 
     by_text: dict[str, list] = {}
     for s in done:
-        key = f"s{s.stmt.stream} {s.stmt.template} {next(iter(s.stmt.params.values()))}"
-        by_text.setdefault(key, []).append((s.t_done_ns - s.t_send_ns) / 1e6)
+        by_text.setdefault(_text_key(s.stmt), []).append((s.t_done_ns - s.t_send_ns) / 1e6)
     log(step="texts", per_text={k: {"n": len(v), "mean_ms": round(sum(v) / len(v), 1)} for k, v in sorted(by_text.items())})
+    log(step="launches", per_text=launches_by_text(done, events))
     log(step="counters", moved={k: v for k, v in sorted(delta.items()) if "_bucket" not in k})
 
     e2e = {"setup_s": {"value": setup_s, "unit": "s"}}
@@ -444,27 +457,66 @@ def run_cell(*, manifest: dict, cell: dict, config: dict, mix: dict, seed: int, 
     return result
 
 
+def _text_key(stmt) -> str:
+    return f"s{stmt.stream} {stmt.template} {next(iter(stmt.params.values()))}"
+
+
+def launches_by_text(done: list[Sent], events: list[dict]) -> dict:
+    """For the log alone: per text and launch occupancy, how many launches its statements waited
+    for, with the mean milliseconds of the launch, of the `device.execute` inside it and of its
+    wait, and the programs a launch ran. A statement span belongs to the answered statement that
+    lies tightest around it on the client's clock. It tells what a run that reads far off did
+    differently (PERF.md, section 7)."""
+    sends = sorted(done, key=lambda s: s.t_send_ns)
+    text_of: dict[str, str] = {}
+    for ev in events:
+        if ev["name"] == "statement":
+            around = [s for s in sends if s.t_send_ns <= ev["t_start_ns"] and ev["t_end_ns"] <= s.t_done_ns]
+            if around:
+                text_of[ev["args"].get("trace_id")] = _text_key(
+                    min(around, key=lambda s: s.t_done_ns - s.t_send_ns).stmt)
+    execute: dict = {}
+    for ev in events:
+        if ev["name"] == "device.execute":
+            ms, programs = execute.get(ev["args"].get("launch_id"), (0.0, 0))
+            execute[ev["args"].get("launch_id")] = (
+                ms + (ev["t_end_ns"] - ev["t_start_ns"]) / 1e6, programs + ev["args"].get("programs", 0))
+    cells: dict[str, dict[str, list]] = {}
+    for ev in events:
+        if ev["name"] != "cop.launch":
+            continue
+        a = ev["args"]
+        row = [(ev["t_end_ns"] - ev["t_start_ns"]) / 1e6, *execute.get(a.get("launch_id"), (0.0, 0)),
+               a.get("queued_ns", 0) / 1e6]
+        for text in {text_of[w] for w in a.get("waiters", ()) if w in text_of}:
+            cells.setdefault(text, {}).setdefault(str(a.get("occupancy")), []).append(row)
+    return {text: {occ: {"n": len(rows), **{k: round(sum(r[i] for r in rows) / len(rows), 2) for i, k in
+                                            enumerate(("launch_ms", "execute_ms", "programs", "queued_ms"))}}
+                   for occ, rows in sorted(by_occ.items())}
+            for text, by_occ in sorted(cells.items())}
+
+
 def breakdown(reduced: dict, events: list[dict]) -> dict:
     """The device ops that took most time, and the longest idle gaps of
-    the busiest chip by what the host was doing: the program's timeline
-    span that covers most of the gap."""
+    the busiest chip by what the host was doing: every part of a gap goes
+    to the innermost of the program's timeline spans that cover it, the
+    shortest one, and to a `statement` only where no engine span does."""
     shift = reduced["shift_ns"]
     totals: dict[str, float] = {"gaps under 1 ms": reduced["short_gaps_s"]}
     for g0, g1 in reduced["gaps_ns"]:
         lo, hi = g0 + shift, g1 + shift
-        cover: dict[str, int] = {}
-        for ev in events:
-            ov = min(hi, ev["t_end_ns"]) - max(lo, ev["t_start_ns"])
-            if ov > 0:
-                cover[ev["name"]] = cover.get(ev["name"], 0) + ov
-        inner = {k: v for k, v in cover.items() if k != "statement"}
-        if inner:
-            label = max(inner, key=inner.get)
-        elif cover:
-            label = "statement (outside any engine span)"
-        else:
-            label = "no statement running"
-        totals[label] = totals.get(label, 0.0) + (g1 - g0) / 1e9
+        over = [ev for ev in events if ev["t_start_ns"] < hi and ev["t_end_ns"] > lo]
+        cuts = sorted({lo, hi, *(t for ev in over for t in (ev["t_start_ns"], ev["t_end_ns"]) if lo < t < hi)})
+        for a, b in zip(cuts, cuts[1:]):
+            cover = [ev for ev in over if ev["t_start_ns"] <= a and ev["t_end_ns"] >= b]
+            inner = [ev for ev in cover if ev["name"] != "statement"]
+            if inner:
+                label = min(inner, key=lambda ev: ev["t_end_ns"] - ev["t_start_ns"])["name"]
+            elif cover:
+                label = "statement (outside any engine span)"
+            else:
+                label = "no statement running"
+            totals[label] = totals.get(label, 0.0) + (b - a) / 1e9
     return {
         "device_ops": reduced["device_ops"],
         "idle_gaps": [[k, v] for k, v in sorted(totals.items(), key=lambda kv: -kv[1])][:10],

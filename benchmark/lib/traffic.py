@@ -110,9 +110,9 @@ class Streams:
         stmts = only if only is not None else [s for st in self.streams for s in st]
         return [self._one(s.stream, s) for s in stmts]
 
-    def run(self, *, laps: int | None = None, deadline_ns: int | None = None) -> list[Sent]:
-        """All streams at once, each on its own thread and connection:
-        `laps` times through its list, or until `deadline_ns` on the
+    def run(self, deadline_ns: int) -> list[Sent]:
+        """All streams at once, each on its own thread and connection,
+        through its list again and again until `deadline_ns` on the
         perf_counter clock (no statement is sent after it; one in flight
         is waited for)."""
         results: list[list[Sent]] = [[] for _ in self.streams]
@@ -120,8 +120,7 @@ class Streams:
         def loop(i: int) -> None:
             st = self.streams[i]
             n = 0
-            while (laps is None or n < laps * len(st)) and (
-                    deadline_ns is None or time.perf_counter_ns() < deadline_ns):
+            while time.perf_counter_ns() < deadline_ns:
                 results[i].append(self._one(i, st[n % len(st)]))
                 n += 1
 

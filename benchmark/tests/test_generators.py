@@ -31,6 +31,19 @@ def test_row_counts_hold_for_every_seed_and_rows_differ():
     assert (a == again).all()
 
 
+def test_every_seed_has_the_same_orders_at_the_same_rows():
+    """The sizes of the orders are no draw of the seed: `l_orderkey` and
+    `l_linenumber` are the same lanes for every seed (their codecs are in
+    the program's keys), the values of the other columns are not."""
+    a = {k: v.copy() for k, v in tpch.lineitem(SIZES["lineitem"], 11, **SIZES).items()}
+    b = tpch.lineitem(SIZES["lineitem"], 2**31 + 5, **SIZES)
+    assert (a["l_orderkey"] == b["l_orderkey"]).all() and (a["l_linenumber"] == b["l_linenumber"]).all()
+    assert all((a[c] != b[c]).mean() > 0.5 for c in ("l_partkey", "l_quantity", "l_shipdate", "l_comment"))
+    counts = np.bincount(np.unique(a["l_orderkey"], return_counts=True)[1])[1:]
+    assert len(counts) == 7 and counts.min() > 0  # still 1 to 7 lineitems an order, shuffled
+    assert len(set(np.diff(np.flatnonzero(np.diff(a["l_orderkey"])))[:50])) > 3
+
+
 def test_the_lineitem_only_configuration_has_the_same_lineitems(world):
     alone = tpch.lineitem(SIZES["lineitem"], 2147483659, lineitem=SIZES["lineitem"])
     assert all((alone[c] == world["lineitem"][c]).all() for c in alone)
@@ -114,4 +127,4 @@ def test_text_columns(world):
 
 def test_a_row_count_that_cannot_be_met_is_refused():
     with pytest.raises(ValueError, match="cannot be spread"):
-        tpch.line_counts(10, 100, np.random.default_rng(0))
+        tpch.line_counts(10, 100)
