@@ -170,6 +170,25 @@ def test_window_kernel_compiles_for_v5e(one_chip, monkeypatch):
     kernel.lower(*at_size, None).compile()
 
 
+def _record_mpp_builds(mpp, monkeypatch):
+    """Keep what `MPPEngine._build_program` is given and the arguments its
+    program is called with; returns (the record, the unpatched builder)."""
+    built = []
+    build_program = mpp._build_program
+
+    def recording(mplan, meta, scan_arg_meta, mesh, axis, n_dev, in_specs, lut_fids=()):
+        prog = build_program(mplan, meta, scan_arg_meta, mesh, axis, n_dev, in_specs, lut_fids)
+
+        def run(*args):
+            built.append(((mplan, meta, scan_arg_meta, axis, n_dev, in_specs, lut_fids), args))
+            return prog(*args)
+
+        return run
+
+    monkeypatch.setattr(mpp, "_build_program", recording)
+    return built, build_program
+
+
 def test_q3_mpp_program_compiles_for_four_chips(topo, monkeypatch):
     """Q3's one shard_map program on a Mesh over the four described
     devices: the engine plans it on four CPU devices, the recorder keeps
@@ -188,19 +207,7 @@ def test_q3_mpp_program_compiles_for_four_chips(topo, monkeypatch):
     s.vars["tidb_allow_mpp"] = "ON"
     mpp = s.cop.mpp
     mpp._mesh = make_mesh(4)
-    built = []
-    build_program = mpp._build_program
-
-    def recording(mplan, meta, scan_arg_meta, mesh, axis, n_dev, in_specs, lut_fids=()):
-        prog = build_program(mplan, meta, scan_arg_meta, mesh, axis, n_dev, in_specs, lut_fids)
-
-        def run(*args):
-            built.append(((mplan, meta, scan_arg_meta, axis, n_dev, in_specs, lut_fids), args))
-            return prog(*args)
-
-        return run
-
-    monkeypatch.setattr(mpp, "_build_program", recording)
+    built, build_program = _record_mpp_builds(mpp, monkeypatch)
     s.must_query(tpch.Q3)
     assert mpp.fallbacks == 0, mpp.last_fallback_reason
     ((mplan, meta, scan_arg_meta, axis, n_dev, in_specs, lut_fids), args), = built
@@ -214,3 +221,36 @@ def test_q3_mpp_program_compiles_for_four_chips(topo, monkeypatch):
     ]
     text = prog.lower(*shapes).compile().as_text()
     assert "all-to-all" in text or "all-reduce" in text
+
+
+def test_q3_two_key_topn_program_compiles_for_one_chip(one_chip, topo, monkeypatch):
+    """Q3 as TPC-H writes it (`ORDER BY revenue DESC, o_orderdate LIMIT
+    10`, grouped by the stream's `l_orderkey`): the clustered program with
+    the fused two-key TopN (the block top-k of 16 candidates and the
+    tie-overflow lane), as `tpch_q3_streams` runs it on one chip."""
+    from jax.sharding import Mesh, NamedSharding
+
+    from tidb_tpu.models import tpch
+    from tidb_tpu.parallel.mesh import make_mesh
+    from tidb_tpu.session import Session
+
+    s = Session()
+    tpch.setup_tpch(s, MPP_ROWS)
+    s.vars["tidb_enable_cop_result_cache"] = "OFF"
+    s.vars["tidb_cop_engine"] = "tpu"
+    s.vars["tidb_allow_mpp"] = "ON"
+    mpp = s.cop.mpp
+    mpp._mesh = make_mesh(1)
+    built, build_program = _record_mpp_builds(mpp, monkeypatch)
+    assert len(s.must_query(tpch.Q3_SPEC)) == 10
+    assert mpp.fallbacks == 0, mpp.last_fallback_reason
+    assert mpp.last_agg == {"agg_mode": "clustered", "topn_keys": 2, "decline": ""}
+    ((mplan, meta, scan_arg_meta, axis, n_dev, in_specs, lut_fids), args), = built
+
+    chip_mesh = Mesh(np.array(topo.devices[:1]), (axis,))
+    prog = build_program(mplan, meta, scan_arg_meta, chip_mesh, axis, n_dev, in_specs, lut_fids)
+    shapes = [
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(chip_mesh, spec))
+        for a, spec in zip(args, in_specs)
+    ]
+    prog.lower(*shapes).compile()

@@ -687,6 +687,49 @@ class TestMppLaunchSpans:
         assert len(gathers) == 2 and gathers[0].args["scans"] == 3 and gathers[0].args["rows"] > 0
         assert gathers[0].t_end_ns <= cold.t_start_ns
 
+    Q3_TWO_KEYS = (
+        "SELECT l.l_orderkey, SUM(l.l_extendedprice * (1 - l.l_discount)) AS revenue, o.o_orderdate, "
+        "o.o_shippriority, COUNT(*) AS cnt FROM customer c JOIN orders o ON c.c_custkey = o.o_custkey "
+        "JOIN lineitem l ON l.l_orderkey = o.o_orderkey "
+        "WHERE c.c_mktsegment = 'BUILDING' AND o.o_orderdate < '1995-03-15' AND l.l_shipdate > '1995-03-15' "
+        "GROUP BY l.l_orderkey, o.o_orderdate, o.o_shippriority ORDER BY {by} LIMIT 10")
+
+    @pytest.mark.parametrize("by,mode,keys,decline,fetches", [
+        ("revenue DESC, o.o_orderdate", "clustered", 2, "", 1),  # Q3 as TPC-H writes it
+        ("o.o_orderdate, revenue DESC", "rows", 0, "", 1),  # a group key first: nothing fuses
+        ("cnt DESC, l.l_orderkey", "rows", 0, "topn_tie_overflow", 2),  # hundreds tie on a count
+    ])
+    def test_launch_and_prepare_say_mode_keys_and_decline(self, q3, by, mode, keys, decline, fetches):
+        """`mpp.launch` and `mpp.prepare` carry `agg_mode`, `topn_keys` and
+        `decline`. A fused two-key TopN fetches a few rows (under 64 KiB
+        whatever the stream); unfused, the fetch is the joined stream's
+        positions and grows with it. A tie overflow runs the statement
+        twice inside ONE launch: the declined pass, then the rows pass."""
+        ring = q3.store.timeline
+        q3.must_query(self.Q3_TWO_KEYS.format(by=by))  # cold: uploads and compiles
+        ring.clear()
+        rows = q3.must_query(self.Q3_TWO_KEYS.format(by=by))
+        assert len(rows) == 10
+        evs = ring.snapshot()
+        launches = _assert_launch_trees(evs)
+        _assert_lanes_well_formed(evs)
+        (l,) = [x for x in launches.values() if x.name == "mpp.launch"]
+        said = {k: l.args[k] for k in ("agg_mode", "topn_keys", "decline", "outcome")}
+        assert said == {"agg_mode": mode, "topn_keys": keys, "decline": decline, "outcome": "ok"}
+        kids = sorted(_children_of(evs, l), key=lambda e: e.t_start_ns)
+        prepares = [e for e in kids if e.name == "mpp.prepare"]
+        fetched = [e.args["d2h_bytes"] for e in kids if e.name == "mpp.fetch"]
+        assert len(prepares) == len(fetched) == fetches
+        assert {k: prepares[-1].args[k] for k in ("agg_mode", "topn_keys", "decline")} == \
+            {"agg_mode": mode, "topn_keys": keys, "decline": decline}
+        if fetches == 2:  # the declined pass was the fused one
+            assert prepares[0].args["agg_mode"] == "clustered" and prepares[0].args["topn_keys"] == 2
+        stream = next(e.args["rows"] for e in evs if e.name == "mpp.gather")
+        if keys:
+            assert fetched[-1] < 64 * 1024
+        else:
+            assert fetched[-1] > 64 * 1024 and fetched[-1] > stream  # a few lanes a surviving position
+
     def test_mpp_statement_gets_device_exec_details(self, q3):
         """The MPP dispatch's compile / transfer / fetch reach the
         statement's exec details like a cop launch's do."""

@@ -314,36 +314,46 @@ def _build_agg(plan: Aggregation, ctx: ExecContext) -> Executor:
 
 
 def _mpp_topn_spec(sort_plan: Sort, inner) -> tuple | None:
-    """ORDER BY <single sum/count aggregate> over Projection?(Aggregation)
-    → (agg_idx, desc) resolved into the Aggregation's agg list, else None.
-    The device then returns only the top-k groups per device (exact: after
-    the hash exchange every group is complete on one device)."""
+    """ORDER BY list over Projection?(Aggregation) whose FIRST key is a
+    sum/count aggregate (not DISTINCT) and whose further keys are
+    group-by columns or further such aggregates, each with its own
+    direction → (agg_idx, desc, Aggregation, more) resolved into the
+    Aggregation's lists, else None. `more` is () for one key, else
+    ((kind, idx, desc), ...) with kind "group" | "agg" (MPPPlan.topn).
+    The device then returns only the groups the answer can need per
+    device (exact: every group is complete on one device, and groups
+    that tie on the first key across the cut all come back, so the host
+    TopN above the gather decides them by the further keys)."""
     from ..expr.expression import Column as _EC
 
-    if len(sort_plan.by) != 1:
-        return None
-    e, desc = sort_plan.by[0]
-    if not isinstance(e, _EC):
-        return None
-    idx = e.idx
+    chain = []
     while isinstance(inner, Projection):
-        pe = inner.exprs[idx]
-        if not isinstance(pe, _EC):
-            return None
-        idx = pe.idx
+        chain.append(inner)
         inner = inner.children[0]
-    if not isinstance(inner, Aggregation):
+    if not isinstance(inner, Aggregation) or not sort_plan.by:
         return None
     ng = len(inner.group_by)
-    if idx < ng:
-        return None  # ordering by a group key: host TopN handles it
-    a = inner.aggs[idx - ng]
-    if a.name not in ("sum", "count") or a.distinct:
-        return None
+    keys = []
+    for e, desc in sort_plan.by:
+        for proj in chain:
+            if not isinstance(e, _EC):
+                return None
+            e = proj.exprs[e.idx]
+        if not isinstance(e, _EC):
+            return None
+        if e.idx < ng:
+            keys.append(("group", e.idx, bool(desc)))
+            continue
+        a = inner.aggs[e.idx - ng]
+        if a.name not in ("sum", "count") or a.distinct:
+            return None
+        keys.append(("agg", e.idx - ng, bool(desc)))
+    if keys[0][0] != "agg":
+        return None  # ordering by a group key first: host TopN handles it
     # carry the Aggregation node so the attach step can verify the gather
     # it found actually fused THIS aggregation (nested aggs would
     # otherwise receive the outer agg's topn)
-    return (idx - ng, bool(desc), inner)
+    return (keys[0][1], keys[0][2], inner, tuple(keys[1:]))
 
 
 def _find_mpp_gather(ex: Executor):
@@ -391,7 +401,7 @@ def _build_limit(plan: Limit, ctx: ExecContext) -> Executor:
         if spec is not None:
             gather = _find_mpp_gather(sort_child)
             if gather is not None and gather.mplan.agg is spec[2]:
-                gather.mplan.topn = (spec[0], spec[1], n)
+                gather.mplan.topn = (spec[0], spec[1], n) + ((spec[3],) if spec[3] else ())
         return TopNExec(sort_child, child.by, plan.count, plan.offset)
     ex = build_executor(child, ctx)
     reader = _pushable_reader(ex)

@@ -87,6 +87,16 @@ WHERE c.c_mktsegment = 'BUILDING' AND o.o_orderdate < '1995-03-15' AND l.l_shipd
 GROUP BY o.o_orderkey, o.o_orderdate
 ORDER BY revenue DESC LIMIT 10"""
 
+# Q3 as the specification writes it (2.4.3): grouped by the stream's
+# l_orderkey beside two ORDERS columns, ordered by TWO keys (the fused
+# multi-key TopN of parallel/mpp.py); joins written JOIN ... ON
+Q3_SPEC = """SELECT l.l_orderkey, SUM(l.l_extendedprice * (1 - l.l_discount)) AS revenue, o.o_orderdate, o.o_shippriority
+FROM customer c JOIN orders o ON c.c_custkey = o.o_custkey
+JOIN lineitem l ON l.l_orderkey = o.o_orderkey
+WHERE c.c_mktsegment = 'BUILDING' AND o.o_orderdate < '1995-03-15' AND l.l_shipdate > '1995-03-15'
+GROUP BY l.l_orderkey, o.o_orderdate, o.o_shippriority
+ORDER BY revenue DESC, o.o_orderdate LIMIT 10"""
+
 
 def _rand_dates(rng, n, y0=1992, y1=1998):
     """Packed date int64s uniform over [y0, y1]."""

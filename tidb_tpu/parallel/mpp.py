@@ -163,6 +163,10 @@ class MPPEngine:
         # (fused | partial | unfused | off) and why levels declined
         self.last_fuse_outcome = ""
         self.last_fuse_reasons: dict[int, str] = {}
+        # how the LAST dispatch aggregated (the args of its mpp.prepare
+        # span, and EXPLAIN ANALYZE's mpp line): agg_mode, topn_keys,
+        # decline
+        self.last_agg: dict = {}
 
     HOST_CACHE_BYTES = 4 << 30
     STAT_CACHE_BYTES = 1 << 30
@@ -315,13 +319,25 @@ class MPPEngine:
                 splits.append(max(b, splits[-1]))
             splits.append(n)
             rawmax = max(splits[i + 1] - splits[i] for i in range(n_dev))
-            # pow2 row bucket (the tile-cache rule): predicates of similar
-            # selectivity land on the same padded shape and share one
-            # compiled program instead of recompiling per constant
-            L = max(8, 1 << (rawmax - 1).bit_length()) if rawmax else 8
-            return (tuple(splits), L, rawmax)
+            return (tuple(splits), self._row_bucket(rawmax), rawmax)
 
         return self._cached_stat(sd, ("casplit", koff, sel_tag, n_dev), compute)
+
+    @staticmethod
+    def _row_bucket(n: int) -> int:
+        """Padded length of a clustered shard of `n` rows. Predicates of
+        similar selectivity land on the same padded shape and share one
+        compiled program instead of recompiling per constant (the
+        tile-cache rule): a power of two up to 2^20 rows, where a program
+        runs in milliseconds whatever it pads. Above that every padded
+        row is paid in the program's stream-long gathers (TPC-H Q3 at 16M
+        rows keeps 8.6M and would run 16.8M), so the bucket is the next
+        multiple of a sixteenth of the enclosing power of two: at most
+        an eighth more rows than there are."""
+        if n <= 1 << 20:
+            return max(8, 1 << (n - 1).bit_length()) if n else 8
+        step = 1 << ((n - 1).bit_length() - 4)
+        return -(-n // step) * step
 
     @staticmethod
     def _shard_pad(a: np.ndarray, splits, L: int, fill=0) -> np.ndarray:
@@ -506,10 +522,19 @@ class MPPEngine:
     # the LONGEST run's shard — a skewed stream would ship n_dev x that
     CLUSTERED_TOPN_MAX = 64
     CLUSTERED_SKEW_MIN = 4096
+    # a fused TopN of MORE than one key cuts by its first key alone, so
+    # every group that ties with the k-th on that key has to come back
+    # for the host to decide by the further keys: each device returns
+    # k + TOPN_TIE_SLACK candidates, and a tie wider than that is the
+    # typed decline `topn_tie_overflow` (the statement runs again
+    # without the fused TopN — never a wrong or truncated answer)
+    TOPN_TIE_SLACK = 6
 
     def prepare(self, mplan: MPPPlan, scans: list[ScanData], variables: dict,
-                gate=None, fused: bool = False):
+                gate=None, fused: bool = False, use_topn: bool = True):
         """Resolve all data-dependent static choices; None → fallback.
+        `use_topn=False` plans as if no TopN were fused (the re-run after
+        a `topn_tie_overflow` decline).
         `gate` (optional () -> None) is the scheduler's shared interrupt
         gate: the per-scan rewrites and per-level key analyses below walk
         O(table bytes) of host lanes, and a KILL/deadline/runaway verdict
@@ -782,7 +807,8 @@ class MPPEngine:
         if mplan.agg is not None:
             agg_meta = self._prepare_agg(mplan, scans, scan_of_joined,
                                          levels=levels, by_frag=by_frag,
-                                         fused=fused)
+                                         fused=fused,
+                                         topn=mplan.topn if use_topn else None)
             if agg_meta is None:
                 # the JOIN still rides the mesh; the aggregation finishes
                 # on host over the joined rows (group-key domains too wide
@@ -854,43 +880,56 @@ class MPPEngine:
             return all(cls._never_null(a) for a in x.args)
         return False
 
-    def _prepare_agg_rowpos(self, mplan, scan_of_joined, levels, by_frag):
+    def _prepare_agg_rowpos(self, mplan, scan_of_joined, levels, by_frag,
+                            topn=None):
         """Build-row-position aggregation (the fused-chain agg mode, PR
-        11): when every group-by column lives on ONE unique-keyed build
-        side whose join keys are a subset of the group keys, each build
-        ROW is exactly one group — the program segment-reduces by the
-        build rowid it already gathered for output, skipping the wide-key
-        lexsort entirely. Groups then live in a dense [0, n_build) space:
-        psum_scatter splits it across devices, each device top-ks its
-        slice, and the host merges n_dev*k candidates (group key VALUES
-        decode host-side from the build scan's original lanes, so dates/
-        strings/decimals all work). Requires a fused TopN like the sorted
-        mode — without it the full segment space would ship to host."""
+        11): when every group-by column is pinned by ONE unique-keyed
+        build side whose join keys are a subset of the group keys, each
+        build ROW is exactly one group — the program segment-reduces by
+        the build rowid it already gathered for output, skipping the
+        wide-key lexsort entirely. A group-by column pins the build side
+        when it lives on it, or when it is the level's PROBE key of the
+        same type (an inner equi-join makes it equal to the build key on
+        every surviving row: Q3 as TPC-H writes it groups by
+        `l_orderkey`, the stream's column, beside two ORDERS columns).
+        Groups then live in a dense [0, n_build) space: psum_scatter
+        splits it across devices, each device top-ks its slice, and the
+        host merges the candidates (group key VALUES decode host-side
+        from the build scan's original lanes — `rp_gsrc` names the lane
+        per group-by column — so dates/strings/decimals all work).
+        Requires a fused TopN (`topn`: MPPPlan.topn, one key or more)
+        like the sorted mode — without it the full segment space would
+        ship to host."""
         agg = mplan.agg
-        if mplan.topn is None or not levels:
+        if topn is None or not levels:
             return None
-        agg_idx, _desc, _k = mplan.topn
-        if agg.aggs[agg_idx].name not in ("sum", "count"):
+        if agg.aggs[topn[0]].name not in ("sum", "count"):
             return None
-        gsd = None
-        goffs = set()
-        for g in agg.group_by:
-            if not isinstance(g, ExprCol):
-                return None
-            sd, _off = scan_of_joined[g.idx]
-            if gsd is not None and sd is not gsd:
-                return None  # group keys span scans: not one build side
-            gsd = sd
-            goffs.add(g.idx)
-        if gsd is None:
+        if not all(isinstance(g, ExprCol) for g in agg.group_by) or not agg.group_by:
             return None
-        lvl = next((l for l in levels if by_frag[id(l.frag.build)] is gsd), None)
-        if lvl is None or lvl.frag.kind != "inner" or lvl.mult != 1:
-            return None
-        if not set(lvl.frag.build_keys) <= goffs:
-            # grouping is COARSER than build rows (key not grouped on):
-            # rowpos segments would split one SQL group across rows
-            return None
+
+        def lane_type(j):
+            sd, off = scan_of_joined[j]
+            ft = sd.frag.ds.out_cols[off].ft
+            return (ft.tp, ft.decimal, ft.is_unsigned)
+
+        lvl = gsrc = None
+        for cand in levels:
+            if cand.frag.kind != "inner" or cand.mult != 1:
+                continue
+            bsd = by_frag[id(cand.frag.build)]
+            alias = {pk: bk for pk, bk in zip(cand.frag.probe_keys, cand.frag.build_keys)
+                     if lane_type(pk) == lane_type(bk)}
+            src = [g.idx if scan_of_joined[g.idx][0] is bsd else alias.get(g.idx)
+                   for g in agg.group_by]
+            # grouping COARSER than build rows (a build key not grouped
+            # on) would split one SQL group across rowpos segments
+            if None not in src and set(cand.frag.build_keys) <= set(src):
+                lvl, gsrc = cand, src
+                break
+        if lvl is None:
+            return None  # group keys span scans: not one build side
+        gsd = by_frag[id(lvl.frag.build)]
         if not (4096 <= gsd.n_rows <= self.ROWPOS_MAX):
             # tiny builds stay on the proven dense/sorted paths (the
             # per-device block must hold a top-k wider than the output
@@ -945,9 +984,10 @@ class MPPEngine:
         return {
             "mode": mode,
             "r_args": r_args,
-            "topn": mplan.topn,
+            "topn": topn,
             "rp_fid": id(lvl.frag.build),
             "rp_rows": gsd.n_rows,
+            "rp_gsrc": gsrc,
             "rp_presence": presence,
             "rp_ck": ck_idx,
             "clustered_reason": creason,
@@ -957,7 +997,8 @@ class MPPEngine:
         }
 
     def _prepare_agg(self, mplan: MPPPlan, scans, scan_of_joined,
-                     levels=None, by_frag=None, fused: bool = False):
+                     levels=None, by_frag=None, fused: bool = False,
+                     topn=None):
         """Device aggregation metadata. Three modes (the dense/sorted
         pair mirrors TPUEngine's dense-vs-segment split; rowpos is the
         PR 11 fused-chain specialization):
@@ -967,21 +1008,24 @@ class MPPEngine:
           side — segment space = build row positions (see
           _prepare_agg_rowpos), tried when dense can't hold the domain;
         - sorted: wide int key domains, only when a TopN over an agg
-          output is fused (mplan.topn) — per-device lexsort + segment
+          output is fused (`topn`) — per-device lexsort + segment
           reduce, hash exchange by group key, final reduce, device top-k.
           The mesh then returns k groups per device instead of shipping
           the joined rows back over the (slow) host link."""
-        meta = self._prepare_agg_keyed(mplan, scan_of_joined)
+        meta = self._prepare_agg_keyed(mplan, scan_of_joined, topn)
         if meta is not None and meta["mode"] == "dense":
             return meta
         if fused:
-            rp = self._prepare_agg_rowpos(mplan, scan_of_joined, levels, by_frag)
+            rp = self._prepare_agg_rowpos(mplan, scan_of_joined, levels, by_frag,
+                                          topn=topn)
             if rp is not None:
                 return rp
         return meta
 
-    def _prepare_agg_keyed(self, mplan: MPPPlan, scan_of_joined):
-        """The dense/sorted packed-group-key modes (pre-PR 11 behavior)."""
+    def _prepare_agg_keyed(self, mplan: MPPPlan, scan_of_joined, topn=None):
+        """The dense/sorted packed-group-key modes (pre-PR 11 behavior).
+        `topn` is the fused TopN (MPPPlan.topn, one key or more) the
+        sorted mode needs; dense ships every group and ignores it."""
         agg = mplan.agg
         domains, key_meta = [], []
         sorted_domains = []  # step-compressed (gcd) domains for wide mode
@@ -1024,15 +1068,14 @@ class MPPEngine:
                 break
         mode = "dense"
         if not dense_ok:
-            if mplan.topn is None:
+            if topn is None:
                 return None
             wide = 1
             for s in sorted_domains:
                 wide *= s + 1
                 if wide > 1 << 62:
                     return None  # even compressed keys overflow the code
-            agg_idx = mplan.topn[0]
-            if agg.aggs[agg_idx].name not in ("sum", "count"):
+            if agg.aggs[topn[0]].name not in ("sum", "count"):
                 return None
             mode = "sorted"
         r_args = self._lower_agg_args(agg, scan_of_joined)
@@ -1050,7 +1093,7 @@ class MPPEngine:
                 acc *= radixes[i]
             meta["strides"] = strides
             meta["radixes"] = radixes
-            meta["topn"] = mplan.topn
+            meta["topn"] = topn
         return meta
 
     # ------------------------------------------------------------- compile
@@ -1081,10 +1124,16 @@ class MPPEngine:
         program spans the whole mesh and no lock serializes dispatches,
         so the lane is the mesh's, split by calling thread like a
         resource group's: one thread's spans nest, two threads' may
-        overlap in time and must not share a track."""
+        overlap in time and must not share a track. `mpp.prepare` and
+        `mpp.launch` say how the aggregation ran: `agg_mode` (dense |
+        sorted | rowpos | clustered | rows: joined rows to the host),
+        `topn_keys` (ORDER BY keys of the TopN fused into the program, 0
+        when none) and `decline` (the typed reason a faster mode or the
+        fused TopN was refused, "" when none)."""
         n_dev = mesh.shape[axis]
         trace = tracing.current_trace()
-        said = {"outcome": "error", "program": ""}
+        said = {"outcome": "error", "program": "", "agg_mode": "",
+                "topn_keys": 0, "decline": ""}
         t0 = time.perf_counter_ns()
         lane = f"mesh:{axis}={n_dev} ({threading.current_thread().name})"
         with TL.device_scope(lane), TL.launch_scope(tracing._next_id()):
@@ -1095,14 +1144,18 @@ class MPPEngine:
                 TL.boundary(
                     "mpp.launch", t0, time.perf_counter_ns(),
                     mesh=f"{axis}={n_dev}", program=said["program"],
-                    outcome=said["outcome"],
+                    outcome=said["outcome"], agg_mode=said["agg_mode"],
+                    topn_keys=said["topn_keys"], decline=said["decline"],
                     waiters=[trace.trace_id] if trace is not None else [],
                 )
 
     def _execute(self, mplan, scans, mesh, variables, axis, gate, fused,
-                 build_cache, schema_ver, said: dict):
+                 build_cache, schema_ver, said: dict, use_topn: bool = True):
         """`execute` inside its launch scope; `said` takes what the
-        `mpp.launch` span says of the run (program digest, outcome)."""
+        `mpp.launch` span says of the run (program digest, outcome, agg
+        mode, fused TopN keys, decline reason). `use_topn=False` is the
+        re-run after a `topn_tie_overflow` decline: the same statement
+        planned without its fused TopN."""
         t_prep = time.perf_counter_ns()
         # reset per dispatch: a stale reason from a PREVIOUS statement
         # must never leak into this one's enforce_mpp warning / EXPLAIN
@@ -1111,7 +1164,8 @@ class MPPEngine:
         tick = gate if gate is not None else (lambda: None)
         if fused is None:
             fused = variables.get("tidb_tpu_mpp_fused", "ON") == "ON"
-        meta = self.prepare(mplan, scans, variables, gate=gate, fused=fused)
+        meta = self.prepare(mplan, scans, variables, gate=gate, fused=fused,
+                            use_topn=use_topn)
         if meta is None:
             self._fallback(self._decline_key)
             TL.boundary("mpp.prepare", t_prep, time.perf_counter_ns())
@@ -1235,7 +1289,18 @@ class MPPEngine:
             if demote is not None:
                 agm["mode"], agm["rp_ck"] = "rowpos", None
                 agm["clustered_reason"] = demote
-        TL.boundary("mpp.prepare", t_prep, time.perf_counter_ns())
+        # what the spans and EXPLAIN ANALYZE say of the aggregation: the
+        # mode, how many ORDER BY keys the program's TopN fused (dense
+        # ships every group and fuses none), and why a faster mode or
+        # the fused TopN was declined
+        said["agg_mode"] = agm["mode"] if agm is not None else "rows"
+        said["topn_keys"] = (mplan.topn_keys
+                             if agm is not None and agm.get("topn") else 0)
+        if use_topn:
+            said["decline"] = (agm or {}).get("clustered_reason") or ""
+        self.last_agg = {k: said[k] for k in ("agg_mode", "topn_keys", "decline")}
+        TL.boundary("mpp.prepare", t_prep, time.perf_counter_ns(),
+                    **self.last_agg)
         for s in scans:
             tick()  # each scan's lane build/upload is O(table bytes)
             is_sharded = id(s.frag) in sharded
@@ -1369,11 +1434,17 @@ class MPPEngine:
         packed = np.asarray(out)
         t_fin = time.perf_counter_ns()
         TL.boundary("mpp.fetch", t_fetch, t_fin, d2h_bytes=int(packed.nbytes))
+        tie_overflow = False
         try:
             tick()
             outs = unpack_rows(packed)
             dropped = int(outs[-1][0])
             outs = outs[:-1]
+            if said["topn_keys"] > 1:
+                # a multi-key TopN program's last lane: some device held
+                # more groups tying with its k-th than it had candidates
+                tie_overflow = bool(np.any(outs[-1]))
+                outs = outs[:-1]
             if dropped:
                 # skewed keys overflowed an exchange bucket: the run is
                 # incomplete — never surface it; host path takes over
@@ -1381,19 +1452,37 @@ class MPPEngine:
                                f"exchange bucket overflow ({dropped} rows)")
                 said["outcome"] = "capacity_overflow"
                 return None
-            # one bump per SUCCESSFUL mesh dispatch (see the outcome block
-            # up top): retried attempts and fallbacks never reach here
-            M.TPU_MPP_FUSED.inc(outcome=outcome)
-            said["outcome"] = "ok"
-            if meta["agg"] is not None:
-                if meta["agg"]["mode"] == "sorted":
-                    return self._finalize_topk(mplan, meta, outs), True
-                if meta["agg"]["mode"] in ("rowpos", "clustered"):
-                    return self._finalize_rowpos(mplan, meta, scans, outs), True
-                return self._finalize_agg(mplan, meta, outs), True
-            return self._finalize_rows(mplan, meta, scans, outs), meta["agg"] is not None
+            if not tie_overflow:
+                # one bump per SUCCESSFUL mesh dispatch (see the outcome
+                # block up top): retried attempts, fallbacks and the
+                # declined first pass of a tie overflow never reach here
+                M.TPU_MPP_FUSED.inc(outcome=outcome)
+                said["outcome"] = "ok"
+                if meta["agg"] is not None:
+                    if meta["agg"]["mode"] == "sorted":
+                        return self._finalize_topk(mplan, meta, outs), True
+                    if meta["agg"]["mode"] in ("rowpos", "clustered"):
+                        return self._finalize_rowpos(mplan, meta, scans, outs), True
+                    return self._finalize_agg(mplan, meta, outs), True
+                return self._finalize_rows(mplan, meta, scans, outs), meta["agg"] is not None
         finally:
             TL.boundary("mpp.finalize", t_fin, time.perf_counter_ns())
+        # the candidates cannot hold every group that ties into the
+        # answer on the first key: a typed decline, counted like every
+        # other, and the statement runs again inside the same launch as
+        # if no TopN were fused (the program the parent of this mode
+        # ran: joined rows to the host, which aggregates and cuts them;
+        # exact by construction)
+        detail = (f"fused TopN: more than {self.TOPN_TIE_SLACK} groups beside the "
+                  f"{meta['agg']['topn'][2]} asked for tie on the first ORDER BY key")
+        self._fallback("topn_tie_overflow", detail)
+        said["decline"] = "topn_tie_overflow"
+        try:
+            return self._execute(mplan, scans, mesh, variables, axis, gate, fused,
+                                 build_cache, schema_ver, said, use_topn=False)
+        finally:
+            # the statement's reason is the decline, not the re-run's notes
+            self._decline("topn_tie_overflow", detail)
 
     @staticmethod
     def _build_lut(lvl, scan_of_joined) -> np.ndarray:
@@ -1519,6 +1608,9 @@ class MPPEngine:
             return acc, kv
 
         drop_acc: list = []  # per-exchange local drop counts (psum'd at end)
+        # per LUT build scan: the build row position every probe row's key
+        # maps to, matched or not (the clustered agg reads its group ids here)
+        lut_pos: dict = {}
 
         # `jax.named_scope` on the stages below names their ops in the
         # device trace ("exchange", "join.lut", "group", "topk"): op
@@ -1577,7 +1669,7 @@ class MPPEngine:
             return new_map, mask_out, new_rowids
 
         @jax.named_scope("join.lut")
-        def lut_join(frag, lvl, flat, pmap_, pmask, prow, bmap, bmask, brow):
+        def lut_join(frag, lvl, flat, pmap_, pmask, prow, bmap, bmask):
             """Fused-level probe: pack the probe keys in the BUILD-local
             domain and gather the device-resident LUT — no build sort, no
             searchsorted, no exchange (the structure is replicated). Out-
@@ -1599,30 +1691,85 @@ class MPPEngine:
                 pkv = ok if pkv is None else (pkv & ok)
             pos = lut[jnp.clip(acc, 0, lvl.lut_dom - 1)]
             bsel = jnp.clip(pos.astype(jnp.int64), 0, B - 1)
+            lut_pos[id(frag.build)] = bsel
             match = pmask & pkv & (pos >= 0) & bmask[bsel]
             merged = dict(pmap_)
             for j, (d, v) in bmap.items():
                 merged[j] = (d[bsel], v[bsel] & match)
             rowids = dict(prow)
-            rowids[id(frag.build)] = jnp.where(match, brow[id(frag.build)][bsel], -1)
+            # the LUT holds build ROW POSITIONS and a LUT build is never
+            # sharded or prefiltered, so its rowid lane is arange: the
+            # position IS the row id, no gather of the lane needed (a
+            # stream-long int64 gather is the dearest op of the program)
+            rowids[id(frag.build)] = jnp.where(match, bsel, -1)
             return merged, match, rowids
+
+        # joined-schema columns something above the joins reads: aggregate
+        # arguments, group keys (the rowpos modes decode theirs on the host
+        # from the group level's build lanes), every level's probe keys
+        # and residual conditions
+        read_above: set[int] = set()
+        for lv in levels.values():
+            read_above.update(lv.frag.probe_keys)
+            for c in lv.r_post:
+                c.collect_columns(read_above)
+        if agg is not None:
+            for ra in agg_meta["r_args"]:
+                for x in ra:
+                    x.collect_columns(read_above)
+            if agg_meta["mode"] not in ("rowpos", "clustered"):
+                read_above.update(g.idx for g in agg.group_by)
+
+        def filters_the_build_below(frag):
+            """True when LUT level `frag` only FILTERS the build side of
+            the LUT level right under it: both inner, its probe keys all
+            columns of that build scan, no residual condition, and
+            nothing above reads a column or the row id of its own build
+            scan (Q3: CUSTOMER keeps the ORDERS rows of one segment).
+            Such a level probes the few build rows once instead of every
+            stream row: its match folds into the lower level's build
+            mask, and its stream-long gathers (the probe key's lane, the
+            LUT, the mask) shrink to build-long ones."""
+            p = frag.probe
+            if not (agg is not None and isinstance(p, JoinFrag)):
+                return False
+            lvl, low = levels[id(frag)], levels[id(p)]
+            b, pb = frag.build, p.build
+            return (lvl.use_lut and low.use_lut and frag.kind == p.kind == "inner"
+                    and not lvl.r_post
+                    and all(pb.side_offset <= j < pb.side_offset + pb.n_cols
+                            for j in frag.probe_keys)
+                    and not any(b.side_offset <= j < b.side_offset + b.n_cols
+                                for j in read_above)
+                    and agg_meta.get("rp_fid") != id(b))
+
+        def residual(lvl, merged, mask):
+            for c in lvl.r_post:
+                d, v = eval_dev(c, merged)
+                d = jnp.broadcast_to(d, mask.shape) if getattr(d, "ndim", 0) == 0 else d
+                v = jnp.broadcast_to(v, mask.shape) if getattr(v, "ndim", 0) == 0 else v
+                mask = mask & v & (d != 0)
+            return mask
 
         def join_stage(frag, flat):
             if isinstance(frag, ScanFrag):
                 return scan_stage(id(frag), flat)
+            lvl = levels[id(frag)]
+            if filters_the_build_below(frag):
+                low = frag.probe
+                pmap_, pmask, prow = join_stage(low.probe, flat)
+                lmap, lmask, _ = scan_stage(id(low.build), flat)
+                bmap, bmask, _ = scan_stage(id(frag.build), flat)
+                _, lmask, _ = lut_join(frag, lvl, flat, lmap, lmask, {}, bmap, bmask)
+                merged, mask, rowids = lut_join(
+                    low, levels[id(low)], flat, pmap_, pmask, prow, lmap, lmask)
+                return merged, residual(levels[id(low)], merged, mask), rowids
             pmap_, pmask, prow = join_stage(frag.probe, flat)
             bmap, bmask, brow = scan_stage(id(frag.build), flat)
-            lvl = levels[id(frag)]
             if lvl.use_lut:
                 merged, mask, rowids = lut_join(
-                    frag, lvl, flat, pmap_, pmask, prow, bmap, bmask, brow
-                )
-                for c in lvl.r_post:
-                    d, v = eval_dev(c, merged)
-                    d = jnp.broadcast_to(d, mask.shape) if getattr(d, "ndim", 0) == 0 else d
-                    v = jnp.broadcast_to(v, mask.shape) if getattr(v, "ndim", 0) == 0 else v
-                    mask = mask & v & (d != 0)
-                return merged, mask, rowids
+                    frag, lvl, flat, pmap_, pmask, prow, bmap, bmask)
+                return merged, residual(lvl, merged, mask), rowids
             pkey, pkv = pack_keys(pmap_, frag.probe_keys, lvl)
             bkey, bkv = pack_keys(bmap, frag.build_keys, lvl)
             if frag.exchange == HASH:
@@ -1715,6 +1862,49 @@ class MPPEngine:
                 v = jnp.broadcast_to(v, mask.shape) if getattr(v, "ndim", 0) == 0 else v
                 mask = mask & v & (d != 0)
             return merged, mask, rowids
+
+        # the fused TopN (sorted / rowpos / clustered modes): the device
+        # cuts the groups by the FIRST ORDER BY key alone. One key: the k
+        # best a device are all the answer can need (a tie on the only
+        # key may fall either way). More keys: k + TOPN_TIE_SLACK
+        # candidates a device, and one more output lane that says
+        # whether MORE groups than that tie with the k-th — if not,
+        # every group that can reach the answer by its further keys is
+        # among the candidates and the host TopN decides exactly; if so,
+        # execute() declines (`topn_tie_overflow`) and runs the
+        # statement without the fused TopN
+        topn = agg_meta.get("topn") if agg_meta is not None else None
+        if topn:
+            agg_idx, desc, k = topn[:3]
+            multi_key = len(topn) > 3
+            # a sum over an argument that can be NULL is NULL for a group
+            # none of whose rows had a value: it orders as SQL orders NULL
+            # (first ascending, last descending), not as its 0 lane
+            nullable_sum = (agg.aggs[agg_idx].name == "sum" and not (
+                agg_meta["r_args"][agg_idx]
+                and self._never_null(agg_meta["r_args"][agg_idx][0])))
+            n_cands = k + self.TOPN_TIE_SLACK if multi_key else k
+
+        def topn_score(lanes_, valid, base=0):
+            lp = self._topn_lane_pos(agg.aggs, agg_idx, base)
+            return self._topk_score(lanes_[lp], valid, desc,
+                                    lanes_[lp + 1] if nullable_sum else None)
+
+        def tie_lane(score, tvals):
+            """() for a one-key TopN. Else the tie-overflow lane: true
+            where this device holds more groups scoring at least its
+            k-th best than the `tvals` it returns (a k-th at the floor
+            means fewer than k groups: all of them are returned)."""
+            if not multi_key:
+                return ()
+            kk = int(tvals.shape[0])
+            if kk >= int(score.shape[0]):
+                over = jnp.zeros((), bool)  # every slot is a candidate
+            else:
+                kth = tvals[min(k, kk) - 1]
+                over = ((kth > self._score_floor(score.dtype))
+                        & (jnp.sum(score >= kth) > kk))
+            return (jnp.broadcast_to(over, (kk,)),)
 
         @jax.named_scope("group")
         def sorted_agg_stage(lanemap, mask):
@@ -1817,15 +2007,13 @@ class MPPEngine:
             @jax.named_scope("topk")
             def finish_topk(fkey, fvals, fvalid):
                 # device top-k on the fused ORDER BY aggregate
-                agg_idx, desc, k = agg_meta["topn"]
-                lane_pos = self._topn_lane_pos(agg.aggs, agg_idx)
                 valid = fvalid
-                score = self._topk_score(fvals[lane_pos], valid, desc)
-                kk = min(k, int(score.shape[0]))
-                _, idx = jax.lax.top_k(score, kk)
+                score = topn_score(fvals, valid)
+                kk = min(n_cands, int(score.shape[0]))
+                tvals, idx = jax.lax.top_k(score, kk)
                 outs = [fkey[idx], valid[idx]]
                 outs.extend(v[idx] for v in fvals)
-                return tuple(outs)
+                return tuple(outs) + tie_lane(score, tvals)
 
             rows_local = int(code.shape[0])
             if n_dev == 1:
@@ -1894,29 +2082,27 @@ class MPPEngine:
                         full.append(jax.lax.dynamic_slice_in_dim(r, start, blk, 0))
                 didx = jax.lax.axis_index(axis)
             blk = full[0].shape[0]
-            agg_idx, desc, k = agg_meta["topn"]
             # presence: the dedicated lane 0 when one was scattered, else
             # the agg count lane _prepare_agg_rowpos proved equal to it
             gcount = full[0] if base == 1 else full[pres]
             valid = gcount > 0
-            score = self._topk_score(
-                full[self._topn_lane_pos(agg.aggs, agg_idx, base)], valid,
-                desc)
+            score = topn_score(full, valid, base)
             # k widened to the output lane count: pack_rows ships one
             # (n_outs, L) matrix and needs L >= n_outs (extra candidate
             # groups are harmless — the host TopN re-cuts exactly)
-            kk = min(max(k, len(full) + 4), blk)
+            kk = min(max(n_cands, len(full) + 4), blk)
             with jax.named_scope("topk"):
-                _, idx = jax.lax.top_k(score, kk)
+                tvals, idx = jax.lax.top_k(score, kk)
+                tie = tie_lane(score, tvals)
             gidx = (didx.astype(jnp.int64) * blk + idx.astype(jnp.int64))
             outs = [jnp.where(valid[idx], gidx, -1), valid[idx]]
             # ship the agg lanes only — a dedicated presence lane (base
             # == 1) served its purpose on device and stays there
             outs.extend(f[idx] for f in full[base:])
-            return tuple(outs)
+            return tuple(outs) + tie
 
         @jax.named_scope("group")
-        def clustered_agg_stage(lanemap, mask, rowids):
+        def clustered_agg_stage(lanemap, mask):
             """Clustered fused-chain aggregation (PR 11): the stream
             arrives SORTED by the group level's probe key and shard-split
             at run boundaries (_clustered_splits), so each group is one
@@ -1927,7 +2113,6 @@ class MPPEngine:
             no exchange anywhere: each device top-ks its own complete
             groups and the host merges n_dev·k exact candidates through
             the same rowpos finalize."""
-            rid = rowids[agg_meta["rp_fid"]]
             kd, _kv = lanemap[agg_meta["rp_ck"]]
             nloc = mask.shape[0]
             idx = jnp.arange(nloc, dtype=jnp.int32)
@@ -1941,6 +2126,11 @@ class MPPEngine:
                 prev = jnp.concatenate([jnp.zeros(1, c.dtype), c[:-1]])
                 return c[rend] - prev
 
+            def run_count(okm):
+                # a shard holds fewer than 2^31 rows: the count lanes scan
+                # and gather as int32 (an int64 gather is two on the chip)
+                return run_sum(okm.astype(jnp.int32))
+
             pres = agg_meta["rp_presence"]
             lanes = []
             for a, ra in zip(agg.aggs, agg_meta["r_args"]):
@@ -1953,35 +2143,32 @@ class MPPEngine:
                     v = jnp.ones(mask.shape, bool)
                 ok = mask & v
                 if a.name == "count":
-                    lanes.append(run_sum(ok.astype(jnp.int64)))
+                    lanes.append(run_count(ok))
                 else:  # sum / avg — eligibility excluded min/max
                     if d.dtype in (jnp.float64, jnp.float32):
                         lanes.append(run_sum(jnp.where(ok, d, 0.0)))
                     else:  # widen BEFORE the cumsum: narrow codec lanes
                         lanes.append(run_sum(
                             jnp.where(ok, d.astype(jnp.int64), 0)))
-                    lanes.append(run_sum(ok.astype(jnp.int64)))
+                    lanes.append(run_count(ok))
             base = 0
             if pres is None:
-                lanes.insert(0, run_sum(mask.astype(jnp.int64)))
+                lanes.insert(0, run_count(mask))
                 base = 1
             match_cnt = lanes[0] if base == 1 else lanes[pres]
-            # group id: matched rows all carry the SAME build row
-            # position (unique build keys), so run_sum(rid·match) /
-            # match-count recovers it exactly without a segmented max
-            rid_sum = run_sum(jnp.where(mask, rid, 0).astype(jnp.int64))
-            gpos = jnp.where(match_cnt > 0,
-                             rid_sum // jnp.maximum(match_cnt, 1), -1)
-            agg_idx, desc, k = agg_meta["topn"]
+            # group id: the build row position the run's key probes to.
+            # A run is one key, the LUT position depends on the key
+            # alone, so every matched row of the run carries the position
+            # its first row has — no run total of the row ids needed
+            gpos = jnp.where(match_cnt > 0, lut_pos[agg_meta["rp_fid"]], -1)
             # only a run's FIRST position represents its group — interior
-            # positions carry the same totals and would duplicate it
+            # positions carry the tails of the totals
             valid = first & (match_cnt > 0)
-            score = self._topk_score(
-                lanes[self._topn_lane_pos(agg.aggs, agg_idx, base)], valid,
-                desc)
-            kk = min(max(k, len(lanes) - base + 6), nloc)
+            score = topn_score(lanes, valid, base)
+            kk = min(max(n_cands, len(lanes) - base + 6), nloc)
             with jax.named_scope("topk"):
                 tvals, ti = self._block_topk(score, kk)
+                tie = tie_lane(score, tvals)
             # a shard with fewer than kk scoreable groups exhausts
             # _block_topk: once everything above the floor is taken it
             # returns floor-valued picks whose INDEX can repeat an
@@ -1996,7 +2183,7 @@ class MPPEngine:
             tvalid = valid[ti] & (tvals > floor)
             outs = [jnp.where(tvalid, gpos[ti], -1), tvalid]
             outs.extend(l[ti] for l in lanes[base:])
-            return tuple(outs)
+            return tuple(outs) + tie
 
         def kernel(*flat):
             drop_acc.clear()
@@ -2026,7 +2213,7 @@ class MPPEngine:
             if agg_meta["mode"] == "rowpos":
                 return with_drops(rowpos_agg_stage(lanemap, mask, rowids))
             if agg_meta["mode"] == "clustered":
-                return with_drops(clustered_agg_stage(lanemap, mask, rowids))
+                return with_drops(clustered_agg_stage(lanemap, mask))
             # fused partial aggregation + psum (exact int/scaled-decimal)
             with jax.named_scope("group"):
                 nseg = agg_meta["nseg"]
@@ -2052,17 +2239,32 @@ class MPPEngine:
         return jax.jit(sm)
 
     @staticmethod
-    def _topk_score(val, valid, desc):
+    def _topk_score(val, valid, desc, cnt=None):
         """Sort lane for the fused ORDER-BY-agg top-k: invalid slots
         sink to the dtype floor. The ascending negation happens INSIDE
         the where — negating the where'd result would send every
         invalid slot to the TOP of the order and crowd the real groups
-        out of the k slots. All three agg modes (sorted finish, rowpos,
-        clustered) share this helper so the sentinel semantics cannot
-        diverge."""
-        if val.dtype in (jnp.float64, jnp.float32):
-            return jnp.where(valid, val if desc else -val, -jnp.inf)
-        return jnp.where(valid, val if desc else -val, -I64_MAX)
+        out of the k slots. `cnt` (the sum's count lane, given when its
+        argument can be NULL) marks the groups whose sum is NULL: they
+        order as SQL orders NULL, above every value ascending and below
+        every value (still above the invalid slots) descending. All
+        three agg modes (sorted finish, rowpos, clustered) share this
+        helper so the sentinel semantics cannot diverge."""
+        if val.dtype not in (jnp.float64, jnp.float32):
+            val = val.astype(jnp.int64)  # the clustered count lanes are int32
+        score = val if desc else -val
+        if cnt is not None:
+            if val.dtype in (jnp.float64, jnp.float32):
+                null = -jnp.finfo(val.dtype).max if desc else jnp.inf
+            else:
+                null = -I64_MAX + 1 if desc else I64_MAX
+            score = jnp.where(cnt > 0, score, null)
+        return jnp.where(valid, score, MPPEngine._score_floor(val.dtype))
+
+    @staticmethod
+    def _score_floor(dtype):
+        """The score of a slot that holds no group (see _topk_score)."""
+        return -jnp.inf if dtype in (jnp.float64, jnp.float32) else -I64_MAX
 
     @staticmethod
     def _topn_lane_pos(aggs, agg_idx, base=0):
@@ -2214,8 +2416,10 @@ class MPPEngine:
             out_fts.extend(ft for _, ft in a.partial_final_types())
         cols: list[Column] = []
         oi = 0
-        for g in agg.group_by:
-            sd, off = soj[g.idx]
+        # a group-by column that is the level's probe key reads the build
+        # key's lane (equal on every joined row): rows are BUILD positions
+        for j in agg_meta["rp_gsrc"]:
+            sd, off = soj[j]
             data = sd.data[off][rows]
             gvalid = sd.valid[off][rows]
             if data.dtype == object:

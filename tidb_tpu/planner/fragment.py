@@ -76,16 +76,32 @@ class MPPPlan:
     agg: Aggregation | None  # fused partial aggregation, if any
     out_cols: list  # joined schema (probe cols then build cols, leftmost first)
     join_node: Join = None  # original plan node (host fallback path)
-    # fused ORDER BY <agg output> LIMIT k (ref: pushed TopN over the MPP
-    # gather, planner/core/task.go attach2Task TopN pushdown): set by the
-    # Limit(Sort(...)) builder when the sort key is a single sum/count
-    # aggregate. Enables the sorted (wide-key) device agg mode, whose
-    # output is k groups per device instead of the joined rows.
-    topn: tuple | None = None  # (agg_idx, desc: bool, k: int)
+    # fused ORDER BY <agg output>[, <further keys>] LIMIT k (ref: pushed
+    # TopN over the MPP gather, planner/core/task.go attach2Task TopN
+    # pushdown): set by the Limit(Sort(...)) builder when the FIRST sort
+    # key is a sum/count aggregate and every further key is a group-by
+    # column or another such aggregate. Enables the device agg modes
+    # whose output is the candidate groups per device instead of the
+    # joined rows. One key: (agg_idx, desc: bool, k: int), the form (and
+    # program key) it always had. More keys: (agg_idx, desc, k, more),
+    # more = ((kind, idx, desc), ...) with kind "group" (idx into
+    # agg.group_by) or "agg" (idx into agg.aggs). The device cuts by the
+    # first key alone and returns every group that can tie into the
+    # answer; the host TopN above the gather orders by the whole list.
+    topn: tuple | None = None
+
+    @property
+    def topn_keys(self) -> int:
+        """How many ORDER BY keys the fused TopN carries (0: none)."""
+        if self.topn is None:
+            return 0
+        return 1 + (len(self.topn[3]) if len(self.topn) > 3 else 0)
 
     def explain(self, indent: int = 0) -> str:
         """Fragment-tree rendering for EXPLAIN (sender/receiver parity)."""
         lines: list[str] = []
+        if self.topn is not None:
+            lines.append(f"TopN(keys:{self.topn_keys}, limit:{self.topn[2]})")
         if self.agg is not None:
             lines.append("PartialAggregation(psum)")
         def walk(f, depth):

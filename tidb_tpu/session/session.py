@@ -4177,10 +4177,18 @@ class Session:
             mline = (
                 f"mpp: dispatches:{d['mpp_tasks']} fallbacks:{d['mpp_fallbacks']}"
             )
-            reason = getattr(cop.mpp, "last_fallback_reason", "") \
-                if getattr(cop, "_mpp", None) is not None else ""
+            mpp = cop.mpp if getattr(cop, "_mpp", None) is not None else None
+            reason = getattr(mpp, "last_fallback_reason", "")
             if d.get("mpp_fallbacks") and reason:
                 mline += f" reason:[{reason}]"
+            la = getattr(mpp, "last_agg", None)
+            if la:
+                # how the mesh aggregated: the mode, the ORDER BY keys of
+                # the TopN fused into the program, and the typed reason
+                # a faster mode or the fused TopN was declined
+                mline += f" agg:{la['agg_mode']} topn_keys:{la['topn_keys']}"
+                if la["decline"]:
+                    mline += f" decline:{la['decline']}"
             lines.append(mline)
         if d.get("window_device_tasks") or d.get("window_fallbacks"):
             # device-window runs vs typed declines (the per-operator
