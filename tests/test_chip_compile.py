@@ -227,7 +227,13 @@ def test_q3_two_key_topn_program_compiles_for_one_chip(one_chip, topo, monkeypat
     """Q3 as TPC-H writes it (`ORDER BY revenue DESC, o_orderdate LIMIT
     10`, grouped by the stream's `l_orderkey`): the clustered program with
     the fused two-key TopN (the block top-k of 16 candidates and the
-    tie-overflow lane), as `tpch_q3_streams` runs it on one chip."""
+    tie-overflow lane), as `tpch_q3_streams` runs it on one chip. By the
+    compiler's own list (the optimized HLO) the program gathers the
+    stream twice, for the ORDERS LUT and its mask under `join.lut/`, and
+    never under `group/`: the run totals are shifted adds (ISSUE 31;
+    three more gathers at every run's end before it)."""
+    import re
+
     from jax.sharding import Mesh, NamedSharding
 
     from tidb_tpu.models import tpch
@@ -253,4 +259,13 @@ def test_q3_two_key_topn_program_compiles_for_one_chip(one_chip, topo, monkeypat
         jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(chip_mesh, spec))
         for a, spec in zip(args, in_specs)
     ]
-    prog.lower(*shapes).compile()
+    text = prog.lower(*shapes).compile().as_text()
+    at = 0
+    for _fid, offs, is_sharded, _pref in scan_arg_meta:
+        if is_sharded:
+            stream = args[at].shape[0]  # the one sharded scan: the compacted, padded lineitem
+        at += 2 + 2 * len(offs)
+    assert meta["agg"]["rp_run_bound"] == 16 and stream == 262_144
+    scopes = [re.search(r'op_name="([^"]*)"', line).group(1) for line in text.splitlines()
+              if re.search(rf"= \w+\[{stream}[,\]][^=]* gather\(", line)]
+    assert len(scopes) == 2 and all("join.lut/" in sc and "group/" not in sc for sc in scopes), scopes

@@ -330,36 +330,95 @@ def test_explain_says_mode_keys_and_decline(db):
     assert "agg:rows topn_keys:0 decline:topn_tie_overflow" in text
 
 
-@pytest.mark.parametrize("fused,golden", [
-    ("ON", "1f22616198e61ea2dcb781ca73c8d03ce09acb1a8f05ee45aa348897464669b9"),
-    ("OFF", "f9bd4f4fb185a9055630d315d84456a98bc178cf25b805b2b25fe0c447542005"),
+@pytest.mark.parametrize("fused,golden,bound", [
+    ("ON", "1f22616198e61ea2dcb781ca73c8d03ce09acb1a8f05ee45aa348897464669b9", "16"),
+    ("OFF", "f9bd4f4fb185a9055630d315d84456a98bc178cf25b805b2b25fe0c447542005", "None"),
 ])
-def test_one_key_program_key_is_unchanged(fused, golden):
+def test_one_key_program_key_is_unchanged(fused, golden, bound):
     """The one-key TopN is the special case and compiles the program it
     compiled before the list: `_program_key` of `models.tpch.Q3` (ORDER
     BY revenue DESC LIMIT 10) at 60,000 rows, clustered and (fusion off)
     sorted, on this suite's mesh of eight virtual devices, as the
-    parent of ISSUE 30 computed them."""
+    parent of ISSUE 30 computed them. ISSUE 31 put ONE more string at
+    the end of what the key hashes, the clustered aggregate's run bound
+    (`None` in every other mode): without it the key is still the
+    golden one, letter for letter."""
+    import hashlib
+
     s = Session()
     tpch.setup_tpch(s, 60_000)
     s.vars["tidb_enable_cop_result_cache"] = "OFF"
     s.vars["tidb_allow_mpp"] = "ON"
     s.vars["tidb_cop_engine"] = "auto"
     s.vars["tidb_tpu_mpp_fused"] = fused
-    keys = []
-    orig = MPPEngine._program_key
+    parts = []
+    orig = MPPEngine._program_key_parts
 
-    def spy(self, *a, **k):
-        keys.append(orig(self, *a, **k))
-        return keys[-1]
+    def spy(*a, **k):
+        parts.append(orig(*a, **k))
+        return parts[-1]
 
-    MPPEngine._program_key = spy
+    MPPEngine._program_key_parts = staticmethod(spy)
     try:
         rows = s.must_query(tpch.Q3)
     finally:
+        MPPEngine._program_key_parts = staticmethod(orig)
+    (p,) = parts
+    assert len(rows) == 10 and s.cop.mpp.last_agg["topn_keys"] == 1
+    assert p[-1] == bound  # this data's longest run of l_orderkey behind the filter: 9 to 16
+    assert hashlib.sha256("|".join(p[:-1]).encode()).hexdigest() == golden
+    assert list(s.cop.mpp._programs) == [hashlib.sha256("|".join(p).encode()).hexdigest()]
+
+
+def _q3_streams_2_texts():
+    """The two texts of the benchmark's `q3_streams_2`, as its harness sends them."""
+    import json
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark", "traffic", "q3_streams_2.json")
+    with open(path) as f:
+        mix = json.load(f)
+    return [mix["templates"][st["template"]]["sql"].format(**st["params"])
+            for stream in mix["streams"] for st in stream["statements"]]
+
+
+def test_the_run_bound_is_in_the_program_key_and_seeds_share_it():
+    """The clustered aggregate's pass count comes from the table's data
+    (the longest run of the compacted stream's key lane, up to a power of
+    two) and is part of `_program_key`. Each text of `q3_streams_2` has
+    ONE key over four seeds of `models/tpch` data at 200,000 rows (a text
+    bakes its ORDERS and CUSTOMER literals, so the two texts are two
+    programs, as before), and one bound serves both texts and every
+    seed: a seed never compiles anew. Another bound is another key."""
+    texts = _q3_streams_2_texts()
+    assert len(texts) == 2
+    seen = []  # (text, key, bound)
+    orig = MPPEngine._program_key
+
+    def spy(self, mplan, meta, *a, **k):
+        seen.append((orig(self, mplan, meta, *a, **k), meta["agg"]["rp_run_bound"]))
+        bumped = dict(meta, agg=dict(meta["agg"], rp_run_bound=2 * meta["agg"]["rp_run_bound"]))
+        assert orig(self, mplan, bumped, *a, **k) != seen[-1][0]
+        return seen[-1][0]
+
+    MPPEngine._program_key = spy
+    try:
+        for seed in (42, 7, 1234, 99):
+            s = Session()
+            tpch.setup_tpch(s, 200_000, seed=seed)
+            s.vars["tidb_enable_cop_result_cache"] = "OFF"
+            s.vars["tidb_allow_mpp"] = "ON"
+            s.vars["tidb_cop_engine"] = "auto"
+            for sql in texts:
+                s.must_query(sql)
+                assert s.cop.mpp.last_agg == {"agg_mode": "clustered", "topn_keys": 2, "decline": ""}
+                assert s.cop.mpp.last_run_passes == seen[-1][1].bit_length() - 1
+    finally:
         MPPEngine._program_key = orig
-    assert keys == [golden] and len(rows) == 10
-    assert s.cop.mpp.last_agg["topn_keys"] == 1
+    assert len(seen) == 8
+    assert {k for k, _ in seen[0::2]} == {seen[0][0]} and {k for k, _ in seen[1::2]} == {seen[1][0]}
+    assert seen[0][0] != seen[1][0]
+    assert {b for _, b in seen} == {16}  # models/tpch draws keys with replacement: runs pass TPC-H's seven
 
 
 @pytest.mark.parametrize("n,bucket", [
@@ -407,16 +466,16 @@ def _lowered_gathers(s, sql):
 def test_a_level_that_only_filters_probes_the_build_side(db):
     """Q3's CUSTOMER level keeps the ORDERS rows of one segment and gives
     nothing else: it probes the 6,000 ORDERS rows once, and the stream is
-    left with the ORDERS LUT, its mask and three run totals (the sum's,
-    its count's, COUNT(*)'s): five stream-long gathers, none for a row id
-    or for `o_custkey`. A text
-    that reads a CUSTOMER column above the joins keeps the level on the
-    stream, and stays exact."""
+    left with the ORDERS LUT and its mask: two stream-long gathers, none
+    for a row id or for `o_custkey`, and none for a run total (the sum's,
+    its count's, COUNT(*)'s: shifted adds since ISSUE 31, where three
+    gathers at every run's end were). A text that reads a CUSTOMER column
+    above the joins keeps the level on the stream, and stays exact."""
     s, tables = db
     rows, gathers = _lowered_gathers(s, q3_sql(REV_DATE, 10))
     assert_exact(rows, groups_of(tables, "BUILDING"), REV_DATE, 10)
     (stream,) = {n for n in gathers if n > 64 and n != N_ORDERS}  # a device's shard of the stream
-    assert gathers.count(stream) == 5, gathers
+    assert gathers.count(stream) == 2, gathers
     assert gathers.count(N_ORDERS) == 2, gathers  # the CUSTOMER LUT and mask, by ORDERS row
     sql = q3_sql(REV_DATE, 10).replace("COUNT(*) AS cnt", "COUNT(*) AS cnt, MAX(c.c_acctbal) AS bal")
     rows2, gathers2 = _lowered_gathers(s, sql)

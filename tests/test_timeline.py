@@ -701,7 +701,9 @@ class TestMppLaunchSpans:
     ])
     def test_launch_and_prepare_say_mode_keys_and_decline(self, q3, by, mode, keys, decline, fetches):
         """`mpp.launch` and `mpp.prepare` carry `agg_mode`, `topn_keys` and
-        `decline`. A fused two-key TopN fetches a few rows (under 64 KiB
+        `decline`, and in the clustered mode alone `run_passes` (log2 of
+        the longest key run the host counted, up to a power of two: the
+        shifted-add passes of the run totals, ISSUE 31). A fused two-key TopN fetches a few rows (under 64 KiB
         whatever the stream); unfused, the fetch is the joined stream's
         positions and grows with it. A tie overflow runs the statement
         twice inside ONE launch: the declined pass, then the rows pass."""
@@ -724,6 +726,9 @@ class TestMppLaunchSpans:
             {"agg_mode": mode, "topn_keys": keys, "decline": decline}
         if fetches == 2:  # the declined pass was the fused one
             assert prepares[0].args["agg_mode"] == "clustered" and prepares[0].args["topn_keys"] == 2
+        # behind the filter this fixture's longest run of l_orderkey is 5 to 8 rows: three passes
+        for e in [l] + prepares:
+            assert e.args.get("run_passes") == (3 if e.args["agg_mode"] == "clustered" else None), e.args
         stream = next(e.args["rows"] for e in evs if e.name == "mpp.gather")
         if keys:
             assert fetched[-1] < 64 * 1024

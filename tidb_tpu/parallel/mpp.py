@@ -165,8 +165,9 @@ class MPPEngine:
         self.last_fuse_reasons: dict[int, str] = {}
         # how the LAST dispatch aggregated (the args of its mpp.prepare
         # span, and EXPLAIN ANALYZE's mpp line): agg_mode, topn_keys,
-        # decline
+        # decline; and the clustered mode's run_passes, None in the others
         self.last_agg: dict = {}
+        self.last_run_passes: int | None = None
 
     HOST_CACHE_BYTES = 4 << 30
     STAT_CACHE_BYTES = 1 << 30
@@ -302,15 +303,20 @@ class MPPEngine:
         ideal n/n_dev split points move LEFT to the start of the key run
         they land in, so no group ever straddles two devices — each
         device's run totals are complete and the program needs no
-        cross-device reduce at all. Returns (splits, L, rawmax): n_dev+1
-        cut positions into the (possibly prefiltered) stream, the padded
-        per-shard length, and the pre-padding longest shard (the skew
-        signal the dispatch guard demotes on)."""
+        cross-device reduce at all. Returns (splits, L, rawmax, longest):
+        n_dev+1 cut positions into the (possibly prefiltered) stream, the
+        padded per-shard length, the pre-padding longest shard (the skew
+        signal the dispatch guard demotes on) and the longest key run of
+        that stream — the bound the program's run totals are summed to
+        (_run_totals), counted on the lane the program sees, so it
+        follows the table version exactly as the splits do."""
         def compute():
             k = sd.lane(koff)[0]
             if sel is not None:
                 k = k[sel]
             n = len(k)
+            edges = np.flatnonzero(k[1:] != k[:-1]) + 1
+            longest = int(np.diff(edges, prepend=0, append=n).max()) if n else 0
             splits = [0]
             for i in range(1, n_dev):
                 b = round(i * n / n_dev)
@@ -319,7 +325,7 @@ class MPPEngine:
                 splits.append(max(b, splits[-1]))
             splits.append(n)
             rawmax = max(splits[i + 1] - splits[i] for i in range(n_dev))
-            return (tuple(splits), self._row_bucket(rawmax), rawmax)
+            return (tuple(splits), self._row_bucket(rawmax), rawmax, longest)
 
         return self._cached_stat(sd, ("casplit", koff, sel_tag, n_dev), compute)
 
@@ -957,9 +963,9 @@ class MPPEngine:
                 lp += 2
         # clustered upgrade: when the stream is already SORTED by the
         # (single) probe key of the group level, equal keys are contiguous
-        # runs — run totals come from one cumsum + two run-boundary
-        # gathers per lane (the seg_reduce trick of the sorted mode,
-        # minus its argsort), and run-aligned shard splits
+        # runs — run totals come from shifted adds bounded by the
+        # longest run (_run_totals: the distance doubling seg_reduce
+        # does its min/max lanes by), and run-aligned shard splits
         # (_clustered_splits) keep every group whole on one device, so
         # the program needs NO B-wide scatter and NO cross-device reduce.
         # TPC-H lineitem is clustered by l_orderkey, so Q3-shape plans
@@ -969,7 +975,7 @@ class MPPEngine:
         if not (levels and all(l.use_lut for l in levels)):
             creason = "chain_not_fully_fused"
         elif not all(a.name in ("sum", "count", "avg") for a in agg.aggs):
-            creason = "agg_needs_minmax"  # min/max have no run-cumsum form
+            creason = "agg_needs_minmax"  # the run totals are sums
         elif len(lvl.frag.probe_keys) != 1:
             creason = "multi_column_stream_key"
         else:
@@ -1128,12 +1134,14 @@ class MPPEngine:
         `mpp.launch` say how the aggregation ran: `agg_mode` (dense |
         sorted | rowpos | clustered | rows: joined rows to the host),
         `topn_keys` (ORDER BY keys of the TopN fused into the program, 0
-        when none) and `decline` (the typed reason a faster mode or the
-        fused TopN was refused, "" when none)."""
+        when none), `decline` (the typed reason a faster mode or the
+        fused TopN was refused, "" when none) and, in the clustered mode
+        alone, `run_passes` (the shifted-add passes that sum a key run:
+        log2 of the longest run the host counted, up to a power of two)."""
         n_dev = mesh.shape[axis]
         trace = tracing.current_trace()
         said = {"outcome": "error", "program": "", "agg_mode": "",
-                "topn_keys": 0, "decline": ""}
+                "topn_keys": 0, "decline": "", "run_passes": None}
         t0 = time.perf_counter_ns()
         lane = f"mesh:{axis}={n_dev} ({threading.current_thread().name})"
         with TL.device_scope(lane), TL.launch_scope(tracing._next_id()):
@@ -1144,10 +1152,17 @@ class MPPEngine:
                 TL.boundary(
                     "mpp.launch", t0, time.perf_counter_ns(),
                     mesh=f"{axis}={n_dev}", program=said["program"],
-                    outcome=said["outcome"], agg_mode=said["agg_mode"],
-                    topn_keys=said["topn_keys"], decline=said["decline"],
+                    outcome=said["outcome"], **self._said_agg(said),
                     waiters=[trace.trace_id] if trace is not None else [],
                 )
+
+    @staticmethod
+    def _said_agg(said: dict) -> dict:
+        """What `mpp.prepare` and `mpp.launch` say of the aggregation."""
+        out = {k: said[k] for k in ("agg_mode", "topn_keys", "decline")}
+        if said["run_passes"] is not None:
+            out["run_passes"] = said["run_passes"]
+        return out
 
     def _execute(self, mplan, scans, mesh, variables, axis, gate, fused,
                  build_cache, schema_ver, said: dict, use_topn: bool = True):
@@ -1280,8 +1295,8 @@ class MPPEngine:
                 sh = (hashlib.sha256(repr(src).encode()).hexdigest()[:12]
                       if ssel is not None else "")
                 koff = soj[agm["rp_ck"]][1]
-                _, _, rawmax = self._clustered_splits(ss, koff, sh, n_dev,
-                                                      ssel)
+                _, _, rawmax, longest = self._clustered_splits(
+                    ss, koff, sh, n_dev, ssel)
                 sn = len(ssel) if ssel is not None else ss.n_rows
                 if rawmax > max(2 * -(-sn // n_dev),
                                 self.CLUSTERED_SKEW_MIN):
@@ -1289,6 +1304,12 @@ class MPPEngine:
             if demote is not None:
                 agm["mode"], agm["rp_ck"] = "rowpos", None
                 agm["clustered_reason"] = demote
+            else:
+                # the run totals' pass count follows the data: the longest
+                # key run the host counted, up to a power of two (TPC-H's
+                # one to seven lineitems an order: 8, three passes), part
+                # of the program key like every shape the kernel bakes
+                agm["rp_run_bound"] = self._run_bound(longest)
         # what the spans and EXPLAIN ANALYZE say of the aggregation: the
         # mode, how many ORDER BY keys the program's TopN fused (dense
         # ships every group and fuses none), and why a faster mode or
@@ -1299,8 +1320,12 @@ class MPPEngine:
         if use_topn:
             said["decline"] = (agm or {}).get("clustered_reason") or ""
         self.last_agg = {k: said[k] for k in ("agg_mode", "topn_keys", "decline")}
+        # clustered alone: how many shifted-add passes sum a run
+        said["run_passes"] = self.last_run_passes = (
+            agm["rp_run_bound"].bit_length() - 1
+            if said["agg_mode"] == "clustered" else None)
         TL.boundary("mpp.prepare", t_prep, time.perf_counter_ns(),
-                    **self.last_agg)
+                    **self._said_agg(said))
         for s in scans:
             tick()  # each scan's lane build/upload is O(table bytes)
             is_sharded = id(s.frag) in sharded
@@ -1326,7 +1351,7 @@ class MPPEngine:
                          and s.frag is self._stream_source(mplan.root))
             if clustered:
                 koff = soj[meta["agg"]["rp_ck"]][1]
-                splits, L, _ = self._clustered_splits(s, koff, h, n_dev, sel)
+                splits, L, _, _ = self._clustered_splits(s, koff, h, n_dev, sel)
                 total = n_dev * L
 
                 def lay(a, _sp=splits, _L=L):
@@ -1509,6 +1534,13 @@ class MPPEngine:
         return frag
 
     def _program_key(self, mplan, meta, scans, shapes, n_dev):
+        parts = self._program_key_parts(mplan, meta, scans, shapes, n_dev)
+        return hashlib.sha256("|".join(parts).encode()).hexdigest()
+
+    @staticmethod
+    def _program_key_parts(mplan, meta, scans, shapes, n_dev) -> list[str]:
+        """Everything the compiled kernel bakes in, as the strings the
+        program key hashes; the clustered run bound is the last."""
         parts = [repr(shapes), str(n_dev)]
         for s, sh in zip(scans, shapes):
             # a prefiltered scan's predicate resolved host-side: the
@@ -1540,9 +1572,11 @@ class MPPEngine:
                       a["mode"], repr(a.get("strides")), repr(a.get("topn")),
                       repr(a.get("rp_scan_idx")), repr(a.get("rp_rows")),
                       # presence-dedup layout and the clustered key lane
-                      # both bake into the kernel's lane indexing
-                      repr(a.get("rp_presence")), repr(a.get("rp_ck"))]
-        return hashlib.sha256("|".join(parts).encode()).hexdigest()
+                      # both bake into the kernel's lane indexing, the run
+                      # bound into its number of passes
+                      repr(a.get("rp_presence")), repr(a.get("rp_ck")),
+                      repr(a.get("rp_run_bound"))]
+        return parts
 
     # ------------------------------------------------------------- kernel
 
@@ -2106,30 +2140,21 @@ class MPPEngine:
             """Clustered fused-chain aggregation (PR 11): the stream
             arrives SORTED by the group level's probe key and shard-split
             at run boundaries (_clustered_splits), so each group is one
-            contiguous run wholly on one device. Run totals come from one
-            cumsum + two run-boundary gathers per lane (seg_reduce's
-            trick without its argsort — the data is already in key
-            order), and the program carries NO B-wide scatter, no psum,
-            no exchange anywhere: each device top-ks its own complete
-            groups and the host merges n_dev·k exact candidates through
-            the same rowpos finalize."""
+            contiguous run wholly on one device. Run totals land on each
+            run's first position by shifted adds (_run_totals), as many
+            passes as the longest run the host counted needs: no scan, no
+            gather of stream length, NO B-wide scatter, no psum, no
+            exchange anywhere. Each device top-ks its own complete groups
+            and the host merges n_dev·k exact candidates through the same
+            rowpos finalize."""
             kd, _kv = lanemap[agg_meta["rp_ck"]]
             nloc = mask.shape[0]
-            idx = jnp.arange(nloc, dtype=jnp.int32)
-            brk = kd[1:] != kd[:-1]
-            first = jnp.concatenate([jnp.ones(1, bool), brk])
-            last = jnp.concatenate([brk, jnp.ones(1, bool)])
-            rend = -jax.lax.cummax(jnp.where(last, -idx, -(nloc - 1))[::-1])[::-1]
+            first = jnp.concatenate([jnp.ones(1, bool), kd[1:] != kd[:-1]])
 
-            def run_sum(vals):
-                c = jnp.cumsum(vals)
-                prev = jnp.concatenate([jnp.zeros(1, c.dtype), c[:-1]])
-                return c[rend] - prev
-
-            def run_count(okm):
-                # a shard holds fewer than 2^31 rows: the count lanes scan
-                # and gather as int32 (an int64 gather is two on the chip)
-                return run_sum(okm.astype(jnp.int32))
+            def count_lane(okm):
+                # a shard holds fewer than 2^31 rows: int32 count lanes
+                # (an int64 add is two on the chip)
+                return okm.astype(jnp.int32)
 
             pres = agg_meta["rp_presence"]
             lanes = []
@@ -2143,18 +2168,20 @@ class MPPEngine:
                     v = jnp.ones(mask.shape, bool)
                 ok = mask & v
                 if a.name == "count":
-                    lanes.append(run_count(ok))
+                    lanes.append(count_lane(ok))
                 else:  # sum / avg — eligibility excluded min/max
                     if d.dtype in (jnp.float64, jnp.float32):
-                        lanes.append(run_sum(jnp.where(ok, d, 0.0)))
-                    else:  # widen BEFORE the cumsum: narrow codec lanes
-                        lanes.append(run_sum(
-                            jnp.where(ok, d.astype(jnp.int64), 0)))
-                    lanes.append(run_count(ok))
+                        lanes.append(jnp.where(ok, d, 0.0))
+                    else:  # widen BEFORE the adds: narrow codec lanes
+                        lanes.append(jnp.where(ok, d.astype(jnp.int64), 0))
+                    lanes.append(count_lane(ok))
             base = 0
             if pres is None:
-                lanes.insert(0, run_count(mask))
+                lanes.insert(0, count_lane(mask))
                 base = 1
+            # values off the mask are zero already, so a masked row or a
+            # shard's pad run adds nothing to the run it lies in
+            lanes = self._run_totals(kd, lanes, agg_meta["rp_run_bound"])
             match_cnt = lanes[0] if base == 1 else lanes[pres]
             # group id: the build row position the run's key probes to.
             # A run is one key, the LUT position depends on the key
@@ -2276,6 +2303,38 @@ class MPPEngine:
                 break
             lane_pos += 1 if a.name == "count" else 2
         return lane_pos
+
+    @staticmethod
+    def _run_bound(longest: int) -> int:
+        """The longest key run up to a power of two: what _run_totals
+        sums to, in log2 of it passes (one bucket, one program)."""
+        return 1 << max(longest - 1, 0).bit_length()
+
+    @staticmethod
+    def _run_totals(key, lanes, bound: int):
+        """Reverse segmented sums over a lane sorted by `key`: each
+        lane's total of a key run, at the run's FIRST position (the
+        positions behind it hold the tails). Distance doubling, as
+        seg_reduce does its min and max lanes: for d = 1, 2, 4, ... below
+        `bound`, a[i] += a[i + d] where position i + d holds i's key (the
+        lane is sorted, so equal ends are one run), the masks made once a
+        distance for every lane. Exact for runs of up to `bound`
+        positions, a power of two; a longer run would be cut short, so
+        the bound comes from a count of the data (_clustered_splits).
+        Integer lanes add the same integers in any order, wrap-around
+        included; a float lane adds a run's own values and nothing of
+        the stream before it."""
+        lanes = list(lanes)
+        d = 1
+        while d < min(bound, key.shape[0]):
+            same = jnp.concatenate([key[d:] == key[:-d], jnp.zeros((d,), bool)])
+            lanes = [
+                a + jnp.where(same, jnp.concatenate([a[d:], jnp.zeros((d,), a.dtype)]),
+                              jnp.zeros((), a.dtype))
+                for a in lanes
+            ]
+            d *= 2
+        return lanes
 
     @staticmethod
     def _block_topk(v, k: int, blk: int = 1024):

@@ -4187,6 +4187,8 @@ class Session:
                 # the TopN fused into the program, and the typed reason
                 # a faster mode or the fused TopN was declined
                 mline += f" agg:{la['agg_mode']} topn_keys:{la['topn_keys']}"
+                if mpp.last_run_passes is not None:
+                    mline += f" run_passes:{mpp.last_run_passes}"
                 if la["decline"]:
                     mline += f" decline:{la['decline']}"
             lines.append(mline)
