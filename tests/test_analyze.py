@@ -432,6 +432,69 @@ class TestFramework:
             assert len(s["reason"].strip()) >= 10
 
 
+# ------------------------------------------------------- the arrows point down
+
+ENGINE_LAYERS = {"copr", "parallel", "executor", "planner", "session", "sched"}
+
+
+def _tidb_modules():
+    """(path relative to the repo, its AST) of every module under tidb_tpu/."""
+    for root, _, files in os.walk(os.path.join(REPO, "tidb_tpu")):
+        for f in sorted(files):
+            if f.endswith(".py"):
+                path = os.path.join(root, f)
+                with open(path) as fh:
+                    yield os.path.relpath(path, REPO), ast.parse(fh.read())
+
+
+def _imported(rel: str, tree: ast.AST) -> set[str]:
+    """Absolute dotted names of everything `rel` imports, at any depth
+    (function-level imports included): `tidb_tpu.copr.tpu_engine`,
+    `tidb_tpu.copr.tpu_engine.TPUEngine`, ..."""
+    pkg = rel[:-3].split(os.sep)[:-1]
+    out = set()
+    for n in ast.walk(tree):
+        if isinstance(n, ast.Import):
+            out.update(a.name for a in n.names)
+        elif isinstance(n, ast.ImportFrom):
+            base = pkg[: len(pkg) - n.level + 1] if n.level else []
+            mod = ".".join(base + ([n.module] if n.module else []))
+            out.add(mod)
+            out.update(f"{mod}.{a.name}" for a in n.names)
+    return out
+
+
+def _arrow_breaches(rule: str) -> list[str]:
+    found = []
+    for rel, tree in _tidb_modules():
+        names = _imported(rel, tree)
+        if rule == "kernels_import_no_engine" and rel.startswith("tidb_tpu/kernels/"):
+            found += [f"{rel} imports {n}" for n in sorted(names)
+                      if n.startswith("tidb_tpu.") and n.split(".")[1] in ENGINE_LAYERS]
+        elif rule == "mpp_and_window_not_through_cop" and rel in (
+                "tidb_tpu/parallel/mpp.py", "tidb_tpu/executor/window_device.py"):
+            found += [f"{rel} imports {n}" for n in sorted(names)
+                      if n.startswith("tidb_tpu.copr.tpu_engine")]
+        elif rule == "one_cop_engine_a_store" and rel not in (
+                "tidb_tpu/copr/client.py", "tidb_tpu/sched/__init__.py"):
+            found += [f"{rel}:{c.lineno} calls TPUEngine(" for c in ast.walk(tree)
+                      if isinstance(c, ast.Call)
+                      and getattr(c.func, "id", getattr(c.func, "attr", "")) == "TPUEngine"]
+    return found
+
+
+@pytest.mark.parametrize("rule", [
+    "kernels_import_no_engine", "mpp_and_window_not_through_cop", "one_cop_engine_a_store"])
+def test_arrows_point_down(rule):
+    """`tidb_tpu/kernels/` lies below the engines: it imports none of
+    them, the MPP engine and the window executor reach it without going
+    through the cop engine, and a `TPUEngine` (devices, lanes, breakers,
+    program caches) is built for a store, never for a statement."""
+    if rule == "kernels_import_no_engine":
+        assert any(rel.startswith("tidb_tpu/kernels/") for rel, _ in _tidb_modules())
+    assert _arrow_breaches(rule) == []
+
+
 # ------------------------------------------------- runtime lock detector
 
 class TestLockWatch:

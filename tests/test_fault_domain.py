@@ -543,7 +543,7 @@ class TestBoundaryLint:
         """The static check t1.sh runs: device boundaries catch only the
         typed taxonomy (allowlisted sites excepted)."""
         res = subprocess.run(
-            [sys.executable, "tools/lint_boundaries.py"],
+            [sys.executable, "-m", "tools.analyze", "--only", "boundary-taxonomy"],
             capture_output=True, text=True, cwd=".",
         )
         assert res.returncode == 0, res.stderr

@@ -36,6 +36,16 @@ def sess():
         flag = "HI" if total > 50000 else "LO"
         rows.append(f"({o}, {cust}, {total / 100:.2f}, '{flag}')")
     s.execute("insert into ord values " + ",".join(rows))
+    # BIGINT UNSIGNED on both sides of 2^63 (NULL every 13th row), a DOUBLE
+    s.execute(
+        "create table wide (w_id bigint primary key, w_cust bigint, "
+        "w_u bigint unsigned, w_f double, w_v int)"
+    )
+    rows = []
+    for i in range(600):
+        u = "null" if i % 13 == 0 else str((1 << 63) + i * 7 if i % 2 else i * 5 + 1)
+        rows.append(f"({i}, {i % 90}, {u}, {float(rng.random() * 100)!r}, {i % 11})")
+    s.execute("insert into wide values " + ",".join(rows))
     return s
 
 
@@ -79,12 +89,33 @@ class TestBroadcastJoin:
         assert _sorted(mpp) == _sorted(host)
         assert len(mpp) == 4
 
-    def test_join_agg_avg_minmax(self, sess):
-        mpp, host = _both(
-            sess,
-            "select c_nation, avg(o_total), min(o_total), max(o_total) from ord join cust on o_cust = c_id group by c_nation",
-        )
-        assert _sorted(mpp) == _sorted(host)
+    @pytest.mark.parametrize("sql,float_col", [
+        ("select c_nation, avg(o_total), min(o_total), max(o_total) "
+         "from ord join cust on o_cust = c_id group by c_nation", None),
+        # the dense mode's MIN/MAX sentinels in the lane's own dtype: a
+        # uint64 lane with values from 2^63 up, groups with masked rows
+        # (the WHERE) and NULL arguments; eight segments, so the dense
+        # masked reduce, a DOUBLE SUM through it (last ulps may differ
+        # from the host's sequential sum)
+        ("select c_nation, min(w_u), max(w_u), count(w_u), sum(w_f) "
+         "from wide join cust on w_cust = c_id where w_v < 9 group by c_nation", 4),
+    ], ids=["decimal", "unsigned_above_2_63"])
+    def test_join_agg_avg_minmax(self, sess, sql, float_col):
+        sess.vars["tidb_enforce_mpp"] = "ON"
+        try:
+            mpp, host = _both(sess, sql)
+        finally:
+            sess.vars["tidb_enforce_mpp"] = "OFF"
+        assert sess.cop.mpp.last_agg["agg_mode"] == "dense"
+        assert sess.cop.mpp.fallbacks == 0, sess.cop.mpp.last_fallback_reason
+        mpp, host = _sorted(mpp), _sorted(host)
+        if float_col is not None:
+            assert any(int(r[2]) >= 1 << 63 for r in host)
+            for m, h in zip(mpp, host):
+                assert float(m[float_col]) == pytest.approx(float(h[float_col]), rel=1e-9)
+            mpp = [r[:float_col] for r in mpp]
+            host = [r[:float_col] for r in host]
+        assert mpp == host and len(host) == 7
 
     def test_build_side_filter_string(self, sess):
         mpp, host = _both(

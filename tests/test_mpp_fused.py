@@ -330,7 +330,7 @@ class TestFloatTopKExhaustion:
     than the top-k width (review findings on the PR 11 agg stages): the
     ascending float score must not send invalid slots to +inf (they
     would crowd every real group out of the k slots → empty result),
-    and _block_topk's exhausted floor-valued picks must not re-ship an
+    and block_topk's exhausted lowest-valued picks must not re-ship an
     already-taken valid position (the host partial merge would sum the
     duplicate → that group's total multiplied). Eight hot groups over a
     200k key domain force the wide-domain fused modes with ~1 group per
@@ -416,7 +416,7 @@ class TestFloatTopKExhaustion:
 class TestClusteredDispatchGuards:
     """The clustered upgrade is re-checked per dispatch (both guards
     depend on the data/predicate, not the plan): a TopN wider than
-    _block_topk's unrolled extraction can afford, or one dominant key
+    block_topk's unrolled extraction can afford, or one dominant key
     run that would drag every run-aligned shard toward the full stream
     length, demote the statement to the scatter-based rowpos mode with
     a typed reason — and stay exact."""

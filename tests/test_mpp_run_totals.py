@@ -1,11 +1,11 @@
 """The clustered aggregate's run totals (ISSUE 31): shifted adds bounded
 by the longest key run the host counted.
 
-`MPPEngine._run_totals` is held to a plain numpy reference kept here, on
+`kernels.primitives.run_totals` is held to a plain numpy reference kept here, on
 one device and on four virtual devices, over streams laid out as the
 engine lays them out (`_clustered_splits` run-aligned cuts, `_shard_pad`
 zero padding, values off the mask zeroed). The bound is never a knob: it
-is `_run_bound` of the longest run `_clustered_splits` counts, and a
+is `run_bound` of the longest run `_clustered_splits` counts, and a
 bound that is too small is a wrong answer, which the R + 1 case shows.
 The second half drives the same thing through SQL: an insert that
 lengthens the longest run past its bucket gives a new table version, a
@@ -17,6 +17,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from tidb_tpu.jaxenv import jax, jnp
+from tidb_tpu.kernels.primitives import run_bound, run_totals
 from tidb_tpu.models import tpch
 from tidb_tpu.parallel.mesh import make_mesh
 from tidb_tpu.parallel.mpp import MPPEngine
@@ -49,17 +50,17 @@ def run_totals_np(key, lanes):
 
 
 def engine_run_totals(key, lanes, mask, n_dev):
-    """The stream through the engine's own layout and `_run_totals`, a
+    """The stream through the engine's own layout and `run_totals`, a
     shard a device; returns (bound, totals in stream order)."""
     eng = MPPEngine()
     splits, L, _, longest = eng._clustered_splits(_Lane(key), 0, "", n_dev, None)
-    bound = MPPEngine._run_bound(longest)
+    bound = run_bound(longest)
     kd = jnp.asarray(MPPEngine._shard_pad(key, splits, L))
     vals = [jnp.asarray(MPPEngine._shard_pad(np.where(mask, l, np.zeros((), l.dtype)), splits, L))
             for l in lanes]
 
     def kernel(k, *vs):
-        return tuple(MPPEngine._run_totals(k, vs, bound))
+        return tuple(run_totals(k, vs, bound))
 
     if n_dev == 1:
         outs = jax.jit(kernel)(kd, *vals)
@@ -142,13 +143,13 @@ def test_run_totals_against_numpy(case, n_dev):
 
 def test_a_bound_that_is_too_small_is_a_wrong_sum():
     """Why the bound follows the data: a run of R + 1 summed with R's
-    three passes loses its last row. `_run_bound` never gives that."""
+    three passes loses its last row. `run_bound` never gives that."""
     key = runs(R + 1)
     ones = jnp.ones(R + 1, jnp.int32)
-    (short,) = MPPEngine._run_totals(jnp.asarray(key), [ones], R)
-    (whole,) = MPPEngine._run_totals(jnp.asarray(key), [ones], MPPEngine._run_bound(R + 1))
+    (short,) = run_totals(jnp.asarray(key), [ones], R)
+    (whole,) = run_totals(jnp.asarray(key), [ones], run_bound(R + 1))
     assert int(short[0]) == R and int(whole[0]) == R + 1
-    assert [MPPEngine._run_bound(n) for n in (0, 1, 2, 3, 7, 8, 9, 1000, 1 << 20)] == \
+    assert [run_bound(n) for n in (0, 1, 2, 3, 7, 8, 9, 1000, 1 << 20)] == \
         [1, 1, 2, 4, 8, 8, 16, 1024, 1 << 20]
 
 

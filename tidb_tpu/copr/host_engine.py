@@ -317,7 +317,7 @@ def _agg_partial_columns(a: AggDesc, chunk: Chunk, mask: np.ndarray, inv: np.nda
             # independent int64 wrap-sums + float estimates (sumsq via
             # 32-bit limbs) — both cop engines land on the identical exact
             # integer whatever their summation order
-            # (tpu_engine._agg_partials_device is the device twin)
+            # (kernels/primitives.py agg_partials is the device twin)
             xi = np.where(vv, dv.astype(np.int64), 0)
             ai = xi >> 32
             bi = xi - (ai << 32)

@@ -238,8 +238,9 @@ def guarded_device_call(fn, bo: "Backoffer", breakers=(), forced: bool = False,
     only when `result is not None`, because a None result means the
     callable declined before touching the device (a half-open probe must
     not close on no evidence) — or (None, err) when the device path
-    lost. tools/lint_boundaries.py pins this as the ONE sanctioned
-    blanket-except site for the MPP/window boundaries."""
+    lost. The boundary-taxonomy pass (tools/analyze/boundary_pass.py) pins
+    this as the ONE sanctioned blanket-except site for the MPP/window
+    boundaries."""
     from ..utils.failpoint import inject as _fp
 
     while True:

@@ -6,7 +6,7 @@ the same fusion is reborn as ONE jit-compiled XLA program per DAG digest:
 
     column tiles [T, R] ──► selection mask ──► partial aggregation
     (device-resident,        (vmapped expr      (masked reductions /
-     dict-coded strings)      kernels)           segment_sum by group code)
+     dict-coded strings)      kernels)           segmented sums by group code)
 
 Design rules (SURVEY §7 hard parts):
   * static shapes: batches pad to tile multiples; recompiles keyed on
@@ -27,21 +27,31 @@ The jit cache is the compile-once analog of the coprocessor cache
 
 from __future__ import annotations
 
-import bisect
 import time
 from threading import Lock, RLock
 
 import numpy as np
 
 from ..jaxenv import jax, jnp
-from ..utils import memory as _mem
 from ..utils import metrics as M
 from ..utils import timeline as TL
 from ..utils import tracing
 from ..chunk.chunk import Chunk, Column
-from ..expr.expression import Column as ExprCol, Constant, Expression, ScalarFunc
-from ..mysqltypes.datum import Datum, K_STR, K_BYTES
-from ..mysqltypes.field_type import ft_longlong
+from ..expr.expression import Column as ExprCol
+from ..kernels.booking import Timed, fetch, to_device, tree_to_device
+from ..kernels.lowering import dict_encode_lane, eval_flat, rewrite, selection_mask
+from ..kernels.primitives import (
+    DIRECT_GROUP_MAX,
+    MERGE_OPS,
+    agg_partials,
+    group_code,
+    group_key_columns,
+    lex_sort_perm,
+    partial_columns,
+    seg_max,
+    seg_sum,
+    top_k,
+)
 from ..mysqltypes.mydecimal import pow10
 from .dag import DAGRequest
 from .host_engine import exact_sum64, exact_sumsq64, execute_dag_host
@@ -52,78 +62,6 @@ from .tilecache import (
     encode_valid_lane,
     pow2_rows,
 )
-
-class _Timed:
-    """A jitted program with its first dispatch timed: JAX traces+compiles
-    synchronously inside the first call (later calls dispatch async in
-    sub-ms), so the first-call wall IS the compile cost — booked as
-    `<prefix>.compile` (the tidb_tpu_compile_seconds series and the
-    trace's compile phase); every later call is booked as
-    `<prefix>.dispatch`: the jit call IS the async dispatch — its wall
-    is queueing cost, not compute (the fetch observes that). The cop
-    engine's programs book under `device.`, the MPP engine's under
-    `mpp.`. A benign race (two threads both timing the first call) at
-    worst records one extra sample."""
-
-    __slots__ = ("fn", "_compiled", "_compile", "_dispatch")
-
-    def __init__(self, fn, prefix: str = "device"):
-        self.fn = fn
-        self._compiled = False
-        self._compile = prefix + ".compile"
-        self._dispatch = prefix + ".dispatch"
-
-    def __call__(self, *args):
-        t0 = time.perf_counter_ns()
-        out = self.fn(*args)
-        t1 = time.perf_counter_ns()
-        if self._compiled:
-            TL.boundary(self._dispatch, t0, t1)
-        else:
-            self._compiled = True
-            TL.boundary(self._compile, t0, t1)
-        return out
-
-
-def _to_device(a: np.ndarray, device=None):
-    """Host→device upload with transfer accounting (`device.h2d`: the h2d
-    half of tidb_tpu_transfer_bytes_total, the upload stage of
-    tidb_tpu_tile_build_seconds and the trace's device.transfer phase).
-    With `device` the array is COMMITTED to that mesh device — jit
-    follows committed inputs, so pinning the uploads is what pins the
-    whole launch to its runner lane (PR 6 per-device dispatch).
-    The bytes also consume into the bound statement MemTracker — device
-    allocations were invisible to memory quotas before PR 4 — so the
-    consume can raise the quota/server-limit error right at the
-    allocation site (a real allocation failure, never a device fault)."""
-    _mem.consume_current(a.nbytes)
-    with TL.span("device.h2d", bytes=int(a.nbytes)):
-        return jnp.asarray(a) if device is None else jax.device_put(a, device)
-
-
-def _fetch(x, programs: int = 1):
-    """Device→host fetch: `jax.device_get` blocks until the async dispatch
-    finishes computing, so the wall of `device.execute` is the HOST
-    blocked in `device_get` for the `programs` dispatched programs of
-    the launch: the observable device execute+fetch time
-    (tidb_tpu_device_execute_seconds); result bytes are the d2h half of
-    the transfer series."""
-    t0 = time.perf_counter_ns()
-    out = jax.device_get(x)
-    t1 = time.perf_counter_ns()
-    nbytes = sum(getattr(l, "nbytes", 0) for l in jax.tree_util.tree_leaves(out))
-    TL.boundary("device.execute", t0, t1, d2h_bytes=int(nbytes), programs=programs)
-    # NOT consumed into the memory tracker: the fetched result becomes a
-    # chunk that drain() charges at materialization — charging the d2h
-    # here too would double-count the same data on the device path only
-    return out
-
-
-def _tree_to_device(tree, device=None):
-    """Upload every leaf of a codec payload pytree (dict of numpy arrays)
-    through `_to_device`, so transfer accounting/quota charges cover the
-    compressed form — the only form that crosses the wire."""
-    return jax.tree_util.tree_map(lambda a: _to_device(a, device), tree)
 
 
 def _mark_device(chunk):
@@ -139,127 +77,6 @@ def _mark_device(chunk):
 
 
 TILE_ROWS = 1 << 16
-DIRECT_GROUP_MAX = 1 << 16
-# group domains up to this size reduce via dense masked reductions
-# (VPU-friendly compare+reduce, fuses across agg lanes) instead of
-# segment_sum: TPU scatter-adds serialize and cost ~100ms per lane at 2M
-# rows while the dense form is bandwidth-bound (~µs at Q1 scale)
-SEG_DENSE_MAX = 64
-
-
-def _seg_ids(seg, nseg):
-    return jnp.arange(nseg, dtype=seg.dtype)[:, None] == seg[None, :]
-
-
-def _seg_sum(vals, seg, nseg):
-    """Sum `vals` per segment; rows with seg >= nseg are dropped (the
-    masked-row overflow slot)."""
-    if nseg <= SEG_DENSE_MAX:
-        zero = jnp.zeros((), dtype=vals.dtype)
-        return jnp.sum(jnp.where(_seg_ids(seg, nseg), vals[None, :], zero), axis=1)
-    return jax.ops.segment_sum(vals, seg, num_segments=nseg + 1)[:nseg]
-
-
-def _seg_min(vals, seg, nseg, fill):
-    if nseg <= SEG_DENSE_MAX:
-        return jnp.min(jnp.where(_seg_ids(seg, nseg), vals[None, :], fill), axis=1)
-    return jax.ops.segment_min(vals, seg, num_segments=nseg + 1)[:nseg]
-
-
-def _seg_max(vals, seg, nseg, fill):
-    if nseg <= SEG_DENSE_MAX:
-        return jnp.max(jnp.where(_seg_ids(seg, nseg), vals[None, :], fill), axis=1)
-    return jax.ops.segment_max(vals, seg, num_segments=nseg + 1)[:nseg]
-
-def lex_sort_perm(ops, iota_dtype=jnp.int32):
-    """Lexicographic sort permutation over significance-ordered key
-    operands (most significant FIRST); ties break by row id.
-
-    Emulates one multi-key `lax.sort` with successive single-key STABLE
-    sorts (np.lexsort's recipe): the TPU backend's x64 comparator rewrite
-    makes multi-key sorts explode at compile time. Measured in PR 22 with
-    the v5e compiler (JAX 0.9.0, libtpu 0.0.34) at 2^22 rows: three int64
-    keys in one sort 303 s vs 80 s in this pass form, four int64 keys
-    474 s, seven int32 keys 325 s vs 35 s. No sort is cheap on this
-    stack: ONE single-key sort costs 14-18 s (int32 key) or 34-49 s
-    (int64 key) to compile, at 2^16 rows as at 2^22."""
-    P = ops[0].shape[0]
-    perm = jnp.arange(P, dtype=iota_dtype)
-    for k in reversed(ops):
-        _, perm = jax.lax.sort((k[perm], perm), num_keys=1)
-    return perm
-
-
-_CMP_SWAP = {"lt": "gt", "le": "ge", "gt": "lt", "ge": "le", "eq": "eq", "ne": "ne"}
-
-
-class Vocab(list):
-    """Sorted dict-encode vocabulary: ORIGINAL values in code order, plus
-    the lookup keys codes were assigned by (weight strings under a ci
-    collation, the values themselves under binary)."""
-
-    def __init__(self, originals, keys=None, coll="utf8mb4_bin"):
-        super().__init__(originals)
-        self.keys = list(self) if keys is None else keys
-        self.coll = coll
-
-    def lookup(self, s: str):
-        """(insertion position, exact-present) for a constant under this
-        vocab's collation — the bisect behind code-space compare/IN."""
-        from ..mysqltypes import collate as _c
-
-        k = _c.weight(s, self.coll) if _c.is_ci(self.coll) else s
-        i = bisect.bisect_left(self.keys, k)
-        return i, i < len(self.keys) and self.keys[i] == k
-
-
-def _dict_encode_lane(d: np.ndarray, v: np.ndarray, coll: str = "utf8mb4_bin"):
-    """Vectorized sorted-dict encoding of an object lane → (int32 codes,
-    Vocab). Handles str lanes (numpy 'U' fast path) and bytes lanes
-    (latin-1 view: byte order == code-point order, so code order stays
-    binary-collation order); mixed lanes take the generic python path.
-    Under a ci collation codes follow WEIGHT order — equal-weight values
-    share one code whose vocab entry is the binary-min original (the same
-    representative the host paths resolve ties to)."""
-    from ..mysqltypes import collate as _coll
-
-    if not v.any():
-        return np.zeros(len(d), np.int32), Vocab([], coll=coll)
-    present = d[v]
-    kinds = {type(x) for x in present.tolist()}
-    if _coll.is_ci(coll) and kinds <= {str}:
-        raw = np.where(v, d, "")
-        wa = _coll.weight_lane(raw, coll).astype("U")
-        sel = np.nonzero(v)[0]
-        # representative per weight class = FIRST occurrence in row order,
-        # matching the host engines' first-row group output and the
-        # first-wins tie rule of min/max
-        uniqw, first = np.unique(wa[sel], return_index=True)
-        reps = [d[i] for i in sel[first]]
-        codes = np.searchsorted(uniqw, wa).astype(np.int32)
-        codes[~v] = 0
-        return codes, Vocab(reps, keys=uniqw.tolist(), coll=coll)
-    if kinds <= {str}:
-        vals = np.where(v, d, "").astype("U")
-        vocab_arr = np.unique(vals[v])
-        codes = np.searchsorted(vocab_arr, vals).astype(np.int32)
-        codes[~v] = 0
-        return codes, Vocab(vocab_arr.tolist())
-    if kinds <= {bytes}:
-        as_str = np.array([x.decode("latin-1") for x in present.tolist()], dtype="U")
-        vocab_arr = np.unique(as_str)
-        codes = np.zeros(len(d), np.int32)
-        codes[v] = np.searchsorted(vocab_arr, as_str).astype(np.int32)
-        orig = [s.encode("latin-1") for s in vocab_arr.tolist()]
-        return codes, Vocab(orig, keys=vocab_arr.tolist())
-    # mixed str/bytes/other: generic exact path
-    vocab = sorted({x if isinstance(x, str) else x.decode("latin-1") for x in present.tolist()})
-    code_of = {s: i for i, s in enumerate(vocab)}
-    codes = np.zeros(len(d), np.int32)
-    for i in np.nonzero(v)[0]:
-        x = d[i]
-        codes[i] = code_of[x if isinstance(x, str) else x.decode("latin-1")]
-    return codes, Vocab(vocab)
 
 
 class DeviceBatch:
@@ -304,7 +121,7 @@ class DeviceBatch:
         rv = np.zeros(self.padded, dtype=bool)
         rv[:n] = True
         t_build = time.perf_counter_ns()
-        self.row_valid = _to_device(rv.reshape(self.t, self.r), device)
+        self.row_valid = to_device(rv.reshape(self.t, self.r), device)
         self.wire_nbytes += self.padded
         self.logical_nbytes += self.padded
         TL.boundary("tile.build", t_build, time.perf_counter_ns(), part="mirror",
@@ -358,7 +175,7 @@ class DeviceBatch:
                     vocab = None
                     if d.dtype == object:
                         coll = getattr(self.batch.table.columns[off].ft, "collate", "utf8mb4_bin")
-                        codes, vocab = _dict_encode_lane(d, v, coll)
+                        codes, vocab = dict_encode_lane(d, v, coll)
                         self.vocabs[off] = vocab
                         d = codes
                     if self.compress:
@@ -378,12 +195,12 @@ class DeviceBatch:
                         enc.args["stride"] = int(pay_d["g"])
             logical = self.padded * (d.dtype.itemsize + 1)  # dense data+valid
             self._data[off] = (
-                _to_device(self._pad2d(d), self.device) if pay_d is None
-                else _tree_to_device(pay_d, self.device)
+                to_device(self._pad2d(d), self.device) if pay_d is None
+                else tree_to_device(pay_d, self.device)
             )
             self._valid[off] = (
-                _to_device(self._pad2d(v), self.device) if pay_v is None
-                else _tree_to_device(pay_v, self.device)
+                to_device(self._pad2d(v), self.device) if pay_v is None
+                else tree_to_device(pay_v, self.device)
             )
             self.lane_sigs[off] = (sig_d, sig_v)
             wire = self._wire(self._data[off]) + self._wire(self._valid[off])
@@ -758,7 +575,7 @@ class TPUEngine:
                         M.TPU_FALLBACK.inc(path="cop", reason="not_lowerable")
                         return execute_dag_host(dag, batch)
                     if isinstance(plan, DevicePlan):
-                        host = _fetch(plan.launch())
+                        host = fetch(plan.launch())
                         with TL.span("cop.finalize", tasks=1):
                             chunk = _mark_device(plan.finalize(host))
                     else:
@@ -881,7 +698,7 @@ class TPUEngine:
                 launched.append(("grp", (grp, out)))
 
         if launched:
-            fetched = _fetch([payload[1] for _, payload in launched],
+            fetched = fetch([payload[1] for _, payload in launched],
                              programs=len(launched))
             with TL.span("cop.finalize", tasks=len(items)):
                 for (kind, payload), host in zip(launched, fetched):
@@ -930,7 +747,7 @@ class TPUEngine:
             if off in dev.vocabs:
                 vocabs[i] = dev.vocabs[off]
 
-        r_conds = [self._rewrite(c, vocabs) for c in conds]
+        r_conds = [rewrite(c, vocabs) for c in conds]
         if any(c is None for c in r_conds):
             return None
 
@@ -951,121 +768,13 @@ class TPUEngine:
             return self._lower_topn(dag, dev, lanes, vocabs, r_conds, sig)
         return self._lower_filter(dag, dev, lanes, r_conds, sig)
 
-    # --- string/dict rewriting --------------------------------------------
-
-    def _rewrite(self, e: Expression, vocabs: dict[int, list]):
-        """Rewrite an expression into device (code-space) form; None if not
-        lowerable. String columns become int32 code lanes; comparisons with
-        string constants map through the sorted vocab so code order ==
-        collation order."""
-        if isinstance(e, ExprCol):
-            return e  # codes lane supplied by caller keyed on idx
-        if isinstance(e, Constant):
-            if e.value.kind in (K_STR, K_BYTES):
-                return None  # bare string const outside rewritten cmp
-            return e
-        if not isinstance(e, ScalarFunc):
-            return None
-        name = e.sig.name
-        # comparison with a string column vs string constant
-        if name in _CMP_SWAP and len(e.args) == 2:
-            a, b = e.args
-            if isinstance(b, ExprCol) and isinstance(a, Constant):
-                a, b = b, a
-                name = _CMP_SWAP[name]
-            if isinstance(a, ExprCol) and a.idx in vocabs and isinstance(b, Constant):
-                if b.value.kind not in (K_STR, K_BYTES):
-                    return None
-                return self._code_cmp(name, a, b, vocabs[a.idx])
-            if isinstance(a, ExprCol) and a.idx in vocabs:
-                return None  # string col vs non-const: host
-        if name == "in" and isinstance(e.args[0], ExprCol) and e.args[0].idx in vocabs:
-            vocab = vocabs[e.args[0].idx]
-            codes = []
-            for c in e.args[1:]:
-                if not isinstance(c, Constant) or c.value.kind not in (K_STR, K_BYTES):
-                    return None
-                i, present = vocab.lookup(c.value.to_str())
-                codes.append(i if present else -1)
-            col = ExprCol(e.args[0].idx, ft_longlong(), e.args[0].name)
-            from ..expr.expression import make_func
-
-            return make_func("in", col, *[Constant(Datum.i(c), ft_longlong()) for c in codes])
-        # strings in any other position: not lowerable
-        for a in e.args:
-            if isinstance(a, ExprCol) and a.idx in vocabs:
-                return None
-        new_args = [self._rewrite(a, vocabs) for a in e.args]
-        if any(a is None for a in new_args):
-            return None
-        return ScalarFunc(e.sig, new_args, e.ret_type)
-
-    def _code_cmp(self, op: str, col: ExprCol, const: Constant, vocab: "Vocab"):
-        """col <op> 'str' → code-space comparison via sorted-vocab bisect
-        (weight-space under a ci collation)."""
-        from ..expr.expression import make_func
-
-        pos, present = vocab.lookup(const.value.to_str())
-        icol = ExprCol(col.idx, ft_longlong(), col.name)
-
-        def c(v):
-            return Constant(Datum.i(v), ft_longlong())
-
-        if op == "eq":
-            return make_func("eq", icol, c(pos if present else -1))
-        if op == "ne":
-            return make_func("ne", icol, c(pos if present else -1))
-        if op == "lt":
-            return make_func("lt", icol, c(pos))
-        if op == "ge":
-            return make_func("ge", icol, c(pos))
-        if op == "le":
-            return make_func("lt" if not present else "le", icol, c(pos))
-        if op == "gt":
-            return make_func("ge" if not present else "gt", icol, c(pos))
-        return None
-
-    # --- kernels ------------------------------------------------------------
-
-    @staticmethod
-    def _eval_device(e: Expression, lanes: dict):
-        """Recursive device eval over [T, R] lanes."""
-
-        def rec(x):
-            if isinstance(x, ExprCol):
-                return lanes[x.idx]
-            if isinstance(x, Constant):
-                v = x.scalar_value()
-                if v is None:
-                    z = jnp.zeros((), dtype=jnp.int64)
-                    return z, jnp.zeros((), dtype=bool)
-                if x.ret_type.is_float():
-                    dt = jnp.float64
-                elif isinstance(v, int) and v > np.iinfo(np.int64).max:
-                    dt = jnp.uint64  # literals above 2^63-1 (BIGINT UNSIGNED)
-                else:
-                    dt = jnp.int64
-                return jnp.asarray(v, dtype=dt), jnp.asarray(True)
-            avals = [rec(a) for a in x.args]
-            return x.eval_xp(jnp, avals)
-
-        return rec(e)
-
-    def _mask(self, r_conds, lanes, row_valid):
-        with jax.named_scope("sel"):
-            mask = row_valid
-            for c in r_conds:
-                d, v = self._eval_device(c, lanes)
-                mask = mask & v & (d != 0)
-            return mask
-
     def _program(self, key, builder):
         with self._lock:
             self._raw.setdefault(key, builder)  # for vmapped group launches
             fn = self._programs.get(key)
             if fn is None:
                 M.TPU_COMPILE_CACHE.inc(result="miss")
-                fn = _Timed(jax.jit(builder))
+                fn = Timed(jax.jit(builder))
                 self._programs[key] = fn
                 self.compile_count += 1
             else:
@@ -1137,7 +846,7 @@ class TPUEngine:
                     return jax.vmap(raw)(*stacked)
 
                 M.TPU_COMPILE_CACHE.inc(result="miss")
-                vfn = _Timed(jax.jit(group))
+                vfn = Timed(jax.jit(group))
                 self._vprograms[(key, gcap, width)] = vfn
                 self.compile_count += 1
             else:
@@ -1152,7 +861,7 @@ class TPUEngine:
         # may compile to a different program
         key = ("filter", repr(r_conds), sig)
         arrs, order = self._flatten_lanes(lanes)
-        fn = self._program(key, lambda flat, rv: self._mask(
+        fn = self._program(key, lambda flat, rv: selection_mask(
             r_conds, self._unflatten(flat, order, rv), rv))
 
         def finalize(mask):
@@ -1257,7 +966,7 @@ class TPUEngine:
                 # representative chosen batch-wide (pre-filter), which can
                 # surface a value outside the qualifying rows — host path
                 return None
-            r_args = [self._rewrite(x, vocabs) if not (isinstance(x, ExprCol) and x.idx in vocabs) else (x if a.name in ("min", "max", "first_row", "count") else None) for x in a.args]
+            r_args = [rewrite(x, vocabs) if not (isinstance(x, ExprCol) and x.idx in vocabs) else (x if a.name in ("min", "max", "first_row", "count") else None) for x in a.args]
             if any(x is None for x in r_args):
                 return None
             a._device_args = r_args
@@ -1305,22 +1014,15 @@ class TPUEngine:
 
         def kernel(flat, row_valid):
             l = self._unflatten(flat, order, row_valid)
-            mask = self._mask(r_conds, l, row_valid)
+            mask = selection_mask(r_conds, l, row_valid)
             flat_mask = mask.reshape(-1)
             with jax.named_scope("agg"):
-                # combined group code, mixed radix; NULL key → extra slot
-                if gb:
-                    code = jnp.zeros(flat_mask.shape, dtype=jnp.int32)
-                    for (idx, lo), dom in zip(key_cols, domains):
-                        d, v = l[idx]
-                        kd = (d.reshape(-1).astype(jnp.int32) - lo + 1) * v.reshape(-1)
-                        code = code * (dom + 1) + kd
-                else:
-                    code = jnp.zeros(flat_mask.shape, dtype=jnp.int32)
+                code = group_code([l[idx] + (lo, dom) for (idx, lo), dom
+                                   in zip(key_cols, domains)], flat_mask.shape)
                 seg = jnp.where(flat_mask, code, nseg)  # masked rows → overflow slot
-                outs = [_seg_sum(flat_mask.astype(jnp.int64), seg, nseg)]
+                outs = [seg_sum(flat_mask.astype(jnp.int64), seg, nseg)]
                 for a in agg.aggs:
-                    outs.extend(self._agg_partials_device(a, l, flat_mask, seg, nseg))
+                    outs.extend(agg_partials(a, a._device_args, l, flat_mask, seg, nseg))
                 return outs
 
         fn, aux = self._packed_program(key, kernel, nseg)
@@ -1347,7 +1049,7 @@ class TPUEngine:
         The reference's high-NDV path is a murmur3 hash shuffle into
         partial/final worker maps (executor/aggregate.go:544); hash tables
         don't map onto the MXU/VPU, so the TPU redesign is sort-based: one
-        multi-operand `lax.sort` over (mask, null-flags, key lanes) makes
+        lexicographic sort (`lex_sort_perm`) over (mask, null-flags, key lanes) makes
         groups contiguous, a cumsum over boundary flags assigns dense
         segment ids, and the same masked segment reductions as the direct
         path produce partial states. Output capacity must be static under
@@ -1371,7 +1073,7 @@ class TPUEngine:
         def make_kernel(gcap):
             def kernel(flat, row_valid):
                 l = self._unflatten(flat, order, row_valid)
-                mask = self._mask(r_conds, l, row_valid).reshape(-1)
+                mask = selection_mask(r_conds, l, row_valid).reshape(-1)
                 with jax.named_scope("agg"):
                     n = mask.shape[0]
                     # lexicographic sort: masked rows last, then NULL flag +
@@ -1413,11 +1115,11 @@ class TPUEngine:
                     for j in range(len(key_idx)):
                         knull = s_keys[2 * j]
                         kval = s_keys[2 * j + 1]
-                        outs.append(_seg_max(jnp.where(s_mask, kval, I64_MIN), seg, gcap, I64_MIN))
-                        outs.append(_seg_max(jnp.where(s_mask, 1 - knull.astype(jnp.int64), -1), seg, gcap, -1))
+                        outs.append(seg_max(jnp.where(s_mask, kval, I64_MIN), seg, gcap, I64_MIN))
+                        outs.append(seg_max(jnp.where(s_mask, 1 - knull.astype(jnp.int64), -1), seg, gcap, -1))
                     l_perm = {i: (dd.reshape(-1)[perm], vv.reshape(-1)[perm]) for i, (dd, vv) in l.items()}
                     for a in agg.aggs:
-                        outs.extend(self._agg_partials_device(a, l_perm, s_mask, seg, gcap, index_lane=perm))
+                        outs.extend(agg_partials(a, a._device_args, l_perm, s_mask, seg, gcap, index_lane=perm))
                     return n_groups, outs
 
             return kernel
@@ -1445,7 +1147,7 @@ class TPUEngine:
                 fn2, aux2 = self._packed_program(
                     base_key + (cap,), make_kernel(cap), cap, has_scalar=True
                 )
-                ng_a, i_arr, f_arr = _fetch(fn2(arrs, dev.row_valid))
+                ng_a, i_arr, f_arr = fetch(fn2(arrs, dev.row_valid))
                 ng = int(ng_a)
                 if ng <= cap:
                     outs = self._unpack((i_arr, f_arr), aux2)
@@ -1527,7 +1229,10 @@ class TPUEngine:
                     flts.append(o.astype(jnp.float64))
                 else:
                     lay.append(("i", len(ints)))
-                    ints.append(o.astype(jnp.int64))
+                    # a uint64 lane (MIN/MAX of BIGINT UNSIGNED) rides the
+                    # int64 stack by its bits; undone by view(uint64) at decode
+                    ints.append(jax.lax.bitcast_convert_type(o, jnp.int64)
+                                if o.dtype == jnp.uint64 else o.astype(jnp.int64))
             aux["layout"] = lay
             i_arr = jnp.stack(ints) if ints else jnp.zeros((0, nseg), jnp.int64)
             f_arr = jnp.stack(flts) if flts else jnp.zeros((0, nseg), jnp.float64)
@@ -1535,7 +1240,7 @@ class TPUEngine:
 
         self._raw.setdefault(key, packed)
         M.TPU_COMPILE_CACHE.inc(result="miss")
-        cached = (_Timed(jax.jit(packed)), aux)
+        cached = (Timed(jax.jit(packed)), aux)
         self._programs[key] = cached
         self.compile_count += 1
         return cached
@@ -1545,128 +1250,15 @@ class TPUEngine:
         i_arr, f_arr = packed
         return [i_arr[k] if t == "i" else f_arr[k] for t, k in aux["layout"]]
 
-    def _agg_partials_device(self, a, lanes, flat_mask, seg, nseg, index_lane=None):
-        name = a.name
-        if a._device_args:
-            d, v = self._eval_device(a._device_args[0], lanes)
-            d = jnp.full(seg.shape, d) if d.ndim == 0 else d.reshape(-1)
-            v = jnp.full(seg.shape, v) if v.ndim == 0 else v.reshape(-1)
-        else:
-            d = jnp.ones(seg.shape, dtype=jnp.int64)
-            v = jnp.ones(seg.shape, dtype=bool)
-        ok = flat_mask & v
-        if name == "count":
-            return [_seg_sum(ok.astype(jnp.int64), seg, nseg)]
-        if name in ("sum", "avg"):
-            if d.dtype == jnp.float64 or d.dtype == jnp.float32:
-                s = _seg_sum(jnp.where(ok, d, 0.0), seg, nseg)
-            else:
-                s = _seg_sum(jnp.where(ok, d.astype(jnp.int64), 0), seg, nseg)
-            cnt = _seg_sum(ok.astype(jnp.int64), seg, nseg)
-            return [s, cnt]
-        if name in ("min", "max"):
-            # sentinels in the lane's OWN dtype: an int64 sentinel written
-            # into a uint64 lane both mis-orders values >= 2^63 and
-            # overflows the decode (BIGINT UNSIGNED)
-            if jnp.issubdtype(d.dtype, jnp.floating):
-                big, small = jnp.asarray(jnp.inf, d.dtype), jnp.asarray(-jnp.inf, d.dtype)
-            else:
-                # sentinels in the lane's OWN dtype: jnp.where silently
-                # TRUNCATES a wider sentinel into the lane dtype (int64
-                # max → int32 -1), poisoning MIN over dict-code lanes
-                info = np.iinfo(np.dtype(str(d.dtype)))
-                big = jnp.asarray(info.max, d.dtype)
-                small = jnp.asarray(info.min, d.dtype)
-            if name == "min":
-                s = _seg_min(jnp.where(ok, d, big), seg, nseg, big)
-            else:
-                s = _seg_max(jnp.where(ok, d, small), seg, nseg, small)
-            if s.dtype == jnp.uint64:
-                # packed transport is int64; undone by view(uint64) at decode
-                s = jax.lax.bitcast_convert_type(s, jnp.int64)
-            cnt = _seg_sum(ok.astype(jnp.int64), seg, nseg)
-            return [s, cnt]
-        if name == "first_row":
-            idx = jnp.arange(seg.shape[0]) if index_lane is None else index_lane
-            first = _seg_min(jnp.where(ok, idx, seg.shape[0]), seg, nseg, jnp.asarray(seg.shape[0]))
-            return [first]
-        if name in ("stddev_pop", "stddev_samp", "var_pop", "var_samp"):
-            # (cnt, sum, sumsq) partials, mirroring the host cop form.
-            # Decimals ship (int64 wrap-sum, float estimate) pairs of the
-            # SCALED ints; decode reconstructs the exact integer sums
-            # (order-independent) and does the single float division —
-            # bit-identical to host_engine whatever the summation order.
-            arg_ft = a.args[0].ret_type
-            cnt = _seg_sum(ok.astype(jnp.int64), seg, nseg)
-            if arg_ft.is_decimal():
-                xi = jnp.where(ok, d.astype(jnp.int64), 0)
-                ai = xi >> 32  # arithmetic shift: hi limb keeps the sign
-                bi = xi - (ai << 32)  # lo limb in [0, 2^32)
-                af, bf = ai.astype(jnp.float64), bi.astype(jnp.float64)
-                return [cnt,
-                        _seg_sum(xi, seg, nseg), _seg_sum(xi.astype(jnp.float64), seg, nseg),
-                        _seg_sum(ai * ai, seg, nseg), _seg_sum(af * af, seg, nseg),
-                        _seg_sum(ai * bi, seg, nseg), _seg_sum(af * bf, seg, nseg),
-                        _seg_sum(bi * bi, seg, nseg), _seg_sum(bf * bf, seg, nseg)]
-            x = jnp.where(ok, d.astype(jnp.float64), 0.0)
-            return [cnt, _seg_sum(x, seg, nseg), _seg_sum(x * x, seg, nseg)]
-        if name in ("bit_and", "bit_or", "bit_xor"):
-            # bitwise reductions decompose per bit: segment min/max/sum-mod-2
-            # over a [n, 64] bit matrix, recombined by shifts (two's
-            # complement places bit 63 via the int64 wrap)
-            arg_ft = a.args[0].ret_type
-            if arg_ft.is_decimal():
-                xf = d.astype(jnp.float64) / float(pow10(max(arg_ft.decimal, 0)))
-                x = jnp.rint(xf).astype(jnp.int64)
-            elif jnp.issubdtype(d.dtype, jnp.floating):
-                x = jnp.rint(d).astype(jnp.int64)
-            else:
-                x = d.astype(jnp.int64)
-            shifts = jnp.arange(64, dtype=jnp.int64)
-            bits = ((x[:, None] >> shifts[None, :]) & 1).astype(jnp.int32)
-            if name == "bit_and":
-                bits = jnp.where(ok[:, None], bits, 1)
-                red = jax.ops.segment_min(bits, seg, num_segments=nseg + 1)[:nseg]
-            elif name == "bit_or":
-                bits = jnp.where(ok[:, None], bits, 0)
-                red = jax.ops.segment_max(bits, seg, num_segments=nseg + 1)[:nseg]
-            else:
-                bits = jnp.where(ok[:, None], bits, 0)
-                red = jax.ops.segment_sum(bits, seg, num_segments=nseg + 1)[:nseg] % 2
-            out = ((red & 1).astype(jnp.int64) << shifts[None, :]).sum(axis=1)
-            return [out]
-        raise NotImplementedError(name)
-
     def _agg_outputs_to_chunk(self, dag, dev, outs, domains, key_cols, vocabs, nseg):
         agg = dag.agg
         out_fts = dag.output_types()
         group_count = np.asarray(outs[0])
         present = np.nonzero(group_count > 0)[0]
-        G = len(present)
-        cols: list[Column] = []
-        # decode group keys from segment index (mixed radix)
-        radix = [d + 1 for d in domains]
-        codes = present.copy()
-        key_vals = []
-        for r in reversed(radix):
-            key_vals.append(codes % r)
-            codes = codes // r
-        key_vals.reverse()
-        oi = 0
-        for (idx, lo), kv in zip(key_cols, key_vals):
-            ft = out_fts[oi]
-            valid = kv > 0
-            if idx in vocabs:
-                vocab = vocabs[idx]
-                data = np.empty(G, dtype=object)
-                for j, code in enumerate(kv):
-                    data[j] = vocab[code - 1] if code > 0 else None
-            else:
-                data = (kv.astype(np.int64) - 1) + lo
-                data[~valid] = 0
-            cols.append(Column(ft, data, valid))
-            oi += 1
-        cols.extend(self._agg_value_cols(dag, dev, outs, 1, oi, present, vocabs))
+        cols = group_key_columns(
+            present, [(lo, dom, vocabs.get(idx)) for (idx, lo), dom in zip(key_cols, domains)],
+            out_fts)
+        cols.extend(self._agg_value_cols(dag, dev, outs, 1, len(cols), present, vocabs))
         return Chunk(cols)
 
     def _agg_value_cols(self, dag, dev, outs, pos, oi, present, vocabs):
@@ -1678,45 +1270,13 @@ class TPUEngine:
         G = len(present)
         cols: list[Column] = []
         for a in agg.aggs:
-            pf = a.partial_final_types()
-            if a.name == "count":
-                cnt = np.asarray(outs[pos])[present]
-                cols.append(Column(out_fts[oi], cnt.astype(np.int64), np.ones(G, dtype=bool)))
-                pos += 1
-                oi += 1
-            elif a.name in ("sum", "avg"):
-                s = np.asarray(outs[pos])[present]
-                cnt = np.asarray(outs[pos + 1])[present]
-                has = cnt > 0
-                sd = s if out_fts[oi].is_float() else s.astype(np.int64)
-                cols.append(Column(out_fts[oi], sd, has))
-                oi += 1
-                if a.name == "avg":
-                    cols.append(Column(out_fts[oi], cnt.astype(np.int64), np.ones(G, dtype=bool)))
-                    oi += 1
-                pos += 2
-            elif a.name in ("min", "max"):
-                s = np.asarray(outs[pos])[present]
-                cnt = np.asarray(outs[pos + 1])[present]
-                has = cnt > 0
-                ft = out_fts[oi]
-                arg = a.args[0]
-                if isinstance(arg, ExprCol) and arg.idx in vocabs:
-                    vocab = vocabs[arg.idx]
-                    data = np.empty(G, dtype=object)
-                    for j in range(G):
-                        data[j] = vocab[int(s[j])] if has[j] and 0 <= int(s[j]) < len(vocab) else None
-                elif ft.is_float():
-                    data = s
-                elif ft.is_int() and ft.is_unsigned:
-                    # undo the kernel's uint64→int64 transport bitcast
-                    data = s.astype(np.int64).view(np.uint64).copy()
-                    data[~has] = 0
-                else:
-                    data = np.where(has, s.astype(np.int64), 0)
-                cols.append(Column(ft, data, has))
-                pos += 2
-                oi += 1
+            if a.name in MERGE_OPS:
+                arg = a.args[0] if a.args else None
+                new = partial_columns(a, outs, pos, present, out_fts[oi:],
+                                      vocabs.get(arg.idx) if isinstance(arg, ExprCol) else None)
+                cols.extend(new)
+                pos += len(MERGE_OPS[a.name])
+                oi += len(new)
             elif a.name in ("stddev_pop", "stddev_samp", "var_pop", "var_samp"):
                 ones = np.ones(G, dtype=bool)
                 cnt = np.asarray(outs[pos])[present].astype(np.int64)
@@ -1770,7 +1330,7 @@ class TPUEngine:
         if len(by) != 1:
             return self._lower_topn_multi(dag, dev, lanes, vocabs, r_conds, sig)
         e, desc = by[0]
-        r_e = self._rewrite(e, vocabs)
+        r_e = rewrite(e, vocabs)
         if r_e is None:
             return None
         n = dag.topn.n
@@ -1779,12 +1339,10 @@ class TPUEngine:
 
         def kernel(flat, row_valid):
             l = self._unflatten(flat, order, row_valid)
-            mask = self._mask(r_conds, l, row_valid)
+            mask = selection_mask(r_conds, l, row_valid)
             with jax.named_scope("topn"):
-                d, v = self._eval_device(r_e, l)
-                d = jnp.full(mask.shape, d) if d.ndim == 0 else d
-                v = jnp.full(mask.shape, v) if v.ndim == 0 else v
-                d, v, m = d.reshape(-1), v.reshape(-1), mask.reshape(-1)
+                d, v = eval_flat(r_e, l, (mask.size,))
+                m = mask.reshape(-1)
                 # integer keys stay integer (exact for packed datetimes/decimals)
                 if jnp.issubdtype(d.dtype, jnp.floating):
                     lo, hi = -jnp.inf, jnp.inf
@@ -1798,7 +1356,7 @@ class TPUEngine:
                 else:
                     # top_k takes largest → negate for asc; NULLs first asc
                     sortkey = jnp.where(m, jnp.where(v, -d, hi), lo)
-                _, idx = jax.lax.top_k(sortkey, min(n, sortkey.shape[0]))
+                _, idx = top_k(sortkey, min(n, sortkey.shape[0]))
                 # ship only k validity bits, not the full row mask
                 return idx, m[idx]
 
@@ -1816,14 +1374,14 @@ class TPUEngine:
         )
 
     def _lower_topn_multi(self, dag: DAGRequest, dev: DeviceBatch, lanes, vocabs, r_conds, sig):
-        """Multi-key TopN: one multi-operand lax.sort over (mask, per-key
+        """Multi-key TopN: one lexicographic sort (`lex_sort_perm`) over (mask, per-key
         NULL-flag + data, row-id), take the first n sorted row-ids (the
         window-kernel sort recipe; ref closure_exec.go topN heap — the TPU
         form is a full sort, exact and still one fused program)."""
         by = dag.topn.by
         r_by = []
         for e, desc in by:
-            r_e = self._rewrite(e, vocabs)
+            r_e = rewrite(e, vocabs)
             if r_e is None:
                 return None
             r_by.append((r_e, desc))
@@ -1833,14 +1391,12 @@ class TPUEngine:
 
         def kernel(flat, row_valid):
             l = self._unflatten(flat, order, row_valid)
-            mask = self._mask(r_conds, l, row_valid).reshape(-1)
+            mask = selection_mask(r_conds, l, row_valid).reshape(-1)
             with jax.named_scope("topn"):
                 rows = mask.shape[0]
                 ops = [(~mask).astype(jnp.int32)]  # masked rows last
                 for r_e, desc in r_by:
-                    d, v = self._eval_device(r_e, l)
-                    d = jnp.full((rows,), d) if d.ndim == 0 else d.reshape(-1)
-                    v = jnp.full((rows,), v) if v.ndim == 0 else v.reshape(-1)
+                    d, v = eval_flat(r_e, l, (rows,))
                     # NULLs first asc / last desc (host _lex_argsort contract)
                     nullkey = jnp.where(v, 0, 1) if desc else jnp.where(v, 1, 0)
                     dd = jnp.where(v, d, jnp.zeros((), d.dtype))

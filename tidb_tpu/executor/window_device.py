@@ -1,11 +1,11 @@
-"""Device window kernels — one `lax.sort` + segmented scans per window spec.
+"""Device window kernels — one lexicographic sort + segmented scans per window spec.
 
 The reference parallelizes windows by hash-sharding partitions across a
 worker fleet (executor/shuffle.go:77) and pipelining within a partition
 (executor/pipelined_window.go:37). On TPU the same work maps onto ONE
 fused XLA program over the whole chunk:
 
-    lexicographic `lax.sort` by (partition, order, row-id) keys
+    lexicographic sort (`lex_sort_perm`) by (partition, order, row-id) keys
       -> partition/peer boundary flags (vectorized compares)
       -> cumulative / segmented scans (cumsum, cummax, associative_scan)
       -> gathers at frame ends
@@ -20,7 +20,7 @@ order).
 
 Strings never reach the device: lanes are dict-encoded to sorted-vocab
 codes (binary-collation order preserved), computed in code space, decoded
-on the way out — the tpu_engine string story applied to windows.
+on the way out — the cop engine's string story applied to windows.
 """
 
 from __future__ import annotations
@@ -29,7 +29,7 @@ from functools import lru_cache, reduce
 
 import numpy as np
 
-from ..copr.tpu_engine import lex_sort_perm
+from ..kernels.primitives import lex_sort_perm
 from ..jaxenv import jax, jnp, pack_flat, unpack_flat
 from ..mysqltypes.mydecimal import DIV_FRAC_INCR, MAX_SCALE, Dec, pow10
 
@@ -50,7 +50,7 @@ _PASSTHROUGH = {"lead", "lag", "first_value", "last_value", "nth_value", "min", 
 
 
 def _bucket(n: int) -> int:
-    """Pad to a power of two so recompiles are bounded (tpu_engine TILE rule)."""
+    """Pad to a power of two so recompiles are bounded (the cop engine's TILE rule)."""
     p = 1024
     while p < n:
         p <<= 1
@@ -170,7 +170,7 @@ def _build_kernel(spec):
         # compile for one 7-key int32 sort vs 35s for the pass form, v5e
         # compiler, PR 22 — see lex_sort_perm); the ascending initial
         # perm IS the row-id tie-break
-        perm = lex_sort_perm(list(words), iota_dtype=jnp.int32)
+        perm = lex_sort_perm(list(words))
         s_ops = [o[perm] for o in words]
         s_vals = [v[perm] for v in vals]
 

@@ -5,7 +5,7 @@ Mapping (reference mechanism → mesh construct):
   region-parallel scan (copr/coprocessor.go:151)   → rows sharded over the
       "dp" mesh axis; each device runs the fused scan/filter/partial-agg
       kernel on its shard
-  partial/final agg split (aggregation descriptors) → local segment_sum
+  partial/final agg split (aggregation descriptors) → local segmented-sum
       partials + `psum` over "dp" — exact for scaled-int decimals
   MPP hash exchange (cophandler/mpp_exec.go:109)    → `all_to_all` over the
       mesh axis after bucketing rows by key hash (hash_repartition)
@@ -22,6 +22,7 @@ from functools import partial
 import numpy as np
 
 from ..jaxenv import jax, jnp
+from ..kernels.primitives import seg_sum
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -54,7 +55,7 @@ def q1_local_kernel(spec: Q1Spec, qty, price, disc, tax, rf, ls, ship, row_valid
     seg = jnp.where(mask, code, spec.nseg)  # masked rows → overflow slot
 
     def ssum(x):
-        return jax.ops.segment_sum(x, seg, num_segments=spec.nseg + 1)[: spec.nseg]
+        return seg_sum(x, seg, spec.nseg)
 
     m64 = mask.astype(jnp.int64)
     disc_price = price * (100 - disc)  # scale 4

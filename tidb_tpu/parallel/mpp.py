@@ -36,21 +36,38 @@ import time
 
 import numpy as np
 
-from ..jaxenv import jax, jnp
+from ..jaxenv import jax, jnp, pack_rows, unpack_rows
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..chunk.chunk import Chunk, Column, col_numpy_dtype, VARLEN
-from ..expr.expression import Column as ExprCol, Constant, Expression
-from ..mysqltypes.datum import Datum
+from ..expr.expression import Column as ExprCol, Constant, Expression, ScalarFunc
+from ..kernels.booking import Timed
+from ..kernels.lowering import and_conds, dict_encode_lane, eval_device, rewrite
+from ..kernels.primitives import (
+    DIRECT_GROUP_MAX,
+    I64_MAX,
+    MERGE_OPS,
+    agg_arg,
+    agg_partials,
+    block_topk,
+    group_code,
+    group_key_columns,
+    lane_bounds,
+    merge_identity,
+    partial_columns,
+    run_bound,
+    run_totals,
+    score_floor,
+    seg_sum,
+    top_k,
+    topk_score,
+)
 from ..planner.fragment import BROADCAST, HASH, LOCAL, JoinFrag, MPPPlan, ScanFrag
 from ..utils import metrics as M
 from ..utils import timeline as TL
 from ..utils import tracing
 from ..utils.memory import consume_current
-
-I64_MAX = np.iinfo(np.int64).max
-DIRECT_GROUP_MAX = 1 << 16
 
 
 class ScanData:
@@ -78,10 +95,8 @@ class ScanData:
         if off not in self._dev:
             d, v = self.data[off], self.valid[off]
             if d.dtype == object:
-                from ..copr.tpu_engine import _dict_encode_lane
-
                 def enc(_d=d, _v=v):
-                    codes, vocab = _dict_encode_lane(_d, _v)
+                    codes, vocab = dict_encode_lane(_d, _v)
                     return codes.astype(np.int64), vocab
 
                 if self.shared is not None and self.version >= 0 and self.orig_offs:
@@ -308,7 +323,7 @@ class MPPEngine:
         padded per-shard length, the pre-padding longest shard (the skew
         signal the dispatch guard demotes on) and the longest key run of
         that stream — the bound the program's run totals are summed to
-        (_run_totals), counted on the lane the program sees, so it
+        (`run_totals`), counted on the lane the program sees, so it
         follows the table version exactly as the splits do."""
         def compute():
             k = sd.lane(koff)[0]
@@ -366,15 +381,13 @@ class MPPEngine:
         join gathers and agg scatters shrink by the selectivity, and the
         compiled program no longer bakes the predicate constants (one
         program per shape, not per constant). Returns int64 positions."""
-        from ..copr.tpu_engine import TPUEngine
-
         def compute():
             mask = None
             for c in rc:
                 used: set[int] = set()
                 c.collect_columns(used)
                 lanes = {off: sd.lane(off) for off in used}
-                d, v = TPUEngine._eval_device(c, lanes)
+                d, v = eval_device(c, lanes)
                 d = np.broadcast_to(np.asarray(d), (sd.n_rows,))
                 v = np.broadcast_to(np.asarray(v), (sd.n_rows,))
                 m = v & (d != 0)
@@ -523,7 +536,7 @@ class MPPEngine:
     LUT_DOM_MAX = 1 << 24
     ROWPOS_MAX = 1 << 22
     # clustered-mode dispatch guards (checked per statement because both
-    # depend on the data/predicate, not the plan): _block_topk unrolls
+    # depend on the data/predicate, not the plan): block_topk unrolls
     # O(k^2) traced ops, and run-aligned shard splits pad every lane to
     # the LONGEST run's shard — a skewed stream would ship n_dev x that
     CLUSTERED_TOPN_MAX = 64
@@ -548,8 +561,6 @@ class MPPEngine:
         (the tidb_tpu_mpp_fused path) additionally specializes each
         eligible join level to the device-resident LUT structure and the
         aggregation to build-row-position segments."""
-        from ..copr.tpu_engine import TPUEngine
-
         tick = gate if gate is not None else (lambda: None)
         by_frag = {id(s.frag): s for s in scans}
         self._restream_largest(mplan, by_frag)
@@ -560,7 +571,6 @@ class MPPEngine:
 
         # rewrite pushed conds per scan (string → dict-code space)
         r_pushed: dict[int, list] = {}
-        eng = TPUEngine()
         for s in scans:
             tick()
             conds = s.frag.ds.pushed_conds
@@ -572,7 +582,7 @@ class MPPEngine:
                 s.lane(off)
                 if off in s.vocabs:
                     vocabs[off] = s.vocabs[off]
-            rc = [eng._rewrite(c, vocabs) for c in conds]
+            rc = [rewrite(c, vocabs) for c in conds]
             if any(c is None for c in rc):
                 self._decline("non_lowerable_cond", "non-lowerable pushed condition")
                 return None
@@ -799,7 +809,7 @@ class MPPEngine:
                     sd.lane(off)
                     if off in sd.vocabs:
                         vocabs[j] = sd.vocabs[off]
-                lvl.r_post = [eng._rewrite(c, vocabs) for c in frag.post_conds]
+                lvl.r_post = [rewrite(c, vocabs) for c in frag.post_conds]
                 if any(c is None for c in lvl.r_post):
                     self._decline("non_lowerable_cond", "non-lowerable ON condition")
                     return False
@@ -876,8 +886,6 @@ class MPPEngine:
         """Statically provable: this expression never evaluates NULL.
         Lets the rowpos agg reuse an aggregate's count lane as the
         group-presence lane (one fewer B-wide scatter)."""
-        from ..expr.expression import ScalarFunc
-
         if isinstance(x, Constant):
             return not x.value.is_null
         if isinstance(x, ExprCol):
@@ -945,7 +953,7 @@ class MPPEngine:
         if r_args is None:
             return None
         # group-presence dedup: the first aggregate whose count lane
-        # provably equals segment_sum(mask) — count(*) or any agg over a
+        # provably equals the per-group sum of the mask — count(*) or any agg over a
         # never-NULL argument — doubles as the presence lane, saving one
         # B-wide scatter (the scatter IS the rowpos agg's cost)
         presence = None
@@ -964,7 +972,7 @@ class MPPEngine:
         # clustered upgrade: when the stream is already SORTED by the
         # (single) probe key of the group level, equal keys are contiguous
         # runs — run totals come from shifted adds bounded by the
-        # longest run (_run_totals: the distance doubling seg_reduce
+        # longest run (`run_totals`: the distance doubling seg_reduce
         # does its min/max lanes by), and run-aligned shard splits
         # (_clustered_splits) keep every group whole on one device, so
         # the program needs NO B-wide scatter and NO cross-device reduce.
@@ -1006,7 +1014,7 @@ class MPPEngine:
                      levels=None, by_frag=None, fused: bool = False,
                      topn=None):
         """Device aggregation metadata. Three modes (the dense/sorted
-        pair mirrors TPUEngine's dense-vs-segment split; rowpos is the
+        pair mirrors the cop engine's direct-vs-sorted split; rowpos is the
         PR 11 fused-chain specialization):
         - dense: direct-addressed buckets + psum when the packed key
           domain is small (ref: cophandler closure exec hash agg);
@@ -1272,7 +1280,7 @@ class MPPEngine:
         # clustered-mode dispatch guards — data/predicate-dependent, so
         # they cannot live in prepare: demote to the scatter-based
         # rowpos mode (the baseline the clustered upgrade came from)
-        # when the fused TopN is too wide for _block_topk's unrolled
+        # when the fused TopN is too wide for block_topk's unrolled
         # O(k^2) extraction, or when one dominant key run would drag
         # every run-aligned shard (and so n_dev x the padding) toward
         # the full stream length. The typed reason lands in
@@ -1309,7 +1317,7 @@ class MPPEngine:
                 # key run the host counted, up to a power of two (TPC-H's
                 # one to seven lineitems an order: 8, three passes), part
                 # of the program key like every shape the kernel bakes
-                agm["rp_run_bound"] = self._run_bound(longest)
+                agm["rp_run_bound"] = run_bound(longest)
         # what the spans and EXPLAIN ANALYZE say of the aggregation: the
         # mode, how many ORDER BY keys the program's TopN fused (dense
         # ships every group and fuses none), and why a faster mode or
@@ -1441,17 +1449,13 @@ class MPPEngine:
         said["program"] = key[:12]
         prog = self._programs.get(key)
         if prog is None:
-            from ..copr.tpu_engine import _Timed
-
             # first call = trace + compile (`mpp.compile`, observed into
             # tidb_tpu_compile_seconds); later calls are `mpp.dispatch`
-            prog = _Timed(self._build_program(mplan, meta, scan_arg_meta, mesh, axis,
+            prog = Timed(self._build_program(mplan, meta, scan_arg_meta, mesh, axis,
                                               n_dev, tuple(in_specs), lut_fids),
                           prefix="mpp")
             self._programs[key] = prog
             self.compile_count += 1
-        from ..jaxenv import unpack_rows
-
         out = prog(*args)
         # the fetch apart from the call: the dispatch returns at once,
         # the host then blocks here until the mesh has computed
@@ -1582,9 +1586,6 @@ class MPPEngine:
 
     def _build_program(self, mplan, meta, scan_arg_meta, mesh, axis, n_dev,
                        in_specs, lut_fids=()):
-        from ..copr.tpu_engine import TPUEngine
-
-        eval_dev = TPUEngine._eval_device
         soj = meta["scan_of_joined"]
         r_pushed = meta["r_pushed"]
         levels = meta["levels"]
@@ -1617,14 +1618,9 @@ class MPPEngine:
             for k, off in enumerate(offs):
                 lanes[off] = (flat[base + 2 + 2 * k], flat[base + 3 + 2 * k])
             sd = sd_by_fid[frag_id]
-            mask = rv
             # a prefiltered scan's lanes hold only surviving rows — its
             # pushed conditions already applied host-side
-            for c in () if pref else r_pushed[id(sd)]:
-                d, v = eval_dev(c, lanes)
-                d = jnp.broadcast_to(d, mask.shape) if getattr(d, "ndim", 0) == 0 else d
-                v = jnp.broadcast_to(v, mask.shape) if getattr(v, "ndim", 0) == 0 else v
-                mask = mask & v & (d != 0)
+            mask = and_conds(() if pref else r_pushed[id(sd)], lanes, rv)
             # re-key lanes into joined-schema space
             joined = {sd.frag.side_offset + off: lv for off, lv in lanes.items()}
             return joined, mask, {frag_id: rowid}
@@ -1777,14 +1773,6 @@ class MPPEngine:
                                 for j in read_above)
                     and agg_meta.get("rp_fid") != id(b))
 
-        def residual(lvl, merged, mask):
-            for c in lvl.r_post:
-                d, v = eval_dev(c, merged)
-                d = jnp.broadcast_to(d, mask.shape) if getattr(d, "ndim", 0) == 0 else d
-                v = jnp.broadcast_to(v, mask.shape) if getattr(v, "ndim", 0) == 0 else v
-                mask = mask & v & (d != 0)
-            return mask
-
         def join_stage(frag, flat):
             if isinstance(frag, ScanFrag):
                 return scan_stage(id(frag), flat)
@@ -1797,13 +1785,13 @@ class MPPEngine:
                 _, lmask, _ = lut_join(frag, lvl, flat, lmap, lmask, {}, bmap, bmask)
                 merged, mask, rowids = lut_join(
                     low, levels[id(low)], flat, pmap_, pmask, prow, lmap, lmask)
-                return merged, residual(levels[id(low)], merged, mask), rowids
+                return merged, and_conds(levels[id(low)].r_post, merged, mask), rowids
             pmap_, pmask, prow = join_stage(frag.probe, flat)
             bmap, bmask, brow = scan_stage(id(frag.build), flat)
             if lvl.use_lut:
                 merged, mask, rowids = lut_join(
                     frag, lvl, flat, pmap_, pmask, prow, bmap, bmask)
-                return merged, residual(lvl, merged, mask), rowids
+                return merged, and_conds(lvl.r_post, merged, mask), rowids
             pkey, pkv = pack_keys(pmap_, frag.probe_keys, lvl)
             bkey, bkv = pack_keys(bmap, frag.build_keys, lvl)
             if frag.exchange == HASH:
@@ -1890,12 +1878,7 @@ class MPPEngine:
                     mask = match
                 else:
                     mask = emitted & pmask[src]
-            for c in lvl.r_post:
-                d, v = eval_dev(c, merged)
-                d = jnp.broadcast_to(d, mask.shape) if getattr(d, "ndim", 0) == 0 else d
-                v = jnp.broadcast_to(v, mask.shape) if getattr(v, "ndim", 0) == 0 else v
-                mask = mask & v & (d != 0)
-            return merged, mask, rowids
+            return merged, and_conds(lvl.r_post, merged, mask), rowids
 
         # the fused TopN (sorted / rowpos / clustered modes): the device
         # cuts the groups by the FIRST ORDER BY key alone. One key: the k
@@ -1920,9 +1903,10 @@ class MPPEngine:
             n_cands = k + self.TOPN_TIE_SLACK if multi_key else k
 
         def topn_score(lanes_, valid, base=0):
-            lp = self._topn_lane_pos(agg.aggs, agg_idx, base)
-            return self._topk_score(lanes_[lp], valid, desc,
-                                    lanes_[lp + 1] if nullable_sum else None)
+            # the TopN aggregate's first partial lane among the flat lanes
+            lp = base + sum(len(MERGE_OPS[a.name]) for a in agg.aggs[:agg_idx])
+            return topk_score(lanes_[lp], valid, desc,
+                              lanes_[lp + 1] if nullable_sum else None)
 
         def tie_lane(score, tvals):
             """() for a one-key TopN. Else the tie-overflow lane: true
@@ -1936,7 +1920,7 @@ class MPPEngine:
                 over = jnp.zeros((), bool)  # every slot is a candidate
             else:
                 kth = tvals[min(k, kk) - 1]
-                over = ((kth > self._score_floor(score.dtype))
+                over = ((kth > score_floor(score.dtype))
                         & (jnp.sum(score >= kth) > kk))
             return (jnp.broadcast_to(over, (kk,)),)
 
@@ -1960,45 +1944,22 @@ class MPPEngine:
                 code = code + kd * st
             code = jnp.where(mask, code, I64_MAX)
 
-            # per-agg raw value lanes (+ count lane), zeroed off-mask
+            # per-agg raw value lanes (+ count lane), neutral off-mask
             lanes = []  # (array, merge_op)
             for a, ra in zip(agg.aggs, agg_meta["r_args"]):
-                if ra:
-                    d, v = eval_dev(ra[0], lanemap)
-                    d = jnp.broadcast_to(d, code.shape) if getattr(d, "ndim", 0) == 0 else d
-                    v = jnp.broadcast_to(v, code.shape) if getattr(v, "ndim", 0) == 0 else v
-                else:
-                    d = jnp.ones(code.shape, jnp.int64)
-                    v = jnp.ones(code.shape, bool)
+                d, v = agg_arg(ra, lanemap, code.shape)
                 ok = mask & v
-                if a.name == "count":
-                    lanes.append((ok.astype(jnp.int64), "sum"))
-                elif a.name in ("sum", "avg"):
-                    z = 0.0 if d.dtype in (jnp.float64, jnp.float32) else 0
-                    lanes.append((jnp.where(ok, d, z), "sum"))
-                    lanes.append((ok.astype(jnp.int64), "sum"))
-                elif a.name == "min":
-                    big = jnp.inf if d.dtype in (jnp.float64, jnp.float32) else I64_MAX
-                    lanes.append((jnp.where(ok, d, big), "min"))
-                    lanes.append((ok.astype(jnp.int64), "sum"))
-                else:  # max
-                    small = -jnp.inf if d.dtype in (jnp.float64, jnp.float32) else -I64_MAX - 1
-                    lanes.append((jnp.where(ok, d, small), "max"))
-                    lanes.append((ok.astype(jnp.int64), "sum"))
-
-            def _neutral(dtype, op):
-                if op == "min":
-                    return jnp.inf if dtype in (jnp.float64, jnp.float32) else I64_MAX
-                if op == "max":
-                    return -jnp.inf if dtype in (jnp.float64, jnp.float32) else -I64_MAX - 1
-                return jnp.zeros((), dtype)
+                if a.name != "count":
+                    op = MERGE_OPS[a.name][0]
+                    lanes.append((jnp.where(ok, d, merge_identity(d.dtype, op)), op))
+                lanes.append((ok.astype(jnp.int64), "sum"))
 
             def seg_reduce(key, vals, max_run: int):
                 """Scatter-free segmented reduce: sort by key, run totals
                 land on each run's FIRST slot. Sum/count lanes use one
                 cumsum + run-boundary gathers (3 vector passes); min/max
                 lanes use distance-doubling combines (log2(max_run)
-                passes). No segment_* scatters anywhere — XLA:CPU
+                passes). No scatter anywhere — XLA:CPU
                 serializes them and TPU pays scatter overhead."""
                 order = jnp.argsort(key)
                 sk = key[order]
@@ -2026,7 +1987,7 @@ class MPPEngine:
                         for i in need_doubling:
                             a = arrs[i]
                             op = vals[i][1]
-                            neut = _neutral(a.dtype, op)
+                            neut = merge_identity(a.dtype, op)
                             sh = jnp.concatenate([a[d:], jnp.full((d,), neut, a.dtype)])
                             contrib = jnp.where(same, sh, neut)
                             if op == "min":
@@ -2044,7 +2005,7 @@ class MPPEngine:
                 valid = fvalid
                 score = topn_score(fvals, valid)
                 kk = min(n_cands, int(score.shape[0]))
-                tvals, idx = jax.lax.top_k(score, kk)
+                tvals, idx = top_k(score, kk)
                 outs = [fkey[idx], valid[idx]]
                 outs.extend(v[idx] for v in fvals)
                 return tuple(outs) + tie_lane(score, tvals)
@@ -2066,7 +2027,7 @@ class MPPEngine:
             vals2 = []
             for i, (_, op) in enumerate(lanes):
                 arr = new_map[i][0]
-                arr = jnp.where(ex_mask, arr, _neutral(arr.dtype, op))
+                arr = jnp.where(ex_mask, arr, merge_identity(arr.dtype, op))
                 vals2.append((arr, op))
             # 3. final reduce: each key has at most one fragment per source
             # device, so n_dev bounds the run length
@@ -2090,13 +2051,13 @@ class MPPEngine:
             pres = agg_meta["rp_presence"]
             lanes = []
             for a, ra in zip(agg.aggs, agg_meta["r_args"]):
-                lanes.extend(self._agg_partials(a, ra, lanemap, mask, seg, Bp, eval_dev))
+                lanes.extend(zip(agg_partials(a, ra, lanemap, mask, seg, Bp),
+                                 MERGE_OPS[a.name]))
             base = 0
             if pres is None:
                 # no aggregate lane provably equals the presence count:
                 # scatter a dedicated one
-                lanes.insert(0, (jax.ops.segment_sum(
-                    mask.astype(jnp.int64), seg, num_segments=Bp + 1)[:Bp], "sum"))
+                lanes.insert(0, (seg_sum(mask.astype(jnp.int64), seg, Bp), "sum"))
                 base = 1
             if n_dev == 1:
                 full = [arr for arr, _ in lanes]
@@ -2126,7 +2087,7 @@ class MPPEngine:
             # groups are harmless — the host TopN re-cuts exactly)
             kk = min(max(n_cands, len(full) + 4), blk)
             with jax.named_scope("topk"):
-                tvals, idx = jax.lax.top_k(score, kk)
+                tvals, idx = top_k(score, kk)
                 tie = tie_lane(score, tvals)
             gidx = (didx.astype(jnp.int64) * blk + idx.astype(jnp.int64))
             outs = [jnp.where(valid[idx], gidx, -1), valid[idx]]
@@ -2141,7 +2102,7 @@ class MPPEngine:
             arrives SORTED by the group level's probe key and shard-split
             at run boundaries (_clustered_splits), so each group is one
             contiguous run wholly on one device. Run totals land on each
-            run's first position by shifted adds (_run_totals), as many
+            run's first position by shifted adds (`run_totals`), as many
             passes as the longest run the host counted needs: no scan, no
             gather of stream length, NO B-wide scatter, no psum, no
             exchange anywhere. Each device top-ks its own complete groups
@@ -2159,13 +2120,7 @@ class MPPEngine:
             pres = agg_meta["rp_presence"]
             lanes = []
             for a, ra in zip(agg.aggs, agg_meta["r_args"]):
-                if ra:
-                    d, v = eval_dev(ra[0], lanemap)
-                    d = jnp.broadcast_to(d, mask.shape) if getattr(d, "ndim", 0) == 0 else d
-                    v = jnp.broadcast_to(v, mask.shape) if getattr(v, "ndim", 0) == 0 else v
-                else:
-                    d = jnp.ones(mask.shape, jnp.int64)
-                    v = jnp.ones(mask.shape, bool)
+                d, v = agg_arg(ra, lanemap, mask.shape)
                 ok = mask & v
                 if a.name == "count":
                     lanes.append(count_lane(ok))
@@ -2181,7 +2136,7 @@ class MPPEngine:
                 base = 1
             # values off the mask are zero already, so a masked row or a
             # shard's pad run adds nothing to the run it lies in
-            lanes = self._run_totals(kd, lanes, agg_meta["rp_run_bound"])
+            lanes = run_totals(kd, lanes, agg_meta["rp_run_bound"])
             match_cnt = lanes[0] if base == 1 else lanes[pres]
             # group id: the build row position the run's key probes to.
             # A run is one key, the LUT position depends on the key
@@ -2194,20 +2149,15 @@ class MPPEngine:
             score = topn_score(lanes, valid, base)
             kk = min(max(n_cands, len(lanes) - base + 6), nloc)
             with jax.named_scope("topk"):
-                tvals, ti = self._block_topk(score, kk)
+                tvals, ti = block_topk(score, kk)
                 tie = tie_lane(score, tvals)
             # a shard with fewer than kk scoreable groups exhausts
-            # _block_topk: once everything above the floor is taken it
-            # returns floor-valued picks whose INDEX can repeat an
-            # already-shipped valid position (argmax over an all-floor
+            # block_topk, whose exhausted picks can repeat the INDEX of an
+            # already-shipped valid position (argmax over an all-lowest
             # block is position 0), and a repeated group would be
             # double-summed by the host partial merge — mask exhausted
             # picks by VALUE, independent of the position they name
-            floor = (jnp.asarray(-jnp.inf, score.dtype)
-                     if score.dtype in (jnp.float64, jnp.float32)
-                     else jnp.asarray(jnp.iinfo(score.dtype).min,
-                                      score.dtype))
-            tvalid = valid[ti] & (tvals > floor)
+            tvalid = valid[ti] & (tvals > lane_bounds(score.dtype)[0])
             outs = [jnp.where(tvalid, gpos[ti], -1), tvalid]
             outs.extend(l[ti] for l in lanes[base:])
             return tuple(outs) + tie
@@ -2220,8 +2170,6 @@ class MPPEngine:
                 matrix (jaxenv.pack_rows, dtype tags in-band): each
                 device→host array read over a remote link costs a full
                 round-trip, so the program ships exactly ONE buffer."""
-                from ..jaxenv import pack_rows
-
                 d = sum(drop_acc) if drop_acc else jnp.zeros((), jnp.int64)
                 d = jax.lax.psum(d, axis)
                 outs = list(outs)
@@ -2244,16 +2192,15 @@ class MPPEngine:
             # fused partial aggregation + psum (exact int/scaled-decimal)
             with jax.named_scope("group"):
                 nseg = agg_meta["nseg"]
-                code = jnp.zeros(mask.shape, dtype=jnp.int32)
-                for g, dom, km in zip(agg.group_by, agg_meta["domains"], agg_meta["key_meta"]):
-                    d, v = lanemap[g.idx]
-                    lo = km[1] if km[0] == "int" else 0
-                    kd = (d.astype(jnp.int32) - lo + 1) * v
-                    code = code * (dom + 1) + kd
+                code = group_code(
+                    [lanemap[g.idx] + (km[1] if km[0] == "int" else 0, dom) for g, dom, km
+                     in zip(agg.group_by, agg_meta["domains"], agg_meta["key_meta"])],
+                    mask.shape)
                 seg = jnp.where(mask, code, nseg)
-                outs = [(jax.ops.segment_sum(mask.astype(jnp.int64), seg, num_segments=nseg + 1)[:nseg], "sum")]
+                outs = [(seg_sum(mask.astype(jnp.int64), seg, nseg), "sum")]
                 for a, ra in zip(agg.aggs, agg_meta["r_args"]):
-                    outs.extend(self._agg_partials(a, ra, lanemap, mask, seg, nseg, eval_dev))
+                    outs.extend(zip(agg_partials(a, ra, lanemap, mask, seg, nseg),
+                                    MERGE_OPS[a.name]))
                 red = {"sum": jax.lax.psum, "min": jax.lax.pmin, "max": jax.lax.pmax}
                 return with_drops([red[op](o, axis) for o, op in outs])
 
@@ -2265,196 +2212,30 @@ class MPPEngine:
         sm = shard_map(kernel, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs)
         return jax.jit(sm)
 
-    @staticmethod
-    def _topk_score(val, valid, desc, cnt=None):
-        """Sort lane for the fused ORDER-BY-agg top-k: invalid slots
-        sink to the dtype floor. The ascending negation happens INSIDE
-        the where — negating the where'd result would send every
-        invalid slot to the TOP of the order and crowd the real groups
-        out of the k slots. `cnt` (the sum's count lane, given when its
-        argument can be NULL) marks the groups whose sum is NULL: they
-        order as SQL orders NULL, above every value ascending and below
-        every value (still above the invalid slots) descending. All
-        three agg modes (sorted finish, rowpos, clustered) share this
-        helper so the sentinel semantics cannot diverge."""
-        if val.dtype not in (jnp.float64, jnp.float32):
-            val = val.astype(jnp.int64)  # the clustered count lanes are int32
-        score = val if desc else -val
-        if cnt is not None:
-            if val.dtype in (jnp.float64, jnp.float32):
-                null = -jnp.finfo(val.dtype).max if desc else jnp.inf
-            else:
-                null = -I64_MAX + 1 if desc else I64_MAX
-            score = jnp.where(cnt > 0, score, null)
-        return jnp.where(valid, score, MPPEngine._score_floor(val.dtype))
-
-    @staticmethod
-    def _score_floor(dtype):
-        """The score of a slot that holds no group (see _topk_score)."""
-        return -jnp.inf if dtype in (jnp.float64, jnp.float32) else -I64_MAX
-
-    @staticmethod
-    def _topn_lane_pos(aggs, agg_idx, base=0):
-        """Flat partial-lane index of the TopN aggregate: count ships
-        one lane, every other agg ships a (value, count) pair."""
-        lane_pos = base
-        for i, a in enumerate(aggs):
-            if i == agg_idx:
-                break
-            lane_pos += 1 if a.name == "count" else 2
-        return lane_pos
-
-    @staticmethod
-    def _run_bound(longest: int) -> int:
-        """The longest key run up to a power of two: what _run_totals
-        sums to, in log2 of it passes (one bucket, one program)."""
-        return 1 << max(longest - 1, 0).bit_length()
-
-    @staticmethod
-    def _run_totals(key, lanes, bound: int):
-        """Reverse segmented sums over a lane sorted by `key`: each
-        lane's total of a key run, at the run's FIRST position (the
-        positions behind it hold the tails). Distance doubling, as
-        seg_reduce does its min and max lanes: for d = 1, 2, 4, ... below
-        `bound`, a[i] += a[i + d] where position i + d holds i's key (the
-        lane is sorted, so equal ends are one run), the masks made once a
-        distance for every lane. Exact for runs of up to `bound`
-        positions, a power of two; a longer run would be cut short, so
-        the bound comes from a count of the data (_clustered_splits).
-        Integer lanes add the same integers in any order, wrap-around
-        included; a float lane adds a run's own values and nothing of
-        the stream before it."""
-        lanes = list(lanes)
-        d = 1
-        while d < min(bound, key.shape[0]):
-            same = jnp.concatenate([key[d:] == key[:-d], jnp.zeros((d,), bool)])
-            lanes = [
-                a + jnp.where(same, jnp.concatenate([a[d:], jnp.zeros((d,), a.dtype)]),
-                              jnp.zeros((), a.dtype))
-                for a in lanes
-            ]
-            d *= 2
-        return lanes
-
-    @staticmethod
-    def _block_topk(v, k: int, blk: int = 1024):
-        """Exact top-k over a long score lane without lax.top_k, which
-        sorts the whole array (XLA:CPU pays ~1s at 2M rows for k=16).
-        Block maxima + k extraction rounds touch O(n + k·(n/blk + blk))
-        elements instead: each round takes the global max among
-        per-block maxima, then recomputes only the winning block's max
-        with every already-taken position masked out. Returns (values,
-        indices into v), both length k."""
-        n = v.shape[0]
-        if v.dtype in (jnp.float64, jnp.float32):
-            lo = jnp.asarray(-jnp.inf, v.dtype)
-        else:
-            lo = jnp.asarray(jnp.iinfo(v.dtype).min, v.dtype)
-        pad = (-n) % blk
-        vp = jnp.concatenate([v, jnp.full((pad,), lo, v.dtype)]) if pad else v
-        m2 = vp.reshape(-1, blk)
-        bm = jnp.max(m2, axis=1)
-        bi = jnp.argmax(m2, axis=1).astype(jnp.int32)
-        vals, idxs = [], []
-        tb = jnp.full((k,), -1, jnp.int32)  # block of the t-th winner
-        tp = jnp.full((k,), -1, jnp.int32)  # in-block position of same
-        car = jnp.arange(blk, dtype=jnp.int32)
-        for t in range(k):
-            j = jnp.argmax(bm).astype(jnp.int32)
-            vals.append(bm[j])
-            idxs.append(j * blk + bi[j])
-            tb = tb.at[t].set(j)
-            tp = tp.at[t].set(bi[j])
-            row = jax.lax.dynamic_slice(m2, (j, jnp.zeros((), j.dtype)), (1, blk))[0]
-            taken = jnp.zeros(blk, bool)
-            for u in range(t + 1):  # k is ~16: the unrolled scan is tiny
-                taken = taken | ((tb[u] == j) & (car == tp[u]))
-            row = jnp.where(taken, lo, row)
-            bm = bm.at[j].set(jnp.max(row))
-            bi = bi.at[j].set(jnp.argmax(row).astype(jnp.int32))
-        # winners drawn from the pad tail (fewer than k real candidates)
-        # clip into range; their scores stay `lo` so validity masks them
-        return jnp.stack(vals), jnp.clip(jnp.stack(idxs), 0, n - 1)
-
-    @staticmethod
-    def _agg_partials(a, r_args, lanemap, mask, seg, nseg, eval_dev):
-        if r_args:
-            d, v = eval_dev(r_args[0], lanemap)
-            d = jnp.broadcast_to(d, seg.shape) if getattr(d, "ndim", 0) == 0 else d
-            v = jnp.broadcast_to(v, seg.shape) if getattr(v, "ndim", 0) == 0 else v
-        else:
-            d = jnp.ones(seg.shape, dtype=jnp.int64)
-            v = jnp.ones(seg.shape, dtype=bool)
-        ok = mask & v
-        if a.name == "count":
-            return [(jax.ops.segment_sum(ok.astype(jnp.int64), seg, num_segments=nseg + 1)[:nseg], "sum")]
-        if a.name in ("sum", "avg"):
-            if d.dtype in (jnp.float64, jnp.float32):
-                s = jax.ops.segment_sum(jnp.where(ok, d, 0.0), seg, num_segments=nseg + 1)[:nseg]
-            else:
-                s = jax.ops.segment_sum(jnp.where(ok, d.astype(jnp.int64), 0), seg, num_segments=nseg + 1)[:nseg]
-            cnt = jax.ops.segment_sum(ok.astype(jnp.int64), seg, num_segments=nseg + 1)[:nseg]
-            return [(s, "sum"), (cnt, "sum")]
-        if a.name in ("min", "max"):
-            if a.name == "min":
-                big = jnp.inf if d.dtype in (jnp.float64, jnp.float32) else I64_MAX
-                s = jax.ops.segment_min(jnp.where(ok, d, big), seg, num_segments=nseg + 1)[:nseg]
-                op = "min"
-            else:
-                small = -jnp.inf if d.dtype in (jnp.float64, jnp.float32) else -I64_MAX - 1
-                s = jax.ops.segment_max(jnp.where(ok, d, small), seg, num_segments=nseg + 1)[:nseg]
-                op = "max"
-            cnt = jax.ops.segment_sum(ok.astype(jnp.int64), seg, num_segments=nseg + 1)[:nseg]
-            return [(s, op), (cnt, "sum")]
-        raise NotImplementedError(a.name)
-
     # ------------------------------------------------------------ finalize
 
     @staticmethod
+    def _partial_fts(agg) -> list:
+        """Field types of the partial layout: group keys, then each
+        aggregate's partial states."""
+        return [g.ret_type for g in agg.group_by] + [
+            ft for a in agg.aggs for _, ft in a.partial_final_types()]
+
+    @staticmethod
     def _partial_agg_cols(agg, soj, outs, pos, sel, out_fts, oi) -> list[Column]:
-        """Per-agg partial-state columns (count / sum+count / min-max+
-        count lanes) from the device output arrays — the shared tail of
-        every agg finalizer. `sel` picks and orders the group rows,
-        `pos` indexes the first value lane, `oi` the first partial
-        field type. min/max over dict-coded lanes decode through the
-        vocab (code order == collation order)."""
-        G = len(sel)
+        """Per-agg partial-state columns from the device output arrays —
+        the shared tail of every agg finalizer. `sel` picks and orders
+        the group rows, `pos` indexes the first value lane, `oi` the
+        first partial field type."""
         cols: list[Column] = []
         for a in agg.aggs:
-            if a.name == "count":
-                cnt = np.asarray(outs[pos])[sel]
-                cols.append(Column(out_fts[oi], cnt.astype(np.int64), np.ones(G, bool)))
-                pos += 1
-                oi += 1
-                continue
-            s = np.asarray(outs[pos])[sel]
-            cnt = np.asarray(outs[pos + 1])[sel]
-            has = cnt > 0
-            pos += 2
-            if a.name in ("sum", "avg"):
-                sd = s if out_fts[oi].is_float() else s.astype(np.int64)
-                cols.append(Column(out_fts[oi], sd, has))
-                oi += 1
-                if a.name == "avg":
-                    cols.append(Column(out_fts[oi], cnt.astype(np.int64), np.ones(G, bool)))
-                    oi += 1
-            elif a.name in ("min", "max"):
-                ft = out_fts[oi]
-                arg = a.args[0] if a.args else None
-                vocab = None
-                if isinstance(arg, ExprCol):
-                    sd2, off = soj[arg.idx]
-                    vocab = sd2.vocabs.get(off)
-                if vocab is not None:
-                    data = np.empty(G, dtype=object)
-                    for j in range(G):
-                        data[j] = (vocab[int(s[j])]
-                                   if has[j] and 0 <= int(s[j]) < len(vocab) else None)
-                    cols.append(Column(ft, data, has))
-                else:
-                    data = s if ft.is_float() else np.where(has, s.astype(np.int64), 0)
-                    cols.append(Column(ft, data, has))
-                oi += 1
+            arg = a.args[0] if a.args else None
+            vocab = None
+            if isinstance(arg, ExprCol):
+                sd, off = soj[arg.idx]
+                vocab = sd.vocabs.get(off)
+            cols.extend(partial_columns(a, outs, pos, sel, out_fts[oi + len(cols):], vocab))
+            pos += len(MERGE_OPS[a.name])
         return cols
 
     def _finalize_rowpos(self, mplan, meta, scans, outs) -> Chunk:
@@ -2470,9 +2251,7 @@ class MPPEngine:
         valid = np.asarray(outs[1]).astype(bool)
         keep = np.nonzero(valid & (gidx >= 0) & (gidx < B))[0]
         rows = gidx[keep]
-        out_fts = [g.ret_type for g in agg.group_by]
-        for a in agg.aggs:
-            out_fts.extend(ft for _, ft in a.partial_final_types())
+        out_fts = self._partial_fts(agg)
         cols: list[Column] = []
         oi = 0
         # a group-by column that is the level's probe key reads the build
@@ -2495,36 +2274,13 @@ class MPPEngine:
         agg = mplan.agg
         agg_meta = meta["agg"]
         soj = meta["scan_of_joined"]
-        nseg = agg_meta["nseg"]
         group_count = np.asarray(outs[0])
         present = np.nonzero(group_count > 0)[0]
-        G = len(present)
-        out_fts = [g.ret_type for g in agg.group_by]
-        for a in agg.aggs:
-            out_fts.extend(ft for _, ft in a.partial_final_types())
-        cols: list[Column] = []
-        radix = [d + 1 for d in agg_meta["domains"]]
-        codes = present.copy()
-        key_vals = []
-        for r in reversed(radix):
-            key_vals.append(codes % r)
-            codes = codes // r
-        key_vals.reverse()
-        oi = 0
-        for km, kv in zip(agg_meta["key_meta"], key_vals):
-            ft = out_fts[oi]
-            valid = kv > 0
-            if km[0] == "dict":
-                vocab = km[1]
-                data = np.empty(G, dtype=object)
-                for j, c in enumerate(kv):
-                    data[j] = vocab[c - 1] if c > 0 else None
-            else:
-                data = (kv.astype(np.int64) - 1) + km[1]
-                data[~valid] = 0
-            cols.append(Column(ft, data, valid))
-            oi += 1
-        cols.extend(self._partial_agg_cols(agg, soj, outs, 1, present, out_fts, oi))
+        out_fts = self._partial_fts(agg)
+        cols = group_key_columns(
+            present, [(0, dom, km[1]) if km[0] == "dict" else (km[1], dom, None)
+                      for km, dom in zip(agg_meta["key_meta"], agg_meta["domains"])], out_fts)
+        cols.extend(self._partial_agg_cols(agg, soj, outs, 1, present, out_fts, len(cols)))
         return Chunk(cols)
 
     def _finalize_topk(self, mplan, meta, outs) -> Chunk:
@@ -2539,9 +2295,7 @@ class MPPEngine:
         keep = np.nonzero(valid & (codes != np.iinfo(np.int64).max))[0]
         G = len(keep)
         codes = codes[keep]
-        out_fts = [g.ret_type for g in agg.group_by]
-        for a in agg.aggs:
-            out_fts.extend(ft for _, ft in a.partial_final_types())
+        out_fts = self._partial_fts(agg)
         cols: list[Column] = []
         oi = 0
         for km, st, radix in zip(agg_meta["key_meta"], agg_meta["strides"], agg_meta["radixes"]):

@@ -5,8 +5,8 @@ and `go test -race` in CI; this package is that discipline rebuilt for
 the Python reproduction, whose concurrency surface (per-lane runner
 threads, the cross-session batcher, the MemTracker tree's strict
 child→parent lock order, per-lane breakers, three TLS bind seams) had
-exactly ONE narrow static check to its name (`tools/lint_boundaries.py`,
-PR 8) while four of the last five PRs shipped "post-review hardening"
+exactly ONE narrow static check to its name (the PR 8 boundary lint,
+now the boundary-taxonomy pass) while four of the last five PRs shipped "post-review hardening"
 lists dominated by mechanically-catchable bug classes.
 
 Two halves:

@@ -1,6 +1,6 @@
 """boundary-taxonomy: device engine boundaries may only catch the TYPED
 error taxonomy (the PR 8 lint, generalized onto the analyzer framework;
-`tools/lint_boundaries.py` remains as a thin CLI shim over this pass).
+`python -m tools.analyze --only boundary-taxonomy` runs it alone).
 
 A `except Exception` / bare `except:` at a device boundary silently
 swallows interrupts, quota verdicts and real lowering bugs behind the

@@ -31,7 +31,7 @@ cd "$(dirname "$0")/.." || exit 1
 # static analyzer suite (PR 9): lock-discipline, tls-bind, interrupt-gate,
 # registry-consistency, boundary-taxonomy — any finding not allowlisted
 # (with a written reason) is a red tier-1. Subsumes the PR 8 boundary
-# lint (tools/lint_boundaries.py remains as a shim over the same pass).
+# lint (`python -m tools.analyze --only boundary-taxonomy` runs it alone).
 ANALYZE_ARGS=""
 RUN_BENCH=0
 while [ $# -gt 0 ]; do
