@@ -116,14 +116,49 @@ def _cop_cases():
     }
 
 
+def _largest_sort_operand(compiled) -> int:
+    """Elements of the largest operand of any `sort` the compiler left
+    in the module (a `lax.top_k` over int64 is one, of three operands:
+    the key's two u32 halves and the position); 0 when it holds none."""
+    import re
+
+    sizes = [0]
+    for types in re.findall(r"= \(?([^=]*?)\)? sort\(", compiled.as_text()):
+        for dims in re.findall(r"\w+\[([\d,]*)\]", types):
+            sizes.append(int(np.prod([int(d) for d in dims.split(",") if d])))
+    return max(sizes)
+
+
 @pytest.mark.parametrize("case", ["q6", "q1", "topn", "sorted_agg"])
 def test_cop_program_compiles_for_v5e(region, one_chip, case):
+    from tidb_tpu.kernels.primitives import topk_blocks
+
     eng, plan_of = region
     sql, family = _cop_cases()[case]
     plan = plan_of(sql)
     assert plan.key[0] == family
     compiled = jax.jit(eng._raw[plan.key]).lower(*_shapes(plan.args, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
+    if case == "topn":
+        # PR 33: no sort of the region's 2,097,152 rows; the largest is of
+        # the 16,384 block maxima (then the 12,800 candidates)
+        assert plan.topk_blk == topk_blocks(REGION_ROWS, 100) == 128
+        assert _largest_sort_operand(compiled) == REGION_ROWS // 128 < 1 << 20
+    elif case == "sorted_agg":
+        assert _largest_sort_operand(compiled) >= REGION_ROWS  # the reader reads a full sort
+
+
+def test_vmapped_topn_group_compiles_for_v5e(region, one_chip):
+    """The 8-wide launch group a 16M-row TopN runs as: `top_k`'s pruned
+    form under `jax.vmap`, eight regions' block maxima a sort."""
+    from tidb_tpu.models import tpch
+
+    eng, plan_of = region
+    plan = plan_of(tpch.TOPN)
+    group = eng._vmapped_program(plan.key, 8, None)
+    compiled = group.fn.lower(*[_shapes(plan.args, one_chip)] * 8).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
+    assert _largest_sort_operand(compiled) == 8 * (REGION_ROWS // 128) < 1 << 20
 
 
 def test_vmapped_q1_group_compiles_for_v5e(region, one_chip):

@@ -51,6 +51,7 @@ from ..kernels.primitives import (
     seg_max,
     seg_sum,
     top_k,
+    topk_blocks,
 )
 from ..mysqltypes.mydecimal import pow10
 from .dag import DAGRequest
@@ -244,14 +245,27 @@ class DevicePlan:
     vmap, so results are bit-identical to solo `launch`+`finalize`.
     """
 
-    __slots__ = ("launch", "finalize", "key", "args", "rows")
+    __slots__ = ("launch", "finalize", "key", "args", "rows", "topk_blk")
 
-    def __init__(self, launch, finalize, key=None, args=None, rows=0):
+    def __init__(self, launch, finalize, key=None, args=None, rows=0, topk_blk=None):
         self.launch = launch
         self.finalize = finalize
         self.key = key  # program-cache key, shared ⇒ vmap-compatible
         self.args = args  # (flat_lanes, row_valid) device inputs
         self.rows = rows  # real (unpadded) row count of the batch
+        # a TopN plan: the block length `top_k` prunes its lane by, 0 for
+        # the plain sort of the lane (`topk_blocks`); None on any other plan
+        self.topk_blk = topk_blk
+
+
+def _note_topk(lower, plans):
+    """`topk_blk` on a `cop.lower` span that lowered a TopN: which form
+    of `top_k` its programs took (the smallest block length, so one
+    plain sort among them reads 0)."""
+    blks = [p.topk_blk for p in plans
+            if isinstance(p, DevicePlan) and p.topk_blk is not None]
+    if blks:
+        lower.args["topk_blk"] = min(blks)
 
 
 class DeviceLane:
@@ -567,8 +581,9 @@ class TPUEngine:
                 t0 = time.perf_counter_ns()
                 launched = False
                 try:
-                    with TL.span("cop.lower", tasks=1, groups=1):
+                    with TL.span("cop.lower", tasks=1, groups=1) as lower:
                         plan = self._plan_for(dag, batch, lane)
+                        _note_topk(lower, [plan])
                     if plan is None:
                         with self._lock:
                             self.fallbacks += 1
@@ -638,6 +653,7 @@ class TPUEngine:
                 p.key for p in plans
                 if isinstance(p, DevicePlan) and p.key is not None and p.args is not None
             })
+            _note_topk(lower, plans)
         results: list = [None] * len(items)
         fusable: dict = {}  # program key -> [task index]
         launched = []  # (kind, payload) in launch order
@@ -1368,9 +1384,11 @@ class TPUEngine:
             chunk = dev.batch.to_chunk(dag.scan.col_offsets)
             return chunk.take(idx[: dag.topn.n])
 
+        lane_rows = dev.row_valid.size
         return DevicePlan(
             lambda: fn(arrs, dev.row_valid), finalize,
             key=key, args=(arrs, dev.row_valid), rows=dev.batch.n_rows,
+            topk_blk=topk_blocks(lane_rows, min(n, lane_rows)),
         )
 
     def _lower_topn_multi(self, dag: DAGRequest, dev: DeviceBatch, lanes, vocabs, r_conds, sig):

@@ -7,6 +7,8 @@ how each is spelled on the device is decided here and nowhere else.
 
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 from ..jaxenv import jax, jnp
@@ -262,11 +264,69 @@ def lex_sort_perm(ops):
 # (`lane_bounds` lowest).
 
 
+# a block of `top_k`'s pruned form is a row of the [n / blk, blk] view the
+# maxima reduce over and the k rows are gathered from: at least the TPU's
+# 128 lanes, a shorter row pads to them
+TOPK_MIN_BLK = 128
+
+
+def topk_blocks(n: int, k: int) -> int:
+    """The block length `top_k` prunes a lane of `n` scores by for its
+    `k` largest, 0 where it takes the plain `lax.top_k`. From the static
+    shape alone: the power of two nearest sqrt(n / k) (the two sorts,
+    of n / blk maxima and of k * blk candidates, are then of one size),
+    at least `TOPK_MIN_BLK`; the pruned form only where the candidates
+    are at most a quarter of the lane (short lanes and `k` near `n` keep
+    the one sort)."""
+    if k < 1 or n < 4 * k:
+        return 0
+    blk = max(TOPK_MIN_BLK, 1 << round(math.log2(n / k) / 2))
+    return blk if 4 * k * blk <= n else 0
+
+
 def top_k(score, k: int):
-    """(values, positions) of the k largest of a score lane. THE site
-    that picks the algorithm for a top-k over a whole lane: `lax.top_k`
-    today, which the TPU lowers to a full sort of the lane."""
-    return jax.lax.top_k(score, k)
+    """(values, positions) of the k largest of a score lane: the values
+    and the positions `lax.top_k(score, k)` gives, ties included (the
+    lower position first). THE site that picks the algorithm for a top-k
+    over a whole lane. `lax.top_k` alone is a full sort of the lane on
+    the TPU (an int64 lane of 2^21 sorts as three u32 operands); where
+    `topk_blocks` gives a block length the lane is pruned first, exactly:
+
+    1. the maximum of each block of `blk` scores (one pass, no sort);
+    2. the k blocks with the largest maxima (`lax.top_k` of n / blk
+       values), put in ascending block order;
+    3. those k blocks as rows: k * blk candidates in position order;
+    4. `lax.top_k` of the candidates; position = block * blk + column.
+
+    Why it is exact. Let v be the k-th largest score and g < k the count
+    of scores above v; `lax.top_k` answers those g and the first k - g
+    scores equal to v, by position. A block left out has a maximum m,
+    and the k chosen blocks have maxima >= m (ties at m go to the lower
+    block, as `lax.top_k` breaks them). Were m > v, k + 1 scores above v
+    would exist, so m <= v: every score above v is a candidate. A
+    left-out score EQUAL to v makes its block's maximum v, so every
+    chosen block holds a score above v (at most g blocks can) or has
+    the maximum v and a lower block index: at least k - g blocks, a v
+    each, all before the left-out one. So the first k - g scores equal
+    to v are candidates too, and with the candidates in position order
+    step 4 breaks ties as the sort of the whole lane does. No capacity,
+    no overflow flag, no fallback branch: the group programs are
+    `jax.vmap` of the raw kernel, where a `lax.cond` runs both sides."""
+    n = score.shape[-1]
+    blk = topk_blocks(n, k)
+    if not blk:
+        with jax.named_scope("topk.sort"):
+            return jax.lax.top_k(score, k)
+    with jax.named_scope("topk.blocks"):
+        pad = (-n) % blk
+        if pad:  # the lowest value at the highest positions: never before a real score
+            lo, _ = lane_bounds(score.dtype)
+            score = jnp.concatenate([score, jnp.full((pad,), lo, score.dtype)])
+        rows = score.reshape(-1, blk)
+        _, b = jax.lax.top_k(jnp.max(rows, axis=1), k)
+        b = jnp.sort(b)
+        vals, c = jax.lax.top_k(rows[b].reshape(-1), k)
+        return vals, b[c // blk] * blk + c % blk
 
 
 def score_floor(dtype):
@@ -298,10 +358,11 @@ def topk_score(val, valid, desc, cnt=None):
 
 
 def block_topk(v, k: int, blk: int = 1024):
-    """Exact top-k over a long score lane without lax.top_k, which
-    sorts the whole array (XLA:CPU pays ~1s at 2M rows for k=16).
-    Block maxima + k extraction rounds touch O(n + k·(n/blk + blk))
-    elements instead: each round takes the global max among
+    """Exact top-k over a long score lane with no sort at all, for a k
+    small enough to unroll (~16; the clustered epilogue's form). `top_k`
+    prunes by block maxima too, but sorts its candidates once, which a
+    k of 100 needs; this one extracts: block maxima + k rounds touch
+    O(n + k·(n/blk + blk)) elements, each round takes the global max among
     per-block maxima, then recomputes only the winning block's max
     with every already-taken position masked out. Returns (values,
     indices into v), both length k. Once fewer than k positions stand
