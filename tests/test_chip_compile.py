@@ -258,15 +258,22 @@ def test_q3_mpp_program_compiles_for_four_chips(topo, monkeypatch):
     assert "all-to-all" in text or "all-reduce" in text
 
 
-def test_q3_two_key_topn_program_compiles_for_one_chip(one_chip, topo, monkeypatch):
+@pytest.mark.parametrize("chips,shard", [(1, 262_144), (4, 65_536)], ids=["1-chip", "4-chips"])
+def test_q3_two_key_topn_program_compiles_for_v5e(topo, monkeypatch, chips, shard):
     """Q3 as TPC-H writes it (`ORDER BY revenue DESC, o_orderdate LIMIT
     10`, grouped by the stream's `l_orderkey`): the clustered program with
     the fused two-key TopN (the block top-k of 16 candidates and the
-    tie-overflow lane), as `tpch_q3_streams` runs it on one chip. By the
-    compiler's own list (the optimized HLO) the program gathers the
-    stream twice, for the ORDERS LUT and its mask under `join.lut/`, and
-    never under `group/`: the run totals are shifted adds (ISSUE 31;
-    three more gathers at every run's end before it)."""
+    tie-overflow lane), as `tpch_q3_streams` runs it on one chip and
+    `tpch_q3_mesh_x4` on the four of a host (the stream cut at key-run
+    edges into four shards, the LUTs replicated). By the compiler's own
+    list (the optimized HLO, one device's part of it) the program gathers
+    its shard of the stream twice, for the ORDERS LUT and its mask under
+    `join.lut/`, and never under `group/`: the run totals are shifted
+    adds (ISSUE 31; three more gathers at every run's end before it). On
+    four chips the one collective the clustered program traces stays in
+    the module: the `psum` of the drop counter (a constant zero without
+    an exchange) is an `all-reduce` of one int64, so a statement's four
+    parts end together; nothing else crosses the chips."""
     import re
 
     from jax.sharding import Mesh, NamedSharding
@@ -281,14 +288,15 @@ def test_q3_two_key_topn_program_compiles_for_one_chip(one_chip, topo, monkeypat
     s.vars["tidb_cop_engine"] = "tpu"
     s.vars["tidb_allow_mpp"] = "ON"
     mpp = s.cop.mpp
-    mpp._mesh = make_mesh(1)
+    mpp._mesh = make_mesh(chips)
     built, build_program = _record_mpp_builds(mpp, monkeypatch)
     assert len(s.must_query(tpch.Q3_SPEC)) == 10
     assert mpp.fallbacks == 0, mpp.last_fallback_reason
     assert mpp.last_agg == {"agg_mode": "clustered", "topn_keys": 2, "decline": ""}
     ((mplan, meta, scan_arg_meta, axis, n_dev, in_specs, lut_fids), args), = built
+    assert n_dev == chips <= len(topo.devices)
 
-    chip_mesh = Mesh(np.array(topo.devices[:1]), (axis,))
+    chip_mesh = Mesh(np.array(topo.devices[:chips]), (axis,))
     prog = build_program(mplan, meta, scan_arg_meta, chip_mesh, axis, n_dev, in_specs, lut_fids)
     shapes = [
         jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(chip_mesh, spec))
@@ -298,9 +306,11 @@ def test_q3_two_key_topn_program_compiles_for_one_chip(one_chip, topo, monkeypat
     at = 0
     for _fid, offs, is_sharded, _pref in scan_arg_meta:
         if is_sharded:
-            stream = args[at].shape[0]  # the one sharded scan: the compacted, padded lineitem
+            stream = args[at].shape[0] // chips  # a device's shard of the one sharded scan: the compacted, padded lineitem
         at += 2 + 2 * len(offs)
-    assert meta["agg"]["rp_run_bound"] == 16 and stream == 262_144
+    assert meta["agg"]["rp_run_bound"] == 16 and stream == shard
     scopes = [re.search(r'op_name="([^"]*)"', line).group(1) for line in text.splitlines()
               if re.search(rf"= \w+\[{stream}[,\]][^=]* gather\(", line)]
     assert len(scopes) == 2 and all("join.lut/" in sc and "group/" not in sc for sc in scopes), scopes
+    crossing = set(re.findall(r"\b(all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)\b", text))
+    assert crossing == ({"all-reduce"} if chips > 1 else set())

@@ -9,6 +9,7 @@ import threading
 import time
 import urllib.request
 
+import jax
 import pytest
 
 from tidb_tpu.session import Session
@@ -410,6 +411,23 @@ class TestBoundaryHook:
         missing = [n for n in list(TL.BOUNDARIES) + ["stmt.plan"] if f"`{n}`" not in text]
         assert not missing, missing
 
+    def test_what_the_mesh_adds_is_documented(self):
+        """README's span table names the arguments the mesh added to the
+        MPP spans (ISSUE 34) on the rows of the spans that carry them,
+        and its metrics table the shard series the hook's table feeds."""
+        import os
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "README.md")) as f:
+            rows = {line.split("|")[1].strip(): line for line in f if line.lstrip().startswith("| `")}
+        for span, args in {"mpp.launch": ("shards", "shard_rows", "shard_len"),
+                           "mpp.prepare": ("shards", "shard_rows", "shard_len"),
+                           "mpp.fetch": ("devices",), "mpp.merge": ("devices", "candidates")}.items():
+            assert all(f"`{a}`" in rows[f"`{span}`"] for a in args), span
+        assert TL.BOUNDARIES["mpp.launch"].shard_rows == "shard_rows"
+        assert "`tidb_tpu_mpp_shard_rows_total{shard}`" in rows["`mpp.launch`"]
+        assert "shard" in rows["`tidb_tpu_mpp_shard_rows_total`"]
+
     def test_launch_scope_keeps_the_outer_id(self):
         with TL.launch_scope(5):
             with TL.launch_scope(6):  # a re-run inside a grouped launch
@@ -639,6 +657,7 @@ class TestMppLaunchSpans:
         d2h0 = M.TPU_TRANSFER_BYTES.value(dir="d2h")
         c0 = _hist_sum("tidb_tpu_compile_seconds_count")
         built0 = q3.cop.mpp.compile_count
+        shard0 = [M.TPU_MPP_SHARD_ROWS.value(shard=str(i)) for i in range(len(jax.devices()))]
         cold_rows = q3.must_query(tpch.Q3)
         h2d1 = M.TPU_TRANSFER_BYTES.value(dir="h2d")
         d2h1 = M.TPU_TRANSFER_BYTES.value(dir="d2h")
@@ -662,18 +681,36 @@ class TestMppLaunchSpans:
         assert len(mpp) == 2
         cold, warm = mpp
         names = lambda l: [e.name for e in _children_of(evs, l)]  # noqa: E731
-        assert set(names(cold)) == {"mpp.prepare", "mpp.upload", "mpp.compile", "mpp.fetch", "mpp.finalize"}
-        assert set(names(warm)) == {"mpp.prepare", "mpp.dispatch", "mpp.fetch", "mpp.finalize"}
+        assert set(names(cold)) == {"mpp.prepare", "mpp.upload", "mpp.compile", "mpp.fetch", "mpp.finalize",
+                                    "mpp.merge"}
+        assert set(names(warm)) == {"mpp.prepare", "mpp.dispatch", "mpp.fetch", "mpp.finalize", "mpp.merge"}
+        # every MPP boundary of the hook's table but the launch itself and the gather before it
         assert set(names(cold)) | set(names(warm)) == {
-            "mpp.prepare", "mpp.upload", "mpp.compile", "mpp.dispatch", "mpp.fetch", "mpp.finalize"}
+            n for n in TL.BOUNDARIES if n.startswith("mpp.")} - {"mpp.gather", "mpp.launch"}
+        n_dev = len(jax.devices())
         for l in mpp:
             assert l.args["outcome"] == "ok" and len(l.args["program"]) == 12
-            assert l.args["mesh"].startswith("dp=") and l.lane.startswith("mesh:dp=")
-            kids = sorted(_children_of(evs, l), key=lambda e: e.t_start_ns)
+            assert l.args["mesh"] == f"dp={n_dev}" and l.lane.startswith("mesh:dp=")
+            (merge,) = [e for e in _children_of(evs, l) if e.name == "mpp.merge"]
+            kids = sorted((e for e in _children_of(evs, l) if e is not merge), key=lambda e: e.t_start_ns)
             assert [k.name for k in kids][0] == "mpp.prepare" and kids[-1].name == "mpp.finalize"
             for a, b in zip(kids, kids[1:]):
                 assert a.t_end_ns <= b.t_start_ns  # siblings, in order
-        assert cold.args["program"] == warm.args["program"]
+            # the cross-device candidate merge is the one grandchild, inside the finalize
+            assert kids[-1].t_start_ns <= merge.t_start_ns and merge.t_end_ns <= kids[-1].t_end_ns
+            assert merge.args["devices"] == n_dev and 10 <= merge.args["candidates"] <= n_dev * 16
+            # what the mesh adds: how the stream lies over the devices (clustered: run-aligned shards)
+            (prep,) = [e for e in kids if e.name == "mpp.prepare"]
+            for e in (l, prep):
+                assert e.args["shards"] == n_dev and len(e.args["shard_rows"]) == n_dev
+                assert 0 < max(e.args["shard_rows"]) <= e.args["shard_len"]
+            assert prep.args["shard_rows"] == l.args["shard_rows"]
+            (fetch,) = [e for e in kids if e.name == "mpp.fetch"]
+            assert fetch.args["devices"] == n_dev
+        assert cold.args["program"] == warm.args["program"] and cold.args["shard_rows"] == warm.args["shard_rows"]
+        # the shard series moved once a successful launch, by each shard's rows
+        for i, n in enumerate(cold.args["shard_rows"]):
+            assert M.TPU_MPP_SHARD_ROWS.value(shard=str(i)) - shard0[i] == 2 * n
         up = [e for e in _children_of(evs, cold) if e.name == "mpp.upload"]
         assert sum(e.args["bytes"] for e in up) == h2d1 - h2d0
         assert {e.args["kind"] for e in up} <= {"lane", "lut"}
@@ -729,6 +766,11 @@ class TestMppLaunchSpans:
         # behind the filter this fixture's longest run of l_orderkey is 5 to 8 rows: three passes
         for e in [l] + prepares:
             assert e.args.get("run_passes") == (3 if e.args["agg_mode"] == "clustered" else None), e.args
+            # the shards' layout goes with the mode too; the device count is said by every pass
+            assert ("shard_rows" in e.args) == ("shard_len" in e.args) == (e.args["agg_mode"] == "clustered")
+            assert e.args["shards"] == len(jax.devices())
+        # the candidate merge exists where candidates do: a pass that ships joined rows has none
+        assert len([e for e in kids if e.name == "mpp.merge"]) == (1 if mode == "clustered" else 0)
         stream = next(e.args["rows"] for e in evs if e.name == "mpp.gather")
         if keys:
             assert fetched[-1] < 64 * 1024

@@ -1134,7 +1134,10 @@ class MPPEngine:
         `mpp.prepare` (host analysis), `mpp.upload` (each cold lane or
         LUT), `mpp.compile` (first call of a program) or `mpp.dispatch`,
         `mpp.fetch` (the host blocked until the program has computed and
-        its packed result has crossed) and `mpp.finalize`. The SPMD
+        its packed result has crossed from `devices` devices) and
+        `mpp.finalize`, which in the rowpos modes encloses `mpp.merge`
+        (every device's candidate groups into one partial chunk:
+        `devices`, `candidates`). The SPMD
         program spans the whole mesh and no lock serializes dispatches,
         so the lane is the mesh's, split by calling thread like a
         resource group's: one thread's spans nest, two threads' may
@@ -1145,11 +1148,17 @@ class MPPEngine:
         when none), `decline` (the typed reason a faster mode or the
         fused TopN was refused, "" when none) and, in the clustered mode
         alone, `run_passes` (the shifted-add passes that sum a key run:
-        log2 of the longest run the host counted, up to a power of two)."""
+        log2 of the longest run the host counted, up to a power of two).
+        They also say how the stream lies over the mesh: `shards` (its
+        devices) and, in the clustered mode alone, `shard_rows` (the
+        rows of each run-aligned shard) and `shard_len` (the length
+        every shard pads to); a launch that ends `ok` adds its
+        `shard_rows` to `tidb_tpu_mpp_shard_rows_total{shard}`."""
         n_dev = mesh.shape[axis]
         trace = tracing.current_trace()
         said = {"outcome": "error", "program": "", "agg_mode": "",
-                "topn_keys": 0, "decline": "", "run_passes": None}
+                "topn_keys": 0, "decline": "", "run_passes": None,
+                "shards": n_dev, "shard_rows": None, "shard_len": None}
         t0 = time.perf_counter_ns()
         lane = f"mesh:{axis}={n_dev} ({threading.current_thread().name})"
         with TL.device_scope(lane), TL.launch_scope(tracing._next_id()):
@@ -1167,9 +1176,10 @@ class MPPEngine:
     @staticmethod
     def _said_agg(said: dict) -> dict:
         """What `mpp.prepare` and `mpp.launch` say of the aggregation."""
-        out = {k: said[k] for k in ("agg_mode", "topn_keys", "decline")}
-        if said["run_passes"] is not None:
-            out["run_passes"] = said["run_passes"]
+        out = {k: said[k] for k in ("agg_mode", "topn_keys", "decline", "shards")}
+        for k in ("run_passes", "shard_rows", "shard_len"):  # clustered alone
+            if said[k] is not None:
+                out[k] = said[k]
         return out
 
     def _execute(self, mplan, scans, mesh, variables, axis, gate, fused,
@@ -1303,7 +1313,7 @@ class MPPEngine:
                 sh = (hashlib.sha256(repr(src).encode()).hexdigest()[:12]
                       if ssel is not None else "")
                 koff = soj[agm["rp_ck"]][1]
-                _, _, rawmax, longest = self._clustered_splits(
+                splits, shard_len, rawmax, longest = self._clustered_splits(
                     ss, koff, sh, n_dev, ssel)
                 sn = len(ssel) if ssel is not None else ss.n_rows
                 if rawmax > max(2 * -(-sn // n_dev),
@@ -1328,10 +1338,16 @@ class MPPEngine:
         if use_topn:
             said["decline"] = (agm or {}).get("clustered_reason") or ""
         self.last_agg = {k: said[k] for k in ("agg_mode", "topn_keys", "decline")}
-        # clustered alone: how many shifted-add passes sum a run
+        # clustered alone: how many shifted-add passes sum a run, and how
+        # the stream lies over the mesh: the rows of each run-aligned
+        # shard and the length every shard pads to (the other modes cut
+        # the stream evenly and say `shards` only)
+        is_clustered = said["agg_mode"] == "clustered"
         said["run_passes"] = self.last_run_passes = (
-            agm["rp_run_bound"].bit_length() - 1
-            if said["agg_mode"] == "clustered" else None)
+            agm["rp_run_bound"].bit_length() - 1 if is_clustered else None)
+        said["shard_rows"] = ([b - a for a, b in zip(splits, splits[1:])]
+                              if is_clustered else None)
+        said["shard_len"] = shard_len if is_clustered else None
         TL.boundary("mpp.prepare", t_prep, time.perf_counter_ns(),
                     **self._said_agg(said))
         for s in scans:
@@ -1462,7 +1478,8 @@ class MPPEngine:
         t_fetch = time.perf_counter_ns()
         packed = np.asarray(out)
         t_fin = time.perf_counter_ns()
-        TL.boundary("mpp.fetch", t_fetch, t_fin, d2h_bytes=int(packed.nbytes))
+        TL.boundary("mpp.fetch", t_fetch, t_fin, d2h_bytes=int(packed.nbytes),
+                    devices=len(out.devices()))
         tie_overflow = False
         try:
             tick()
@@ -1491,7 +1508,11 @@ class MPPEngine:
                     if meta["agg"]["mode"] == "sorted":
                         return self._finalize_topk(mplan, meta, outs), True
                     if meta["agg"]["mode"] in ("rowpos", "clustered"):
-                        return self._finalize_rowpos(mplan, meta, scans, outs), True
+                        # every device's candidates become one partial chunk
+                        with TL.span("mpp.merge", devices=n_dev) as sp:
+                            chunk = self._finalize_rowpos(mplan, meta, scans, outs)
+                            sp.args["candidates"] = chunk.num_rows
+                        return chunk, True
                     return self._finalize_agg(mplan, meta, outs), True
                 return self._finalize_rows(mplan, meta, scans, outs), meta["agg"] is not None
         finally:

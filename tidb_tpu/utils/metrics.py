@@ -438,6 +438,10 @@ TPU_MPP_FUSED = REGISTRY.counter(
     "tidb_tpu_mpp_fused_total",
     "MPP dispatches by fusion outcome (fused | partial | unfused | off)",
 )
+TPU_MPP_SHARD_ROWS = REGISTRY.counter(
+    "tidb_tpu_mpp_shard_rows_total",
+    "stream rows each mesh shard of a clustered MPP dispatch held, by shard (padding not counted)",
+)
 TPU_BUILD_CACHE = REGISTRY.counter(
     "tidb_tpu_build_cache_total",
     "device-resident build-side cache lifecycle (hit | miss | evict | invalidate)",

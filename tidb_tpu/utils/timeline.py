@@ -345,13 +345,15 @@ class Boundary:
     (`tidb_tpu_device_execute_seconds`, by resource group). `stage`: the
     `tidb_tpu_tile_build_seconds` stage it is booked under, for its
     share of the wall (`_WallShare`). `trace_tags`: tags only the phase
-    event carries."""
+    event carries. `shard_rows`: the argument (a list, one entry a mesh
+    device) whose entries are added to `tidb_tpu_mpp_shard_rows_total`
+    by shard, when the span's `outcome` is "ok"."""
 
     __slots__ = ("cat", "trace_name", "ms_key", "counts", "dir", "seconds", "stage",
-                 "trace_tags")
+                 "trace_tags", "shard_rows")
 
     def __init__(self, cat, trace_name=None, ms_key=None, counts=(), dir=None,
-                 seconds=None, stage=None, trace_tags=None):
+                 seconds=None, stage=None, trace_tags=None, shard_rows=None):
         self.cat = cat
         self.trace_name = trace_name
         self.ms_key = ms_key
@@ -360,6 +362,7 @@ class Boundary:
         self.seconds = seconds
         self.stage = stage
         self.trace_tags = trace_tags
+        self.shard_rows = shard_rows
 
 
 _H2D = (("bytes", "h2d_bytes"),)
@@ -385,13 +388,14 @@ BOUNDARIES: dict[str, Boundary] = {
     "tile.encode": Boundary("tile", stage="encode"),
     # MPP path (executor/mpp_gather.py, parallel/mpp.py): children of mpp.launch
     "mpp.gather": Boundary("host"),
-    "mpp.launch": Boundary("launch"),
+    "mpp.launch": Boundary("launch", shard_rows="shard_rows"),
     "mpp.prepare": Boundary("host"),
     "mpp.upload": Boundary("transfer", "mpp.upload", "h2d_ms", _H2D, dir="h2d"),
     "mpp.compile": Boundary("compile", "mpp.compile", "compile_ms", seconds="compile"),
     "mpp.dispatch": Boundary("dispatch"),
     "mpp.fetch": Boundary("execute", "mpp.fetch", "execute_ms", _D2H, dir="d2h"),
     "mpp.finalize": Boundary("host"),
+    "mpp.merge": Boundary("host"),  # inside mpp.finalize
 }
 
 
@@ -462,6 +466,9 @@ def boundary(name: str, t_start_ns: int, t_end_ns: int, _seconds: float | None =
         M.TPU_EXECUTE_SECONDS.observe(dt_ns / 1e9, resource_group=current_group())
     if b.dir is not None:
         M.TPU_TRANSFER_BYTES.inc(args[b.counts[0][0]], dir=b.dir)
+    if b.shard_rows is not None and args.get("outcome") == "ok":
+        for i, n in enumerate(args.get(b.shard_rows) or ()):
+            M.TPU_MPP_SHARD_ROWS.inc(n, shard=str(i))
     if b.ms_key is not None:
         tracing.add_phase(b.ms_key, dt_ns / 1e6)
     for arg, key in b.counts:
