@@ -267,9 +267,13 @@ def test_q3_two_key_topn_program_compiles_for_v5e(topo, monkeypatch, chips, shar
     `tpch_q3_mesh_x4` on the four of a host (the stream cut at key-run
     edges into four shards, the LUTs replicated). By the compiler's own
     list (the optimized HLO, one device's part of it) the program gathers
-    its shard of the stream twice, for the ORDERS LUT and its mask under
-    `join.lut/`, and never under `group/`: the run totals are shifted
-    adds (ISSUE 31; three more gathers at every run's end before it). On
+    its shard of the stream ONCE, for the ORDERS level's mask under
+    `join.lut/` (ISSUE 35: the level's build row positions are an `s32`
+    argument lane laid out as the stream is, sharded with it, where the
+    program gathered the ORDERS LUT; the CUSTOMER level's are a replicated
+    lane of ORDERS' rows), and never under `group/`: the run totals are
+    shifted adds (ISSUE 31; three more gathers at every run's end before
+    it). On
     four chips the one collective the clustered program traces stays in
     the module: the `psum` of the drop counter (a constant zero without
     an exchange) is an `all-reduce` of one int64, so a statement's four
@@ -311,6 +315,13 @@ def test_q3_two_key_topn_program_compiles_for_v5e(topo, monkeypatch, chips, shar
     assert meta["agg"]["rp_run_bound"] == 16 and stream == shard
     scopes = [re.search(r'op_name="([^"]*)"', line).group(1) for line in text.splitlines()
               if re.search(rf"= \w+\[{stream}[,\]][^=]* gather\(", line)]
-    assert len(scopes) == 2 and all("join.lut/" in sc and "group/" not in sc for sc in scopes), scopes
+    assert len(scopes) == 1 and all("join.lut/" in sc and "group/" not in sc for sc in scopes), scopes
+    # one argument a LUT level after the scans' lanes: both are position lanes, none a LUT
+    (stream_pos, stream_spec), (orders_pos, orders_spec) = sorted(
+        zip(args[at:], in_specs[at:]), key=lambda a: -a[0].shape[0])
+    assert len(args) == at + 2 == at + len(lut_fids) and set(meta["pos_scan"]) == set(lut_fids)
+    assert stream_pos.dtype == orders_pos.dtype == np.int32
+    assert stream_pos.shape == (stream * chips,) and tuple(stream_spec) == (axis,)
+    assert orders_pos.shape == (MPP_ROWS // 4,) and tuple(orders_spec) == ()
     crossing = set(re.findall(r"\b(all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)\b", text))
     assert crossing == ({"all-reduce"} if chips > 1 else set())

@@ -18,6 +18,8 @@ from tidb_tpu.session import Session
 from tidb_tpu.utils import metrics as M
 from tidb_tpu.utils.failpoint import FP
 
+from test_mpp_join_pos import counted, pos_lanes
+
 
 @pytest.fixture(autouse=True)
 def _clean_failpoints():
@@ -41,8 +43,25 @@ def q3():
     return s
 
 
-def _run(s, mode):
-    """Q3 under `mode` in (fused, unfused, host); restores fused/auto."""
+# Q3 with a CUSTOMER column read above the joins: the CUSTOMER level then
+# probes every stream row with `o_custkey` as gathered from the ORDERS
+# level's build side, so its positions cannot be a resident lane (ISSUE
+# 35: `MPPEngine._level_forms`) and its LUT stays a device-resident
+# structure of the BuildSideCache. In `tpch.Q3` itself both levels take
+# their positions as lanes of the engine's device-lane cache and no LUT
+# goes to the device.
+Q3_LUT = tpch.Q3.replace("o.o_orderdate\nFROM", "o.o_orderdate, SUM(c.c_acctbal) AS bal\nFROM")
+assert Q3_LUT != tpch.Q3
+
+
+def _moved(before):
+    """How `tidb_tpu_mpp_join_pos_total` moved since `before = counted()`,
+    as (lane_hit, lane_built, in_program)."""
+    return tuple(int(b - a) for a, b in zip(before, counted()))
+
+
+def _run(s, mode, sql=tpch.Q3):
+    """`sql` (Q3) under `mode` in (fused, unfused, host); restores fused/auto."""
     if mode == "host":
         s.vars["tidb_allow_mpp"] = "OFF"
         s.vars["tidb_cop_engine"] = "host"
@@ -51,7 +70,7 @@ def _run(s, mode):
         s.vars["tidb_cop_engine"] = "auto"
         s.vars["tidb_tpu_mpp_fused"] = "ON" if mode == "fused" else "OFF"
     try:
-        return s.must_query(tpch.Q3)
+        return s.must_query(sql)
     finally:
         s.vars["tidb_allow_mpp"] = "ON"
         s.vars["tidb_cop_engine"] = "auto"
@@ -177,40 +196,56 @@ class TestBuildSideCache:
         s.vars["tidb_allow_mpp"] = "ON"
         m0 = M.TPU_BUILD_CACHE.value(outcome="miss")
         h0 = M.TPU_BUILD_CACHE.value(outcome="hit")
-        first = s.must_query(tpch.Q3)
+        j0 = counted()
+        first = s.must_query(Q3_LUT)
         misses = M.TPU_BUILD_CACHE.value(outcome="miss") - m0
-        assert misses >= 2, "orders + customer LUTs build on first dispatch"
-        second = s.must_query(tpch.Q3)
+        assert misses == 1, "the customer LUT builds on first dispatch; the ORDERS level's positions are a lane"
+        assert _moved(j0) == (0, 1, 1)
+        second = s.must_query(Q3_LUT)
         assert M.TPU_BUILD_CACHE.value(outcome="miss") == m0 + misses, \
             "second statement must not rebuild"
-        assert M.TPU_BUILD_CACHE.value(outcome="hit") - h0 >= 2
+        assert M.TPU_BUILD_CACHE.value(outcome="hit") - h0 >= 1
+        assert _moved(j0) == (1, 1, 2), "nor the stream's position lane"
         assert first == second
         assert s.store.build_cache.nbytes > 0
 
     def test_dml_version_bump_never_serves_stale(self):
         """A write to a dimension table bumps its data version (carried
         in the codec sig): the next dispatch purges the stale structure
-        (outcome=invalidate) and the answer tracks the host oracle."""
+        (outcome=invalidate) and the answer tracks the host oracle. The
+        position lanes of Q3 itself carry the versions of BOTH tables
+        they are made from: the ORDERS-row lane (by CUSTOMER's version)
+        is built again and the stale one evicted, the stream's (LINEITEM
+        and ORDERS, neither written) is served."""
         s = Session()
         tpch.setup_tpch(s, 30_000)
         s.vars["tidb_enable_cop_result_cache"] = "OFF"
         s.vars["tidb_allow_mpp"] = "ON"
         before = _run(s, "fused")
+        _run(s, "fused", Q3_LUT)
+        lanes = pos_lanes(s.cop.mpp)
+        assert len(lanes) == 2
         i0 = M.TPU_BUILD_CACHE.value(outcome="invalidate")
         # flip every customer into the Q3 segment: the build side the
         # cached LUT's lanes came from changes materially
         s.execute("UPDATE customer SET c_mktsegment = 'BUILDING'")
+        j0 = counted()
         after = _run(s, "fused")
-        assert M.TPU_BUILD_CACHE.value(outcome="invalidate") > i0
+        assert _moved(j0) == (1, 1, 0)
         assert after == _run(s, "host"), "stale build side served"
         assert after != before, "the update must change the top-10"
+        now = pos_lanes(s.cop.mpp)
+        assert len(now) == 2 and len(set(now) & set(lanes)) == 1, "the stale lane is evicted, not kept beside the new"
+        assert _run(s, "fused", Q3_LUT) == _run(s, "host", Q3_LUT)
+        assert M.TPU_BUILD_CACHE.value(outcome="invalidate") > i0
 
     def test_ddl_schema_bump_invalidates(self):
         s = Session()
         tpch.setup_tpch(s, 30_000)
         s.vars["tidb_enable_cop_result_cache"] = "OFF"
         s.vars["tidb_allow_mpp"] = "ON"
-        base = _run(s, "fused")
+        base = _run(s, "fused", Q3_LUT)
+        q3 = _run(s, "fused")
         bc = s.store.build_cache
         n0 = len(bc._od)
         assert n0 > 0
@@ -219,9 +254,10 @@ class TestBuildSideCache:
         # (an index on the predicate column would switch customer to an
         # index scan and never consult the cache at all)
         s.execute("ALTER TABLE customer ADD INDEX icn (c_name)")
-        again = _run(s, "fused")
+        again = _run(s, "fused", Q3_LUT)
         assert M.TPU_BUILD_CACHE.value(outcome="invalidate") > i0
-        assert again == base == _run(s, "host")
+        assert again == base == _run(s, "host", Q3_LUT)
+        assert _run(s, "fused") == q3 == _run(s, "host")
 
     def test_concurrent_duplicate_build_keeps_byte_ledger(self):
         """Two statements racing a miss on the same key both build (the
@@ -259,7 +295,8 @@ class TestBuildSideCache:
         tpch.setup_tpch(s, 30_000)
         s.vars["tidb_enable_cop_result_cache"] = "OFF"
         s.vars["tidb_allow_mpp"] = "ON"
-        warm = _run(s, "fused")
+        warm = _run(s, "fused", Q3_LUT)
+        q3 = _run(s, "fused")
         bc = s.store.build_cache
         assert bc.nbytes > 0 and len(bc._od) > 0
         e0 = M.TPU_BUILD_CACHE.value(outcome="evict")
@@ -278,7 +315,14 @@ class TestBuildSideCache:
             root.set_limit(0)
             root.degraded = False
         # next statement rebuilds and stays exact
-        assert _run(s, "fused") == warm
+        assert _run(s, "fused", Q3_LUT) == warm
+        assert len(bc._od) > 0
+        # Q3's position lanes are lanes of its scans: they lie in the
+        # engine's own budgeted device-lane cache with the stream's data
+        # lanes, which the sweep does not reach, and are served
+        j0 = counted()
+        assert _run(s, "fused") == q3
+        assert _moved(j0) == (2, 0, 0) and len(pos_lanes(s.cop.mpp)) == 2  # Q3_LUT shares the stream's
 
 
 class TestFusedChaosBattery:

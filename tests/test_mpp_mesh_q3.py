@@ -193,7 +193,7 @@ def run_and_watch(s, sql):
     (launch,) = [e for e in evs if e.name == "mpp.launch"]
     merges = [e for e in evs if e.name == "mpp.merge"]
     return SimpleNamespace(rows=rows, lanes=seen[-1] if seen else None, launch=launch.args, merges=merges,
-                           said=dict(s.cop.mpp.last_agg), fell=s.cop.mpp.fallbacks - before)
+                           said=dict(s.cop.mpp.last_agg), fell=s.cop.mpp.fallbacks - before, engine=s.cop.mpp)
 
 
 def stream_keys(tables):
@@ -305,6 +305,17 @@ def test_the_shares_add_up_to_the_whole(awkward):
     (merge,) = awkward.four.merges
     assert merge.args == {"devices": 4, "candidates": len(merged), "launch_id": awkward.four.launch["launch_id"]}
     assert awkward.one.merges[0].args["candidates"] == CANDS
+    # the stream's position lane (ISSUE 35) lies at the same shard edges as its data lanes: a
+    # shard's first rows are the ORDERS rows of its own keys in stream order, the rest is padding
+    assert awkward.four.launch["join_pos_lanes"] == awkward.one.launch["join_pos_lanes"] == 2
+    (lane,) = [np.asarray(a) for k, a in awkward.four.engine._dev_cache.items()
+               if k[4] and k[2][0] == "c" and k[2][2][0] == "jpos"]
+    lane = lane.reshape(4, awkward.four.launch["shard_len"])
+    row_of = {int(k): i for i, k in enumerate(od["o_orderkey"].tolist())}
+    for d in range(4):
+        n = cuts[d + 1] - cuts[d]
+        assert lane[d, :n].tolist() == [row_of[int(k)] for k in keys[cuts[d]:cuts[d + 1]]]
+        assert np.all(lane[d, n:] == -1)
 
 
 def test_the_shard_series_counts_each_shards_rows():

@@ -331,7 +331,7 @@ def test_explain_says_mode_keys_and_decline(db):
 
 
 @pytest.mark.parametrize("fused,golden,bound", [
-    ("ON", "1f22616198e61ea2dcb781ca73c8d03ce09acb1a8f05ee45aa348897464669b9", "16"),
+    ("ON", "ac5a983c9a3de187d3475df92ddf9506fed29ee68ce51289806bd60010d51b26", "16"),
     ("OFF", "f9bd4f4fb185a9055630d315d84456a98bc178cf25b805b2b25fe0c447542005", "None"),
 ])
 def test_one_key_program_key_is_unchanged(fused, golden, bound):
@@ -342,7 +342,11 @@ def test_one_key_program_key_is_unchanged(fused, golden, bound):
     parent of ISSUE 30 computed them. ISSUE 31 put ONE more string at
     the end of what the key hashes, the clustered aggregate's run bound
     (`None` in every other mode): without it the key is still the
-    golden one, letter for letter."""
+    golden one, letter for letter. ISSUE 35 changed the fused program
+    and so its golden key (`1f226161...` before): a LUT level says the
+    form it took (`pos:<scan>` | `lut`) and ORDERS ships no `o_custkey`
+    lane; with fusion off there is no LUT level and the key of ISSUE
+    30's parent stands."""
     import hashlib
 
     s = Session()
@@ -436,10 +440,8 @@ def test_clustered_row_bucket(n, bucket):
     assert bucket >= n and (n <= 1 << 20 or bucket <= n * 1.125)
 
 
-def _lowered_gathers(s, sql):
-    """The statement's MPP program as StableHLO: lengths of its gathers' results."""
-    import re
-
+def _lowered_text(s, sql):
+    """The statement's rows and its MPP program as StableHLO text."""
     texts = []
     orig = MPPEngine._build_program
 
@@ -460,23 +462,35 @@ def _lowered_gathers(s, sql):
         MPPEngine._build_program = orig
         s.cop.mpp._programs.clear()
     (text,) = texts
+    return rows, text
+
+
+def _lowered_gathers(s, sql):
+    """The statement's MPP program as StableHLO: lengths of its gathers' results."""
+    import re
+
+    rows, text = _lowered_text(s, sql)
     return rows, [int(n) for n in re.findall(r'"stablehlo.gather".*?-> tensor<(\d+)x', text)]
 
 
 def test_a_level_that_only_filters_probes_the_build_side(db):
     """Q3's CUSTOMER level keeps the ORDERS rows of one segment and gives
     nothing else: it probes the 6,000 ORDERS rows once, and the stream is
-    left with the ORDERS LUT and its mask: two stream-long gathers, none
-    for a row id or for `o_custkey`, and none for a run total (the sum's,
-    its count's, COUNT(*)'s: shifted adds since ISSUE 31, where three
-    gathers at every run's end were). A text that reads a CUSTOMER column
-    above the joins keeps the level on the stream, and stays exact."""
+    left with the ORDERS level. Since ISSUE 35 both levels read their
+    build row positions from a resident lane, so each is ONE gather, of
+    the build side's mask: none of a LUT, none for a row id or for
+    `o_custkey`, and none for a run total (the sum's, its count's,
+    COUNT(*)'s: shifted adds since ISSUE 31, where three gathers at every
+    run's end were). A text that reads a CUSTOMER column above the joins
+    keeps the level on the stream, where its probe key is gathered from
+    the ORDERS level's build side and its LUT in the program, and stays
+    exact."""
     s, tables = db
     rows, gathers = _lowered_gathers(s, q3_sql(REV_DATE, 10))
     assert_exact(rows, groups_of(tables, "BUILDING"), REV_DATE, 10)
     (stream,) = {n for n in gathers if n > 64 and n != N_ORDERS}  # a device's shard of the stream
-    assert gathers.count(stream) == 2, gathers
-    assert gathers.count(N_ORDERS) == 2, gathers  # the CUSTOMER LUT and mask, by ORDERS row
+    assert gathers.count(stream) == 1, gathers  # the ORDERS mask, by stream position
+    assert gathers.count(N_ORDERS) == 1, gathers  # the CUSTOMER mask, by ORDERS row
     sql = q3_sql(REV_DATE, 10).replace("COUNT(*) AS cnt", "COUNT(*) AS cnt, MAX(c.c_acctbal) AS bal")
     rows2, gathers2 = _lowered_gathers(s, sql)
     assert_exact(rows2, groups_of(tables, "BUILDING"), REV_DATE, 10)

@@ -112,8 +112,8 @@ class ScanData:
         return self._dev[off], self.valid[off]
 
 
-def _pad(a: np.ndarray, total: int):
-    out = np.zeros(total, dtype=a.dtype)
+def _pad(a: np.ndarray, total: int, fill=0):
+    out = np.full(total, fill, dtype=a.dtype)
     out[: len(a)] = a
     return out
 
@@ -299,9 +299,15 @@ class MPPEngine:
         is clustered by l_orderkey; any PK-ordered fact table qualifies).
         Cached per (table, version, offset) like every host analysis.
         Checked on the raw lane: a prefiltered selection (np.nonzero)
-        preserves order, so the compacted stream inherits it."""
+        preserves order, so the compacted stream inherits it. A lane
+        with a NULL is not clustered: the slot under a NULL holds some
+        value, a run is cut by values alone, and a run that STARTS on a
+        NULL would read its group id off a row whose join position says
+        "no key" (`_join_pos_lane`)."""
         def compute():
-            d, _ = sd.lane(off)
+            d, v = sd.lane(off)
+            if not v.all():
+                return False
             # lane() dict-encodes object lanes upstream, so the object
             # check is belt-and-braces — the guard that actually keeps
             # string keys off the fused path is prepare's typed
@@ -830,12 +836,14 @@ class MPPEngine:
                 # on host over the joined rows (group-key domains too wide
                 # for direct addressing, e.g. raw date/orderkey keys)
                 self.last_fallback_reason = "agg on host: group-key domain too wide"
-        return {
+        meta = {
             "scan_of_joined": scan_of_joined,
             "r_pushed": r_pushed,
             "levels": {id(l.frag): l for l in levels},
             "agg": agg_meta,
         }
+        meta["folds"], meta["pos_scan"] = self._level_forms(mplan, meta)
+        return meta
 
     @staticmethod
     def _pack_host(key_idxs, scan_of_joined, los, strides):
@@ -1153,12 +1161,20 @@ class MPPEngine:
         devices) and, in the clustered mode alone, `shard_rows` (the
         rows of each run-aligned shard) and `shard_len` (the length
         every shard pads to); a launch that ends `ok` adds its
-        `shard_rows` to `tidb_tpu_mpp_shard_rows_total{shard}`."""
+        `shard_rows` to `tidb_tpu_mpp_shard_rows_total{shard}`. And how
+        the LUT levels found their build rows: `join_pos_lanes` (levels
+        whose positions were an argument lane, `_level_forms`) on both,
+        `join_pos_built` (of those lanes, the ones this launch had to
+        build) on the launch, which ends after the lanes are put; a
+        launch that ends `ok` adds one a LUT level to
+        `tidb_tpu_mpp_join_pos_total{outcome}` (lane_hit | lane_built |
+        in_program)."""
         n_dev = mesh.shape[axis]
         trace = tracing.current_trace()
         said = {"outcome": "error", "program": "", "agg_mode": "",
                 "topn_keys": 0, "decline": "", "run_passes": None,
-                "shards": n_dev, "shard_rows": None, "shard_len": None}
+                "shards": n_dev, "shard_rows": None, "shard_len": None,
+                "join_pos_lanes": 0, "join_pos_built": 0}
         t0 = time.perf_counter_ns()
         lane = f"mesh:{axis}={n_dev} ({threading.current_thread().name})"
         with TL.device_scope(lane), TL.launch_scope(tracing._next_id()):
@@ -1170,13 +1186,16 @@ class MPPEngine:
                     "mpp.launch", t0, time.perf_counter_ns(),
                     mesh=f"{axis}={n_dev}", program=said["program"],
                     outcome=said["outcome"], **self._said_agg(said),
+                    join_pos_built=said["join_pos_built"],
                     waiters=[trace.trace_id] if trace is not None else [],
                 )
 
     @staticmethod
     def _said_agg(said: dict) -> dict:
-        """What `mpp.prepare` and `mpp.launch` say of the aggregation."""
-        out = {k: said[k] for k in ("agg_mode", "topn_keys", "decline", "shards")}
+        """What `mpp.prepare` and `mpp.launch` both say: of the
+        aggregation, the stream's layout and the LUT levels' lanes."""
+        out = {k: said[k] for k in ("agg_mode", "topn_keys", "decline", "shards",
+                                    "join_pos_lanes")}
         for k in ("run_passes", "shard_rows", "shard_len"):  # clustered alone
             if said[k] is not None:
                 out[k] = said[k]
@@ -1242,11 +1261,18 @@ class MPPEngine:
         def note(j):
             sd, off = soj[j]
             need[id(sd)].add(off)
+        pos_scan = meta["pos_scan"]
         for lvl in meta["levels"].values():
             # a LUT level's build keys live in the LUT itself — the raw
-            # build key lanes never enter the program
-            keys = (lvl.frag.probe_keys if lvl.use_lut
-                    else lvl.frag.probe_keys + lvl.frag.build_keys)
+            # build key lanes never enter the program; nor do the probe
+            # keys of a level that takes its positions as a lane, unless
+            # something else reads them
+            if id(lvl.frag) in pos_scan:
+                keys = []
+            elif lvl.use_lut:
+                keys = lvl.frag.probe_keys
+            else:
+                keys = lvl.frag.probe_keys + lvl.frag.build_keys
             for j in keys:
                 note(j)
             for c in lvl.r_post:
@@ -1269,6 +1295,8 @@ class MPPEngine:
                     used = set(); x.collect_columns(used)
                     for j in used:
                         note(j)
+            if meta["agg"].get("rp_ck") is not None:
+                note(meta["agg"]["rp_ck"])  # the clustered runs are cut by it
 
         # flatten args: per scan (in mplan.scans order): rowid, row_valid,
         # then (data, valid) per needed offset (sorted). A fused SHARDED
@@ -1338,6 +1366,7 @@ class MPPEngine:
         if use_topn:
             said["decline"] = (agm or {}).get("clustered_reason") or ""
         self.last_agg = {k: said[k] for k in ("agg_mode", "topn_keys", "decline")}
+        said["join_pos_lanes"], said["join_pos_built"] = len(pos_scan), 0
         # clustered alone: how many shifted-add passes sum a run, and how
         # the stream lies over the mesh: the rows of each run-aligned
         # shard and the length every shard pads to (the other modes cut
@@ -1350,6 +1379,12 @@ class MPPEngine:
         said["shard_len"] = shard_len if is_clustered else None
         TL.boundary("mpp.prepare", t_prep, time.perf_counter_ns(),
                     **self._said_agg(said))
+        by_frag = {id(s.frag): s for s in scans}
+        pos_lanes: dict = {}  # LUT level -> (its position lane, the lane's spec)
+        # per LUT level, how its positions came: lane_hit | lane_built |
+        # in_program (`tidb_tpu_mpp_join_pos_total`, counted when the
+        # launch ends ok)
+        join_pos: list[str] = []
         for s in scans:
             tick()  # each scan's lane build/upload is O(table bytes)
             is_sharded = id(s.frag) in sharded
@@ -1378,8 +1413,8 @@ class MPPEngine:
                 splits, L, _, _ = self._clustered_splits(s, koff, h, n_dev, sel)
                 total = n_dev * L
 
-                def lay(a, _sp=splits, _L=L):
-                    return self._shard_pad(a, _sp, _L)
+                def lay(a, fill=0, _sp=splits, _L=L):
+                    return self._shard_pad(a, _sp, _L, fill)
 
                 def tg(tag):
                     return ("c", n_dev, tag)
@@ -1389,8 +1424,8 @@ class MPPEngine:
             else:
                 total = max(-(-n // n_dev), 1) * n_dev if is_sharded else max(n, 1)
 
-                def lay(a, _t=total):
-                    return _pad(a, _t)
+                def lay(a, fill=0, _t=total):
+                    return _pad(a, _t, fill)
 
                 def tg(tag):
                     return tag
@@ -1405,7 +1440,9 @@ class MPPEngine:
 
             spec = P(axis) if is_sharded else P()
 
-            def put(tag, build, _shd=NamedSharding(mesh, spec), _ck=ck, _tg=tg):
+            shd = NamedSharding(mesh, spec)
+
+            def put(tag, build, _shd=shd, _ck=ck, _tg=tg):
                 args.append(self._dev_put(_ck(_tg(tag)), build, _shd))
 
             if pref:
@@ -1424,19 +1461,54 @@ class MPPEngine:
                 in_specs += [spec, spec]
             scan_arg_meta.append((id(s.frag), offs, is_sharded, pref))
             shapes.append((total, is_sharded, offs, pref))
+            # the LUT levels that probe with this scan's rows as they lie
+            # here (`_level_forms`) take the build row position of every
+            # row as one more lane of the scan: int32, -1 where the key
+            # finds no build row and on the padding. It depends on the two
+            # tables' data alone, not on the statement's literals (they
+            # enter through the masks and through which rows `sel` kept),
+            # so it stays resident like the scan's other lanes. BOTH data
+            # versions sit where a lane's version does: a write to either
+            # table makes a new lane and evicts this one; a scan without a
+            # version (a read under the table's last commit) on either
+            # side builds its lane for the statement and caches nothing.
+            for lvl in lvls:
+                if pos_scan.get(id(lvl.frag)) != id(s.frag):
+                    continue
+                bsd = by_frag[id(lvl.frag.build)]
+                tag = ("jpos", bsd.frag.ds.table.id,
+                       tuple(soj[j][1] for j in lvl.frag.probe_keys),
+                       tuple(soj[j][1] for j in lvl.frag.build_keys),
+                       tuple(lvl.lut_lo), tuple(lvl.lut_stride),
+                       tuple(lvl.lut_size), lvl.lut_dom, h)
+                both = (ver, bsd.version)
+                key = (None if min(both) < 0
+                       else (tid, both, tg(tag), total, is_sharded))
+                join_pos.append("lane_hit" if key in self._dev_cache else "lane_built")
+                pos_lanes[id(lvl.frag)] = (self._dev_put(
+                    key, lambda _lvl=lvl: lay(self._join_pos_lane(_lvl, soj, sel), fill=-1),
+                    shd), spec)
 
-        # LUT levels: the device-resident build structure enters the
-        # program replicated, after every scan's lanes. Resident copies
-        # come from the store's BuildSideCache under (table, span,
-        # schema-ver, codec-sig) — the sig carries the data version and
-        # every layout parameter, so a write OR a layout change can never
-        # serve a stale structure (a schema bump purges via get(), DDL/
-        # bulk-load additionally purge through TileCache.invalidate_table)
-        by_frag = {id(s.frag): s for s in scans}
+        # LUT levels: one argument each after every scan's lanes, in
+        # level order: the level's position lane or, where the program
+        # gathers the positions itself, the device-resident build
+        # structure, replicated. Resident copies come from the store's
+        # BuildSideCache under (table, span, schema-ver, codec-sig) — the
+        # sig carries the data version and every layout parameter, so a
+        # write OR a layout change can never serve a stale structure (a
+        # schema bump purges via get(), DDL/bulk-load additionally purge
+        # through TileCache.invalidate_table)
         lut_fids = []
-        for lvl in meta["levels"].values():
+        for lvl in lvls:
             if not lvl.use_lut:
                 continue
+            lut_fids.append(id(lvl.frag))
+            if id(lvl.frag) in pos_lanes:
+                lane, spec = pos_lanes[id(lvl.frag)]
+                args.append(lane)
+                in_specs.append(spec)
+                continue
+            join_pos.append("in_program")
             tick()  # the LUT build walks O(build rows) host lanes
             bsd = by_frag[id(lvl.frag.build)]
             boffs = tuple(soj[bk][1] for bk in lvl.frag.build_keys)
@@ -1458,7 +1530,7 @@ class MPPEngine:
                 lut = build()
             args.append(lut)
             in_specs.append(P())
-            lut_fids.append(id(lvl.frag))
+        said["join_pos_built"] = join_pos.count("lane_built")
 
         tick()
         key = self._program_key(mplan, meta, scans, shapes, n_dev)
@@ -1503,6 +1575,8 @@ class MPPEngine:
                 # block up top): retried attempts, fallbacks and the
                 # declined first pass of a tie overflow never reach here
                 M.TPU_MPP_FUSED.inc(outcome=outcome)
+                for how in join_pos:
+                    M.TPU_MPP_JOIN_POS.inc(outcome=how)
                 said["outcome"] = "ok"
                 if meta["agg"] is not None:
                     if meta["agg"]["mode"] == "sorted":
@@ -1553,10 +1627,115 @@ class MPPEngine:
         return lut
 
     @staticmethod
+    def _join_pos_lane(lvl, scan_of_joined, sel) -> np.ndarray:
+        """The build row position of every probe row of a LUT level whose
+        probe keys are columns of one base scan (`_level_forms`), the
+        rows `sel` keeps of it when it is prefiltered: `lut[pack(key)]`
+        where every key dimension is present and inside the build
+        domain, else -1 — what `lut_join` computes where it gathers the
+        LUT itself. int32, built by numpy from `_build_lut`'s array: once
+        per (versions of both tables, layout), one pass over the key
+        lanes, which costs less than the upload that follows it and
+        keeps the LUT and the key lanes off the device."""
+        lut = MPPEngine._build_lut(lvl, scan_of_joined)
+        acc = ok = None
+        for j, lo, st, size in zip(lvl.frag.probe_keys, lvl.lut_lo,
+                                   lvl.lut_stride, lvl.lut_size):
+            sd, off = scan_of_joined[j]
+            d, v = sd.lane(off)
+            if sel is not None:
+                d, v = d[sel], v[sel]
+            dd = d.astype(np.int64)
+            ok_j = v & (dd >= lo) & (dd < lo + size)
+            term = (dd - lo) * st
+            acc = term if acc is None else acc + term
+            ok = ok_j if ok is None else ok & ok_j
+        return np.where(ok, lut[np.where(ok, acc, 0)], np.int32(-1))
+
+    @staticmethod
     def _stream_source(frag):
         while isinstance(frag, JoinFrag):
             frag = frag.probe
         return frag
+
+    @staticmethod
+    def _level_forms(mplan, meta):
+        """The form each LUT level of the chain takes, from the plan's
+        shape alone. Returns (folds, pos_scan).
+
+        `folds`: ids of the levels that only FILTER the build side of
+        the LUT level right under them: both inner, the upper level's
+        probe keys all columns of the lower level's build scan, no
+        residual condition, and nothing above reads a column or the row
+        id of the upper level's own build scan (Q3: CUSTOMER keeps the
+        ORDERS rows of one segment). Such a level probes the few build
+        rows once instead of every stream row: its match folds into the
+        lower level's build mask, and its stream-long gathers shrink to
+        build-long ones. The level under a fold runs as an ordinary one.
+
+        `pos_scan`: level id -> id of the base scan whose rows, as the
+        host lays them out, the level probes with: the lower level's
+        build scan for a fold, the stream source for a level whose probe
+        keys are all columns of it while every level under it is a LUT
+        level (a LUT probe moves no row; an exchange or a compact join
+        does). The build row position of each such row is known before
+        the program runs, so it enters as an argument lane of that scan
+        (`_join_pos_lane`) and the program neither packs the keys nor
+        gathers the LUT. Every other LUT level, one whose probe key was
+        gathered from a lower level's build side among them, keeps
+        `lut[key]` in the program."""
+        levels, agg_meta = meta["levels"], meta["agg"]
+        chain = []  # root first
+        f = mplan.root
+        while isinstance(f, JoinFrag):
+            chain.append(f)
+            f = f.probe
+        stream = f
+        # joined-schema columns something above the joins reads: aggregate
+        # arguments, group keys (the rowpos modes decode theirs on the host
+        # from the group level's build lanes), every level's probe keys
+        # and residual conditions
+        read_above: set[int] = set()
+        for lv in levels.values():
+            read_above.update(lv.frag.probe_keys)
+            for c in lv.r_post:
+                c.collect_columns(read_above)
+        if agg_meta is not None:
+            for ra in agg_meta["r_args"]:
+                for x in ra:
+                    x.collect_columns(read_above)
+            if agg_meta["mode"] not in ("rowpos", "clustered"):
+                read_above.update(g.idx for g in mplan.agg.group_by)
+
+        def columns_of(scan, idxs):
+            return all(scan.side_offset <= j < scan.side_offset + scan.n_cols
+                       for j in idxs)
+
+        def filters_the_build_below(frag):
+            p = frag.probe
+            if not (agg_meta is not None and isinstance(p, JoinFrag)):
+                return False
+            lvl, low = levels[id(frag)], levels[id(p)]
+            b = frag.build
+            return (lvl.use_lut and low.use_lut and frag.kind == p.kind == "inner"
+                    and not lvl.r_post
+                    and columns_of(p.build, frag.probe_keys)
+                    and not any(columns_of(b, (j,)) for j in read_above)
+                    and agg_meta.get("rp_fid") != id(b))
+
+        folds, pos_scan = set(), {}
+        under_fold = False
+        for i, frag in enumerate(chain):
+            if not under_fold and filters_the_build_below(frag):
+                folds.add(id(frag))
+                pos_scan[id(frag)] = id(frag.probe.build)
+                under_fold = True
+                continue
+            under_fold = False
+            if (columns_of(stream, frag.probe_keys)
+                    and all(levels[id(g)].use_lut for g in chain[i:])):
+                pos_scan[id(frag)] = id(stream)
+        return folds, pos_scan
 
     def _program_key(self, mplan, meta, scans, shapes, n_dev):
         parts = self._program_key_parts(mplan, meta, scans, shapes, n_dev)
@@ -1567,6 +1746,7 @@ class MPPEngine:
         """Everything the compiled kernel bakes in, as the strings the
         program key hashes; the clustered run bound is the last."""
         parts = [repr(shapes), str(n_dev)]
+        scan_at = {id(sc): i for i, sc in enumerate(mplan.scans)}
         for s, sh in zip(scans, shapes):
             # a prefiltered scan's predicate resolved host-side: the
             # program is constant-free, so every same-shape predicate
@@ -1584,6 +1764,12 @@ class MPPEngine:
                 str(lvl.use_lut), repr(lvl.lut_lo), repr(lvl.lut_size),
                 repr(lvl.lut_stride), str(lvl.lut_dom),
             ]
+            if lvl.use_lut:
+                # the form the level took (`_level_forms`): its positions
+                # a lane of the scan at that place in mplan.scans, or
+                # gathered from the LUT by the program
+                parts.append("pos:%d" % scan_at[meta["pos_scan"][fid]]
+                             if fid in meta["pos_scan"] else "lut")
         if meta["agg"]:
             a = meta["agg"]
             # int keys bake `lo` (km[1]) into the compiled kernel, so the
@@ -1622,8 +1808,10 @@ class MPPEngine:
         for fid, offs, is_sharded, pref in scan_arg_meta:
             arg_plan.append((fid, pos, offs, pref))
             pos += 2 + 2 * len(offs)
-        # LUT args (replicated) follow the scan args, in level order
-        lut_arg_pos = {fid: pos + i for i, fid in enumerate(lut_fids)}
+        # one argument a LUT level follows the scan args, in level order:
+        # its position lane (laid out as its probe scan is) or its LUT
+        # (replicated)
+        level_arg_pos = {fid: pos + i for i, fid in enumerate(lut_fids)}
 
         # r_pushed is keyed by id(ScanData); scan_arg_meta carries frag ids.
         # Re-key via scan_of_joined (every ScanData maps to its frag).
@@ -1721,29 +1909,36 @@ class MPPEngine:
 
         @jax.named_scope("join.lut")
         def lut_join(frag, lvl, flat, pmap_, pmask, prow, bmap, bmask):
-            """Fused-level probe: pack the probe keys in the BUILD-local
-            domain and gather the device-resident LUT — no build sort, no
-            searchsorted, no exchange (the structure is replicated). Out-
-            of-domain or absent keys miss; per-statement build filters
-            apply through the gathered build mask."""
-            lut = flat[lut_arg_pos[id(frag)]]
+            """Fused-level probe: the build row position of every probe
+            row, -1 where its key is NULL, outside the build domain or
+            absent, then the build side's mask at that position — no
+            build sort, no searchsorted, no exchange (the structure is
+            replicated). The positions are an argument lane where the
+            host could know them before the program ran (`_level_forms`);
+            else the program packs the probe keys in the BUILD-local
+            domain and gathers the device-resident LUT. Per-statement
+            build filters apply through the gathered build mask."""
             B = bmask.shape[0]
-            acc = None
-            pkv = None
-            for j, lo, st, size in zip(frag.probe_keys, lvl.lut_lo,
-                                       lvl.lut_stride, lvl.lut_size):
-                d, v = pmap_[j]
-                dd = d.astype(jnp.int64)
-                # per-dimension range check BEFORE packing: values outside
-                # the build domain must miss, never wrap into a false slot
-                ok = v & (dd >= lo) & (dd < lo + size)
-                term = (dd - lo) * st
-                acc = term if acc is None else acc + term
-                pkv = ok if pkv is None else (pkv & ok)
-            pos = lut[jnp.clip(acc, 0, lvl.lut_dom - 1)]
+            if id(frag) in meta["pos_scan"]:
+                pos = flat[level_arg_pos[id(frag)]]
+            else:
+                lut = flat[level_arg_pos[id(frag)]]
+                acc = None
+                pkv = None
+                for j, lo, st, size in zip(frag.probe_keys, lvl.lut_lo,
+                                           lvl.lut_stride, lvl.lut_size):
+                    d, v = pmap_[j]
+                    dd = d.astype(jnp.int64)
+                    # per-dimension range check BEFORE packing: values outside
+                    # the build domain must miss, never wrap into a false slot
+                    ok = v & (dd >= lo) & (dd < lo + size)
+                    term = (dd - lo) * st
+                    acc = term if acc is None else acc + term
+                    pkv = ok if pkv is None else (pkv & ok)
+                pos = jnp.where(pkv, lut[jnp.clip(acc, 0, lvl.lut_dom - 1)], -1)
             bsel = jnp.clip(pos.astype(jnp.int64), 0, B - 1)
             lut_pos[id(frag.build)] = bsel
-            match = pmask & pkv & (pos >= 0) & bmask[bsel]
+            match = pmask & (pos >= 0) & bmask[bsel]
             merged = dict(pmap_)
             for j, (d, v) in bmap.items():
                 merged[j] = (d[bsel], v[bsel] & match)
@@ -1755,50 +1950,12 @@ class MPPEngine:
             rowids[id(frag.build)] = jnp.where(match, bsel, -1)
             return merged, match, rowids
 
-        # joined-schema columns something above the joins reads: aggregate
-        # arguments, group keys (the rowpos modes decode theirs on the host
-        # from the group level's build lanes), every level's probe keys
-        # and residual conditions
-        read_above: set[int] = set()
-        for lv in levels.values():
-            read_above.update(lv.frag.probe_keys)
-            for c in lv.r_post:
-                c.collect_columns(read_above)
-        if agg is not None:
-            for ra in agg_meta["r_args"]:
-                for x in ra:
-                    x.collect_columns(read_above)
-            if agg_meta["mode"] not in ("rowpos", "clustered"):
-                read_above.update(g.idx for g in agg.group_by)
-
-        def filters_the_build_below(frag):
-            """True when LUT level `frag` only FILTERS the build side of
-            the LUT level right under it: both inner, its probe keys all
-            columns of that build scan, no residual condition, and
-            nothing above reads a column or the row id of its own build
-            scan (Q3: CUSTOMER keeps the ORDERS rows of one segment).
-            Such a level probes the few build rows once instead of every
-            stream row: its match folds into the lower level's build
-            mask, and its stream-long gathers (the probe key's lane, the
-            LUT, the mask) shrink to build-long ones."""
-            p = frag.probe
-            if not (agg is not None and isinstance(p, JoinFrag)):
-                return False
-            lvl, low = levels[id(frag)], levels[id(p)]
-            b, pb = frag.build, p.build
-            return (lvl.use_lut and low.use_lut and frag.kind == p.kind == "inner"
-                    and not lvl.r_post
-                    and all(pb.side_offset <= j < pb.side_offset + pb.n_cols
-                            for j in frag.probe_keys)
-                    and not any(b.side_offset <= j < b.side_offset + b.n_cols
-                                for j in read_above)
-                    and agg_meta.get("rp_fid") != id(b))
-
         def join_stage(frag, flat):
             if isinstance(frag, ScanFrag):
                 return scan_stage(id(frag), flat)
             lvl = levels[id(frag)]
-            if filters_the_build_below(frag):
+            if id(frag) in meta["folds"]:
+                # the level only filters the build below (`_level_forms`)
                 low = frag.probe
                 pmap_, pmask, prow = join_stage(low.probe, flat)
                 lmap, lmask, _ = scan_stage(id(low.build), flat)

@@ -442,6 +442,12 @@ TPU_MPP_SHARD_ROWS = REGISTRY.counter(
     "tidb_tpu_mpp_shard_rows_total",
     "stream rows each mesh shard of a clustered MPP dispatch held, by shard (padding not counted)",
 )
+TPU_MPP_JOIN_POS = REGISTRY.counter(
+    "tidb_tpu_mpp_join_pos_total",
+    "LUT join levels of MPP dispatches that ended ok, by where the build row positions came "
+    "from (lane_hit: a resident lane | lane_built: a lane built in that launch | "
+    "in_program: gathered from the LUT by the program)",
+)
 TPU_BUILD_CACHE = REGISTRY.counter(
     "tidb_tpu_build_cache_total",
     "device-resident build-side cache lifecycle (hit | miss | evict | invalidate)",
